@@ -130,8 +130,8 @@ class ModelConfig:
     scan_layers: bool = True          # lax.scan over stacked layers
     remat: bool = True                # checkpoint each layer
     # 'full' = recompute everything (max memory headroom); 'dots' = save
-    # matmul outputs (fewer recomputed FLOPs; measured +3.3 MFU pts on
-    # llama3-1b/v5e vs 'full').
+    # matmul outputs (fewer recomputed FLOPs; the difference is not
+    # measured on this code).
     remat_policy: str = 'dots'
     attention_impl: str = 'auto'      # 'auto'|'pallas'|'xla'|'ring'
     # Pallas flash-attention tile sizes (0 ⇒ the kernel's default).
@@ -174,8 +174,8 @@ class ModelConfig:
     # adapter gather+dot through ops/fused_lora under this knob).
     # 'pallas_interpret': the same kernels under the Pallas
     # interpreter (CPU tier-1 pinning). Engines validate the knob at
-    # construction (paged-only; softcap rejected) — see
-    # models/inference.py _resolve_decode_kernel.
+    # construction (paged-only; softcap rejected; 'pallas' needs a TPU
+    # and tp=1) — see models/inference.py _resolve_decode_kernel.
     decode_kernel: str = 'xla'
 
     @property

@@ -80,6 +80,5 @@ def quantize_params(params: Any, cfg: ModelConfig) -> Any:
         return out
 
     # One jitted dispatch for the whole tree: eager per-leaf quantize
-    # costs a device round trip per op, which dominates on tunneled
-    # devices.
+    # compiles and launches every op on its own.
     return jax.jit(walk)(params)

@@ -31,7 +31,8 @@ def _build() -> bool:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=120, check=False)
     except (OSError, subprocess.TimeoutExpired) as e:
-        logger.debug('logmux build skipped: %s', e)
+        logger.warning('logmux build skipped (python pump in use): %s',
+                       e)
         return False
     if proc.returncode != 0:
         logger.warning('logmux build failed:\n%s', proc.stderr)

@@ -31,11 +31,16 @@ the kernel (cheap relative to attention FLOPs at the sizes we run).
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from skypilot_tpu.parallel import sharding as sharding_lib
+
+logger = logging.getLogger(__name__)
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
@@ -452,6 +457,12 @@ def flash_attention(q: jax.Array,
                  block_q % 128 == 0 and block_k % 128 == 0)
         impl = 'pallas' if (on_tpu and tiles and
                             not logit_softcap) else 'xla'
+        # Trace time, so once per compiled program: which attention a
+        # run got is read from its log, not guessed from its flags.
+        logger.info(
+            "flash_attention: impl='auto' resolved to %r (seq=%d "
+            'head_dim=%d block_q=%d block_k=%d softcap=%s tpu=%s)', impl,
+            s, d, block_q, block_k, bool(logit_softcap), on_tpu)
     if impl == 'xla':
         n_rep = h // k.shape[2]
         return _reference_attention(q, _repeat_kv(k, n_rep),
@@ -478,6 +489,18 @@ def flash_attention(q: jax.Array,
         if s % block_q or s % block_k:
             raise ValueError(f'seq {s} must tile by block_q={block_q}, '
                              f'block_k={block_k}')
-        return _flash(q, k, v, causal, window, sm_scale, block_q, block_k,
-                      impl == 'pallas_interpret')
+        kernel = lambda q, k, v: _flash(  # noqa: E731
+            q, k, v, causal, window, sm_scale, block_q, block_k,
+            impl == 'pallas_interpret')
+        mesh = jax.sharding.get_abstract_mesh()
+        if not mesh.empty and mesh.size > 1:
+            # A Mosaic call has no partitioning rule (jax refuses to
+            # lower it under a sharded jit), so under an ambient mesh
+            # (sharding.use_mesh) each device runs the kernel on its
+            # own batch and head shard. Attention mixes neither, so no
+            # collective is needed.
+            spec = sharding_lib.spec_for('batch', None, 'act_heads', None)
+            kernel = sharding_lib.shard_map(
+                kernel, in_specs=(spec, spec, spec), out_specs=spec)
+        return kernel(q, k, v)
     raise ValueError(f'Unknown impl {impl!r}')

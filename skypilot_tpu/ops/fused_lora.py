@@ -11,21 +11,17 @@ the A/B STACKS directly through `ids[b]` — the row's adapter tiles
 stream straight from the resident stack into VMEM and both dots run in
 one pass. No gathered a_sel/b_sel intermediate ever exists.
 
-Dtype discipline matches the XLA twin: both dots run in the input
-compute dtype with default accumulation (LoRADenseGeneral /
-MultiLoRADenseGeneral use no preferred_element_type on the delta
-dots), so fp32 engines see bit-level-scale agreement and the
-composition-matrix pin is greedy equivalence + tolerance, same
-contract as the fused attention kernel.
+Dtype discipline matches the XLA twin: both dots take operands in the
+input compute dtype, accumulate in float32 (the MXU's way; Mosaic wants
+it said) and round each product to the compute dtype, which is what
+XLA's default-precision dots do. fp32 engines see bit-level agreement
+under the interpreter; on the v5e the compiled kernel matched the twin
+at bf16, d=2048, r=16 (chip_smoke.py, PR 21).
 
-Verdict (documented in docs/performance.md "Fused paged-decode
-kernel" and
-surfaced by `bench.py --dryrun-serve-kernel`): the fusion removes
-B·(in·r + r·out) HBM round-trip bytes per adapted projection per step,
-but at decode shapes the delta is ≪ the base W·x matmul that runs
-either way, so it is wired behind the SAME decode_kernel knob rather
-than its own — it pays exactly when the attention fusion pays (many
-slots × many resident adapters), and costs nothing to carry.
+The fusion removes B·(in·r + r·out) HBM round-trip bytes per adapted
+projection per step. Whether that shows next to the base W·x matmul
+that runs either way is not measured (ROADMAP S5, R8); until it is,
+the kernel rides the SAME decode_kernel knob rather than its own.
 """
 from __future__ import annotations
 
@@ -39,10 +35,14 @@ def _lora_kernel(ids_ref, x_ref, a_ref, b_ref, o_ref):
     """Grid cell (b,): z = (x[b] @ A[ids[b]]) @ B[ids[b]].
     x (1, T, IN); a (1, IN, R); b (1, R, OUT); o (1, T, OUT)."""
     x = x_ref[0]
-    a = a_ref[0]
-    z = jax.lax.dot_general(x, a, (((1,), (0,)), ((), ())))
-    o_ref[0] = jax.lax.dot_general(z, b_ref[0],
-                                   (((1,), (0,)), ((), ())))
+    # float32 accumulator, rounded to the compute dtype: see "Dtype
+    # discipline" above.
+    z = jax.lax.dot_general(
+        x, a_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(x.dtype)
+    o_ref[0] = jax.lax.dot_general(
+        z, b_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def fused_multi_lora(x: jax.Array,

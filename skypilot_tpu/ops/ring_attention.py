@@ -80,12 +80,12 @@ def _chunk_update_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
     offs_ref is scalar-prefetched [q_offset, k_offset] — traced values
     inside the fori_loop ring step, so they ride in SMEM rather than
     being baked into the kernel."""
-    q = q_ref[0, :, 0, :]                             # (Sq, D)
-    k = k_ref[0, :, 0, :]                             # (Sk, D)
-    v = v_ref[0, :, 0, :]
-    m = m_ref[0, 0]                                   # (Sq,)
-    l = l_ref[0, 0]
-    o = o_ref[0, :, 0, :]                             # (Sq, D) f32
+    q = q_ref[0]                                      # (Sq, D)
+    k = k_ref[0]                                      # (Sk, D)
+    v = v_ref[0]
+    m = m_ref[0, 0, 0]                                # (Sq,)
+    l = l_ref[0, 0, 0]
+    o = o_ref[0]                                      # (Sq, D) f32
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale
@@ -100,12 +100,16 @@ def _chunk_update_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
     m_new = jnp.maximum(m, m_cur)
     p = jnp.exp(s - m_new[:, None])
     alpha = jnp.exp(m - m_new)
-    l_out[0, 0] = l * alpha + jnp.sum(p, axis=-1)
-    m_out[0, 0] = m_new
-    o_out[0, :, 0, :] = (
+    l_out[0, 0, 0] = l * alpha + jnp.sum(p, axis=-1)
+    m_out[0, 0, 0] = m_new
+    # The MXU accumulates in 32 bits and Mosaic wants that said; the
+    # round trip through v.dtype is the twin's einsum output rounding.
+    o_out[0] = (
         o * alpha[:, None] +
-        jax.lax.dot_general(p.astype(v.dtype), v,
-                            (((1,), (0,)), ((), ()))).astype(jnp.float32))
+        jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32
+        ).astype(v.dtype).astype(jnp.float32))
 
 
 def _chunk_update_pallas(q, k, v, o, m, l, *, sm_scale, mask_mode,
@@ -118,33 +122,40 @@ def _chunk_update_pallas(q, k, v, o, m, l, *, sm_scale, mask_mode,
     s_k = k.shape[1]
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32)])
-    grid = (batch, heads)
-    qo_spec = pl.BlockSpec((1, s_q, 1, head_dim),
-                           lambda b, h, offs: (b, 0, h, 0))
-    kv_spec = pl.BlockSpec((1, s_k, 1, head_dim),
-                           lambda b, h, offs: (b, 0, h, 0))
-    ml_spec = pl.BlockSpec((1, 1, s_q), lambda b, h, offs: (b, h, 0))
+    # Tiles Mosaic accepts (last two block dims multiples of (8, 128)
+    # or the array's own): (B, S, H, D) is viewed as (B, S, H*D) — a
+    # free reshape — so one head is the lane-aligned column block
+    # (S, D); m/l (B, H, Sq) become (B, H, 1, Sq) rows.
+    qo_spec = pl.BlockSpec((1, s_q, head_dim),
+                           lambda b, h, offs: (b, 0, h))
+    kv_spec = pl.BlockSpec((1, s_k, head_dim),
+                           lambda b, h, offs: (b, 0, h))
+    ml_spec = pl.BlockSpec((1, 1, 1, s_q),
+                           lambda b, h, offs: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
+        grid=(batch, heads),
         in_specs=[qo_spec, kv_spec, kv_spec, qo_spec, ml_spec, ml_spec],
         out_specs=[qo_spec, ml_spec, ml_spec],
     )
     kernel = functools.partial(_chunk_update_kernel, sm_scale=sm_scale,
                                mask_mode=mask_mode)
+    fold = lambda x: x.reshape(x.shape[0], x.shape[1], -1)
+    row = lambda x: x[:, :, None, :]
     o_new, m_new, l_new = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct(o.shape, jnp.float32),
-            jax.ShapeDtypeStruct(m.shape, jnp.float32),
-            jax.ShapeDtypeStruct(l.shape, jnp.float32),
+            jax.ShapeDtypeStruct(fold(o).shape, jnp.float32),
+            jax.ShapeDtypeStruct(row(m).shape, jnp.float32),
+            jax.ShapeDtypeStruct(row(l).shape, jnp.float32),
         ],
         interpret=interpret,
-    )(offs, q, k, v, o, m, l)
+    )(offs, fold(q), fold(k), fold(v), fold(o), row(m), row(l))
     # Tuple, not list: the lax.cond skip branch in the ring step passes
     # its carry through unchanged, and branch pytrees must match.
-    return o_new, m_new, l_new
+    return (o_new.reshape(o.shape), m_new[:, :, 0, :],
+            l_new[:, :, 0, :])
 
 
 def ring_attention(q: jax.Array,

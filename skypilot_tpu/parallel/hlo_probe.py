@@ -1,17 +1,18 @@
-"""Compiled-HLO collective probe.
+"""Compiled-HLO probe: what the compiler made of a sharded program.
 
-While the chip is unreachable, compile-time proxies stand in for
-hardware measurements (the BENCH_r03+ pattern: compile counts and
-transfer counts instead of tok/s). This module adds the sharded-serving
-proxy: parse a compiled executable's optimized HLO text and count the
+Parses a compiled executable's optimized HLO text and counts the
 collectives GSPMD inserted — how many all-reduces a tp-sharded decode
-step pays per tick and how many bytes they move over ICI.
+step pays per tick and how many bytes they move over ICI — and reads
+the shapes each Mosaic custom call was handed, which is how a
+multi-chip run shows that a Pallas kernel got its per-device shard and
+not the gathered whole. These are counts and shapes, not times.
 
 Consumed by the inference engines (`decode_hlo_stats`, which feeds the
 `skytpu_engine_tp_allreduce_bytes` / `skytpu_engine_tp_collectives`
-gauges) and by `bench.py --dryrun-serve-sharded` (the MULTICHIP_serve
-row). Pure text parsing — no jax import, so it is testable without a
-device and adds nothing to engine import time.
+gauges), by the trainer (`compiled_step_collectives`, behind
+`train.run --probe-hlo`) and by `bench.py --dryrun-serve-sharded` (the
+MULTICHIP_serve row). Pure text parsing — no jax import, so it is
+testable without a device and adds nothing to engine import time.
 """
 from __future__ import annotations
 
@@ -99,6 +100,34 @@ def collective_stats(hlo_text: str) -> Dict[str, Any]:
     stats['total_bytes'] = sum(stats[op.replace('-', '_') + '_bytes']
                                for op in _COLLECTIVES)
     return stats
+
+
+def custom_call_operands(hlo_text: str,
+                         target: str = 'tpu_custom_call') -> list:
+    """Operand shapes of every custom call to `target` in optimized HLO
+    text, one list of 'dtype[dims]' strings per call, in program order.
+    `tpu_custom_call` is how a Pallas TPU kernel appears; its
+    `operand_layout_constraints={...}` attribute lists exactly the
+    operands it was handed. Under a mesh those must be the per-device
+    shard: a kernel handed the global shape runs the whole batch on
+    every chip."""
+    calls = []
+    key = 'operand_layout_constraints={'
+    for line in hlo_text.splitlines():
+        if f'custom_call_target="{target}"' not in line:
+            continue
+        start = line.find(key)
+        if start < 0:
+            continue
+        # The attribute's value nests braces (`bf16[8,128]{1,0}`).
+        start += len(key)
+        depth, end = 1, start
+        while end < len(line) and depth:
+            depth += {'{': 1, '}': -1}.get(line[end], 0)
+            end += 1
+        calls.append([f'{dt}[{dims}]' for dt, dims in
+                      _SHAPE_RE.findall(line[start:end - 1])])
+    return calls
 
 
 # Memory-layout op mnemonics for gather_stats. Matched with a

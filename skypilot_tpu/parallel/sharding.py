@@ -15,7 +15,10 @@ import jax
 from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-# (logical name, physical mesh axis/axes or None=replicated)
+# (logical name, physical mesh axis/axes or None=replicated). A dim
+# with no name (None in a leaf's metadata: the cache's block and
+# position dims) stays unsharded without a row of its own; flax looks
+# every row's name up with PartitionSpec.index, which refuses None.
 LOGICAL_AXIS_RULES: List[Tuple[str, object]] = [
     # Activations.
     ('batch', ('dp', 'fsdp')),      # data parallel shards the batch
@@ -33,7 +36,6 @@ LOGICAL_AXIS_RULES: List[Tuple[str, object]] = [
     ('expert', 'ep'),               # MoE experts under expert parallelism
     ('layers', 'pp'),               # stacked layer dim under pipeline
     ('stage', 'pp'),                # pipeline executor's stage buffers
-    (None, None),
 ]
 
 
@@ -42,45 +44,28 @@ def logical_axis_rules() -> List[Tuple[str, object]]:
 
 
 def shard_map(fn, *, mesh: Optional[Mesh] = None, in_specs, out_specs):
-    """`shard_map` across jax versions, the single call site for the
-    whole framework. Newer jax exposes `jax.shard_map` (ambient-mesh
-    capable, `check_vma=` kwarg); 0.4.x ships it as
-    `jax.experimental.shard_map.shard_map` (explicit mesh required,
-    `check_rep=` kwarg). `mesh=None` uses the ambient mesh — on 0.4.x
-    that resolves the `with mesh:` context at trace time. Replication
-    checking is disabled either way: callers here wrap collectives whose
-    variance the checker can't infer (same rationale as the check_vma
-    note in collective_bench)."""
-    if hasattr(jax, 'shard_map'):
-        kwargs = dict(in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        if mesh is not None:
-            kwargs['mesh'] = mesh
-        return jax.shard_map(fn, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if mesh is None:
-        from jax._src import mesh as _mesh_lib
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-        if mesh.empty:
-            raise ValueError(
-                'shard_map with mesh=None needs an ambient mesh: pass '
-                'mesh= or enter a `with mesh:` / use_mesh(mesh) context')
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """`jax.shard_map`, the single call site for the whole framework.
+    `mesh=None` uses the ambient mesh (`use_mesh`). Replication
+    checking is disabled: callers here wrap collectives whose variance
+    the checker can't infer (same rationale as the check_vma note in
+    collective_bench)."""
+    kwargs = dict(in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    if mesh is not None:
+        kwargs['mesh'] = mesh
+    return jax.shard_map(fn, **kwargs)
 
 
 def use_mesh(mesh: Mesh):
-    """Ambient-mesh context manager across jax versions: `jax.set_mesh`
-    where it exists, else the Mesh object itself (the 0.4.x context
-    manager that sets thread_resources for pjit and `shard_map` above)."""
-    if hasattr(jax, 'set_mesh'):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Ambient-mesh context manager: what `shard_map(mesh=None)`, bare
+    PartitionSpec constraints and the flash kernel's per-device wrap
+    read. (`with mesh:` sets the older resource env only, which
+    `jax.shard_map` does not see.)"""
+    return jax.set_mesh(mesh)
 
 
 def spec_for(*logical_axes: Optional[str]) -> PartitionSpec:
     """PartitionSpec for a tuple of logical axis names."""
-    rules = dict((k, v) for k, v in LOGICAL_AXIS_RULES if k is not None)
+    rules = dict(LOGICAL_AXIS_RULES)
     parts = []
     for name in logical_axes:
         if name is None:
@@ -135,12 +120,6 @@ def tree_shardings(mesh: Mesh, abstract_tree):
     logical_specs = nn.get_partition_spec(abstract_tree)
     return nn.logical_to_mesh_sharding(logical_specs, mesh,
                                        logical_axis_rules())
-
-
-def shard_params_sharding(mesh: Mesh, abstract_params):
-    """NamedShardings for a flax param pytree with logical metadata.
-    (Historical name; alias of tree_shardings.)"""
-    return tree_shardings(mesh, abstract_params)
 
 
 def _axes_of(entry) -> Tuple[str, ...]:

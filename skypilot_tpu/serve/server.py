@@ -1801,10 +1801,13 @@ def main(argv=None) -> int:
                              '(fused VMEM block-table walk — dequant, '
                              'score, softmax and weighted sum in one '
                              'pass; also fuses resident multi-LoRA '
-                             'gather+dot). Requires --paged-block-size; '
-                             'off-TPU, pallas degrades to the '
-                             'interpreter twin (docs/performance.md '
-                             '"Fused decode kernel")')
+                             'gather+dot). Requires --paged-block-size '
+                             'and a TPU (it refuses to start without '
+                             'one, and under --tp > 1); '
+                             'pallas_interpret runs the same kernel '
+                             'under the Pallas interpreter on the CPU '
+                             '(docs/performance.md "Fused decode '
+                             'kernel")')
     parser.add_argument('--preempt-drain-timeout', type=float,
                         default=serve_constants
                         .preempt_notice_budget_seconds(),
@@ -1848,6 +1851,7 @@ def main(argv=None) -> int:
                              decode_kernel=args.decode_kernel)
     logger.info('sampling filters: top_k=%s top_p=%s (0 = off)',
                 args.top_k, args.top_p)
+    distributed.log_device_memory('after weights placed')
     # Preemption pre-warm BEFORE ready: a replacement replica restores
     # the fleet's hot prefixes so its first shared-prefix request is a
     # cache hit, not a TTFT cliff.

@@ -98,7 +98,8 @@ def _load_native() -> Optional[ctypes.CDLL]:
                 proc = subprocess.run(cmd, capture_output=True, text=True,
                                       timeout=120, check=False)
             except (OSError, subprocess.TimeoutExpired) as e:
-                logger.debug('dataloader build skipped: %s', e)
+                logger.warning('dataloader build skipped (numpy '
+                               'loader in use): %s', e)
                 _load_failed = True
                 return None
             if proc.returncode != 0:

@@ -3,7 +3,8 @@
 MFU = achieved matmul FLOPs/s ÷ peak bf16 FLOPs/s of the slice, using the
 standard 6·N-active + attention-term FLOPs/token model
 (ModelConfig.flops_per_token). Chip peak numbers come from
-topology.GENERATIONS so the same math works on any generation.
+topology.GENERATIONS so the same math works on any generation; a device
+that is not in that table is an error, not a default.
 """
 from __future__ import annotations
 
@@ -43,8 +44,11 @@ _STEP_COLLECTIVES = obs.gauge(
 
 
 def detect_chip_peak_tflops() -> float:
-    """Peak bf16 TFLOPs of one local device, by device-kind sniffing; falls
-    back to v5e if unknown (CPU test runs report vs-v5e numbers)."""
+    """Peak bf16 TFLOPs of one local device, from its device kind and
+    topology.GENERATIONS. Raises for a kind that is not in the table,
+    the CPU included: a utilization against a guessed peak is not a
+    measurement. Callers off the chip pass their own peak to `mfu` or
+    do without one."""
     dev = jax.devices()[0]
     kind = getattr(dev, 'device_kind', '').lower()
     squashed = kind.replace(' ', '')
@@ -55,7 +59,10 @@ def detect_chip_peak_tflops() -> float:
         for alias in gen.aliases + (gen.name,):
             if alias in squashed:
                 return gen.bf16_tflops_per_chip
-    return topology.GENERATIONS['v5e'].bf16_tflops_per_chip
+    raise ValueError(
+        f'no published bf16 peak for device kind {kind!r} (platform '
+        f'{dev.platform!r}); known generations: '
+        f'{sorted(topology.GENERATIONS)}. Pass peak_tflops_per_chip.')
 
 
 @dataclasses.dataclass
@@ -145,7 +152,9 @@ def publish_throughput(cfg: ModelConfig, batch_size: int, seq_len: int,
                        ) -> Tuple[float, float]:
     """Compute (tokens/sec over all chips, MFU) and publish both into
     the registry — the one call sites (bench.py, trainers) use so the
-    derived numbers and the scraped numbers can never disagree."""
+    derived numbers and the scraped numbers can never disagree. MFU is
+    against the local device's published peak, so this raises off the
+    chip (detect_chip_peak_tflops)."""
     tps = tokens_per_sec(batch_size, seq_len, step_time_s)
     utilization = mfu(cfg, batch_size, seq_len, step_time_s, num_chips)
     _TOKENS_PER_SEC.set(tps)

@@ -214,6 +214,7 @@ def main(argv=None) -> int:
                                             jax.random.PRNGKey(0),
                                             train_config,
                                             zero_sharding=args.zero1)
+    distributed.log_device_memory('after state init')
     from skypilot_tpu.train import metrics as metrics_lib
     opt_total, opt_per_dev = metrics_lib.publish_opt_state_bytes(state)
     if args.zero1:
@@ -411,14 +412,29 @@ def main(argv=None) -> int:
                     math.exp(min(val_loss, 30.0)))
         return val_loss
 
+    import contextlib
+
+    from skypilot_tpu.parallel import sharding as sharding_lib
+
+    def step_ctx():
+        """What every trace of the step runs under: the ambient mesh
+        (the flash kernel's shard_map reads it). The elastic step's
+        bit-parity contract requires running WITHOUT a mesh context
+        (make_elastic_train_step docstring); placements are carried
+        entirely by the jit shardings either way."""
+        if elastic_ctx is not None:
+            return contextlib.nullcontext()
+        return sharding_lib.use_mesh(mesh)
+
     if args.probe_hlo:
         from skypilot_tpu.train.trainer import compiled_step_collectives
         # Datasets advance on every next_batch: probe with the first
         # batch, then hand that same batch back to the loop so no
         # training data is skipped.
         probed_batch = batch_for(start_step)
-        probe = compiled_step_collectives(
-            step_fn, state, probed_batch, dp=mesh_cfg.dp)
+        with step_ctx():
+            probe = compiled_step_collectives(
+                step_fn, state, probed_batch, dp=mesh_cfg.dp)
         inner_batch_for = batch_for
         replay = {'batch': probed_batch}
 
@@ -431,6 +447,10 @@ def main(argv=None) -> int:
             'reduce_scatter=%d (+%d unfused partition-scatter)',
             probe['all_reduce'], probe['all_gather'],
             probe['reduce_scatter'], probe['partition_scatter'])
+        # Under a mesh each Pallas kernel must have been handed its
+        # per-device shard, not the gathered batch.
+        logger.info('compiled step kernel operands: %s',
+                    probe['kernel_operands'])
 
     loss = float('nan')
     # Profile a small steady-state slice: step 2 (past compile+warmup)
@@ -445,13 +465,12 @@ def main(argv=None) -> int:
                        'profile (start_step=%d, steps=%d)', start_step,
                        args.steps)
     profiling = False
-    import contextlib
-    # The elastic step's bit-parity contract requires running WITHOUT
-    # the mesh context (make_elastic_train_step docstring); placements
-    # are carried entirely by the jit shardings either way.
-    loop_ctx = (contextlib.nullcontext() if elastic_ctx is not None
-                else mesh)
-    with loop_ctx:
+    import time
+    # Wall time between logged steps, over the steps in between. The
+    # float(loss) below waits for the step, so with --log-every 1 this
+    # is the step time; the first one includes the compile.
+    last_log = (time.perf_counter(), start_step - 1)
+    with step_ctx():
         for step in range(start_step, args.steps):
             if elastic_ctx is not None and elastic_ctx[2].pending():
                 from skypilot_tpu.train import elastic as elastic_lib
@@ -491,9 +510,12 @@ def main(argv=None) -> int:
                 manager.save(step + 1, state)
             if step % args.log_every == 0 or step == args.steps - 1:
                 loss = float(metrics['loss'])
-                logger.info('step %d/%d loss=%.4f grad_norm=%.3f', step,
-                            args.steps, loss,
-                            float(metrics['grad_norm']))
+                now = time.perf_counter()
+                logger.info('step %d/%d loss=%.4f grad_norm=%.3f '
+                            'step_time=%.3fs', step, args.steps, loss,
+                            float(metrics['grad_norm']),
+                            (now - last_log[0]) / (step - last_log[1]))
+                last_log = (now, step)
             if eval_fn is not None and (
                     (step + 1) % args.eval_every == 0 or
                     step == args.steps - 1):

@@ -121,11 +121,16 @@ def create_sharded_state(
     """
     tc = train_config or TrainConfig()
     model = Transformer(cfg)
+    # The init forward pass exists only to create the params, and its
+    # one-row batch cannot be split over the mesh's batch axes the way
+    # the flash kernel's shard_map asks: attention there is plain XLA.
+    init_model = Transformer(dataclasses.replace(cfg,
+                                                 attention_impl='xla'))
     tx = make_optimizer(tc, lora_only=cfg.lora_rank > 0)
     dummy = jnp.ones((1, min(cfg.max_seq_len, 128)), jnp.int32)
 
     def init_fn(rng_):
-        variables = model.init(rng_, dummy)
+        variables = init_model.init(rng_, dummy)
         return TrainState.create(apply_fn=model.apply,
                                  params=variables['params'], tx=tx)
 
@@ -498,8 +503,8 @@ def compiled_step_collectives(step_fn, state, batch,
                               dp: Optional[int] = None
                               ) -> Dict[str, Any]:
     """Collective-op stats of the COMPILED train step — the training
-    counterpart of the engines' decode_hlo_stats (the BENCH_r03+
-    compile-time proxy while the chip is unreachable).
+    counterpart of the engines' decode_hlo_stats. Counts and shapes,
+    not times.
 
     Lowers and compiles `step_fn` AOT (an honest second compile:
     `.lower().compile()` does NOT reuse the jit dispatch cache — spend
@@ -508,11 +513,15 @@ def compiled_step_collectives(step_fn, state, batch,
     Adds `partition_scatter` — the CPU backend's unfused spelling of
     reduce-scatter (all-reduce + partition-id slice; see
     hlo_probe.partition_scatter_count) — and `reduce_scatter_effective`
-    = native + unfused, the number the ZeRO-1 pins read on any backend.
+    = native + unfused, the number the ZeRO-1 pins read on any backend;
+    and `kernel_operands`, the operand shapes each Pallas TPU kernel in
+    the step was handed (hlo_probe.custom_call_operands: empty off the
+    TPU, the per-device shard under a mesh).
     """
     from skypilot_tpu.parallel import hlo_probe
     text = step_fn.lower(state, batch).compile().as_text()
     stats = hlo_probe.collective_stats(text)
+    stats['kernel_operands'] = hlo_probe.custom_call_operands(text)
     stats['partition_scatter'] = hlo_probe.partition_scatter_count(
         text, shards=dp)
     stats['reduce_scatter_effective'] = (stats['reduce_scatter'] +

@@ -49,7 +49,8 @@ CELLS = [
     # replication note in docs/performance.md), so correctness — the
     # greedy stream vs the single-chip pallas engine — is what the tp
     # cell pins.
-    ('pallas-paged', dict(paged_block_size=8, decode_kernel='pallas')),
+    ('pallas-paged', dict(paged_block_size=8,
+                          decode_kernel='pallas_interpret')),
 ]
 
 

@@ -422,10 +422,11 @@ class TestDeepRingWedgeRecovery:
 
 
 # ---------------------------------------------------------------------
-# Fused pallas decode kernel cells (ISSUE 18): decode_kernel='pallas'
-# across the matrix. On CPU the knob auto-degrades to the Pallas
-# INTERPRETER ('pallas_interpret') — same kernel program, interpreted —
-# which is what makes these cells tier-1. The pin is greedy-token
+# Fused pallas decode kernel cells (ISSUE 18): the kernel across the
+# matrix, asked for as 'pallas_interpret' BY NAME — the same kernel
+# program under the Pallas interpreter, which is what makes these cells
+# tier-1 ('pallas' itself needs the chip and raises without one; the
+# compiled kernel is checked by chip_smoke.py). The pin is greedy-token
 # equivalence to the same-knobs XLA engine via the shared reference
 # streams: streaming softmax reorders reductions, so bit identity of
 # logits is NOT the contract (ops/paged_attention.py docstring);
@@ -449,9 +450,8 @@ class TestPallasDecodeKernel:
                              ids=[c[0] for c in _PALLAS_CELLS])
     def test_cell_matches_xla_stream(self, refs, name, kw):
         ref = refs['int8' if 'int8' in name else '']
-        engine = _engine(decode_kernel='pallas', **kw)
+        engine = _engine(decode_kernel='pallas_interpret', **kw)
         try:
-            # CPU run: 'pallas' resolved to the interpreter twin.
             assert engine.decode_kernel == 'pallas_interpret'
             assert engine.cfg.decode_kernel == 'pallas_interpret'
             got, stats = engine.generate(PROMPT, max_new_tokens=16)
@@ -462,7 +462,7 @@ class TestPallasDecodeKernel:
             engine.stop()
 
     def test_multi_lora_cell_matches_xla_twin(self):
-        """decode_kernel='pallas' also swaps MultiLoRADenseGeneral onto
+        """The kernel knob also swaps MultiLoRADenseGeneral onto
         the fused gather+dot kernel; a mixed base+adapter batch must
         stream identically to the XLA engine sharing its params."""
         import jax.numpy as jnp
@@ -487,7 +487,8 @@ class TestPallasDecodeKernel:
 
         xla = _engine(paged_block_size=8, max_adapters=2, **lora_kw)
         pal = _engine(paged_block_size=8, max_adapters=2,
-                      decode_kernel='pallas', params=xla.params,
+                      decode_kernel='pallas_interpret',
+                      params=xla.params,
                       **lora_kw)
         try:
             for engine in (xla, pal):
@@ -504,7 +505,26 @@ class TestPallasDecodeKernel:
 
     def test_rejects_non_paged_engine(self):
         with pytest.raises(NotImplementedError, match='paged'):
-            _engine(decode_kernel='pallas')
+            _engine(decode_kernel='pallas_interpret')
+
+    def test_pallas_off_tpu_raises(self):
+        """No fallback that hides the device: 'pallas' means the
+        compiled kernel, so without a TPU it refuses, naming the
+        platform and the interpreter's own name, instead of serving
+        from the interpreter under the kernel's name."""
+        with pytest.raises(RuntimeError, match='needs a TPU') as e:
+            _engine(paged_block_size=8, decode_kernel='pallas')
+        assert 'cpu' in str(e.value)
+        assert 'pallas_interpret' in str(e.value)
+
+    def test_pallas_under_tp_is_refused_at_construction(self):
+        """jax does not partition a Mosaic call and the kernel is not
+        yet wrapped over the kv-head axis (ROADMAP S6): a tp engine
+        says so when it is built, not from a failed tick."""
+        from skypilot_tpu.models.inference import _resolve_decode_kernel
+        with pytest.raises(NotImplementedError, match='shard_map'):
+            _resolve_decode_kernel('pallas', _cfg(paged_block_size=8),
+                                   tp=2)
 
     def test_rejects_unknown_kernel(self):
         with pytest.raises(ValueError, match='decode_kernel'):
@@ -515,18 +535,19 @@ class TestPallasDecodeKernel:
         with pytest.raises(NotImplementedError, match='softcap'):
             ContinuousBatchingEngine(
                 _cfg(attn_logit_softcap=30.0), num_slots=2,
-                paged_block_size=8, decode_kernel='pallas')
+                paged_block_size=8, decode_kernel='pallas_interpret')
 
     def test_kernel_probe_eliminates_pool_window_gathers(self, refs):
-        """The compile-time perf proxy (chip unreachable): the fused
-        kernel's compiled decode step must carry strictly FEWER gather
+        """A count, not a speed: the fused kernel's compiled decode
+        step must carry strictly FEWER gather
         ops than the XLA twin's — the pool-window gather
         (`kf[gidx]`/`vf[gidx]`) is what the in-kernel table walk
         deletes. Pinned on 'gather' specifically: interpreter-mode
         emulation adds dynamic-slices on CPU, so 'total' is not
         comparable across kernels."""
         xla = _engine(paged_block_size=8)
-        pal = _engine(paged_block_size=8, decode_kernel='pallas')
+        pal = _engine(paged_block_size=8,
+                      decode_kernel='pallas_interpret')
         try:
             xs = xla.decode_kernel_hlo_stats()
             ps = pal.decode_kernel_hlo_stats()

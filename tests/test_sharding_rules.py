@@ -73,7 +73,6 @@ class TestDecodeRules:
         import inspect
 
         from skypilot_tpu.models import inference
-        from skypilot_tpu.parallel import sharding as sharding_lib
         from skypilot_tpu.train import trainer
         assert 'tree_shardings' in inspect.getsource(trainer)
         assert 'tree_shardings' in inspect.getsource(inference)
@@ -81,7 +80,6 @@ class TestDecodeRules:
         for mod in (trainer, inference):
             assert 'logical_to_mesh_sharding' not in \
                 inspect.getsource(mod), mod.__name__
-        assert sharding_lib.shard_params_sharding is not None  # alias
 
 
 class TestMeshPlumbing:
