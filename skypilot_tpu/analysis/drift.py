@@ -12,8 +12,8 @@ directions, so neither can rot alone.
   docs/observability.md, and every `skytpu_*` name the doc mentions
   is registered somewhere (stale rows are findings too).
 - trace-discipline: every `tracing.span(...)` / `start_span(...)` /
-  `record_span(...)` call site uses a LITERAL name declared in
-  `tracing.KNOWN_SPANS`, every declared span name has a live call
+  `record_span(...)` / `phase(...)` call site uses a LITERAL name
+  declared in `tracing.KNOWN_SPANS`, every declared span name has a live call
   site, and the docs/observability.md span catalog matches the table
   in both directions — span names cannot silently drift out of the
   trace vocabulary `skytpu trace` and the flight recorder render.
@@ -169,22 +169,24 @@ def collect_metrics(tree: ProjectTree) -> Dict[str, Tuple[str, int]]:
 
 
 _TRACING_MODULE = 'tracing'
-_SPAN_FUNCS = ('span', 'start_span', 'record_span')
+_SPAN_FUNCS = ('span', 'start_span', 'record_span', 'phase')
 _KNOWN_SPANS = 'KNOWN_SPANS'
 _DOC_SPAN_SECTION = '### Span catalog'
-_DOC_SPAN_ROW_RE = re.compile(r'^\|\s*`([a-z_]+\.[a-z_]+)`')
+_DOC_SPAN_ROW_RE = re.compile(r'^\|\s*`([a-z_]+(?:\.[a-z_]+)+)`')
 
 
 def collect_span_sites(tree: ProjectTree
                        ) -> List[Tuple[Optional[str], str, int]]:
     """(span name, repo_rel, line) for every tracing.span/start_span/
-    record_span call; name is None when the first argument is not a
-    string literal (a finding — a dynamic name defeats the closed
+    record_span/phase call; name is None when the first argument is not
+    a string literal (a finding — a dynamic name defeats the closed
     vocabulary). Exported for thin test wrappers."""
     out: List[Tuple[Optional[str], str, int]] = []
     for mod in tree.modules.values():
-        if mod.rel.endswith(f'{_TRACING_MODULE}.py'):
-            continue  # the tracer's own internals are not call sites
+        # In the tracer itself a bare call of one of its own span
+        # functions is a site too (its compile listener records
+        # `engine.compile`).
+        own = mod.rel.endswith(f'{_TRACING_MODULE}.py')
         imports = tree.import_map(mod)
         for node in ast.walk(mod.tree):
             if not isinstance(node, ast.Call):
@@ -198,6 +200,8 @@ def collect_span_sites(tree: ProjectTree
                     head = chain.split('.')[0]
                     target = imports.resolve_module(head) or head
                     is_span = target.endswith(_TRACING_MODULE)
+            elif isinstance(func, ast.Name) and own:
+                is_span = func.id in _SPAN_FUNCS
             elif isinstance(func, ast.Name) and \
                     func.id in imports.symbols:
                 prefix, sym = imports.symbols[func.id]

@@ -2007,6 +2007,9 @@ class ContinuousBatchingEngine:
             'generation': self._generation,
             'decode_steps': self._decode_steps,
             'tick_stats': dict(self.tick_stats),
+            # seconds per `engine.tick.*` phase since tracing came on
+            # (empty with tracing off): which phase the wedge sits in
+            'phase_totals': tracing.phase_totals(),
             'active_slots': [i for i, r in enumerate(self._slots)
                              if r is not None],
             'queue_depth': self._queue.qsize(),
@@ -3404,7 +3407,8 @@ class ContinuousBatchingEngine:
                         self._commit_gen(
                             gen, lambda: setattr(self, '_cache', cache))
                         starting = False
-                    self._tick(gen)
+                    with tracing.phase('engine.tick'):
+                        self._tick(gen)
                 except _StaleEngineError:
                     return
                 except Exception as e:  # pylint: disable=broad-except
@@ -3498,16 +3502,12 @@ class ContinuousBatchingEngine:
                     self._heartbeat = time_lib.monotonic()
                     self._warm_tick = True
 
-    def _tick(self, gen: int) -> None:
-        self._check_gen(gen)
-        # Snapshot the slot table AND the queue: every read/write in
-        # this tick goes to THESE objects. If the watchdog abandons the
-        # thread mid-tick it swaps both for fresh ones, so a stale
-        # thread resuming here mutates only its own abandoned state —
-        # it can neither corrupt the successor's slots nor steal
-        # requests from the successor's queue.
-        slots = self._slots
-        queue = self._queue
+    def _housekeep(self, slots, queue, gen: int) -> Tuple[float, float]:
+        """The tick's chores before admission (`engine.tick.housekeep`):
+        engine-thread work, ingest expiry, the deadline and cancellation
+        scans of slots and queue, SLO preemption. Returns the wall and
+        monotonic instants the deadlines were judged at, which the
+        admission loop judges by too."""
         # Engine-thread work (handoff gathers, ingest finalizes) runs
         # FIRST: these items need the pool tree while no dispatch is in
         # flight, and a decode-tier replica must finalize an ingest
@@ -3619,6 +3619,11 @@ class ContinuousBatchingEngine:
                                    'tokens_done': len(req.tokens)})
                     queue.requeue_front(req)
                     need -= 1
+        return now, mono_now
+
+    def _admit_waiting(self, slots, queue, gen: int, now: float,
+                       mono_now: float) -> None:
+        """The admission loop (`engine.tick.admit`)."""
         # Admit new requests into free slots (between ticks — this is
         # the "continuous" in continuous batching). Requests that
         # expired or were cancelled while queued are dropped, not
@@ -3672,6 +3677,24 @@ class ContinuousBatchingEngine:
                             'request aborted')
                         if isinstance(e, _StaleEngineError) else e)
                     raise
+
+    def _tick(self, gen: int) -> None:
+        self._check_gen(gen)
+        # Snapshot the slot table AND the queue: every read/write in
+        # this tick goes to THESE objects. If the watchdog abandons the
+        # thread mid-tick it swaps both for fresh ones, so a stale
+        # thread resuming here mutates only its own abandoned state —
+        # it can neither corrupt the successor's slots nor steal
+        # requests from the successor's queue.
+        slots = self._slots
+        queue = self._queue
+        # Each stretch of the tick sits in exactly one `engine.tick.*`
+        # phase (docs/observability.md), so what no phase covers is the
+        # tick's self time. Tracing off, a phase is one boolean test.
+        with tracing.phase('engine.tick.housekeep'):
+            now, mono_now = self._housekeep(slots, queue, gen)
+        with tracing.phase('engine.tick.admit'):
+            self._admit_waiting(slots, queue, gen, now, mono_now)
         # Chunked prefill (paged mode): every mid-prefill slot advances
         # ONE fixed-shape chunk, then the decode below still runs for
         # the slots already past prefill — the interleaving that keeps
@@ -3682,7 +3705,8 @@ class ContinuousBatchingEngine:
                       if r is not None and r.prefilling]
         if prefilling:
             self._admitting_tick = True
-            self._prefill_tick(slots, prefilling, gen)
+            with tracing.phase('engine.tick.prefill'):
+                self._prefill_tick(slots, prefilling, gen)
             prefilling = [i for i, r in enumerate(slots)
                           if r is not None and r.prefilling]
         # Admission (and its possible compile) is over; refresh the
@@ -3763,7 +3787,8 @@ class ContinuousBatchingEngine:
                 # columns so nothing dangles, discarding by identity.
                 self._flush_ring(slots, gen)
             elif not prefilling:
-                self._wake.wait(timeout=0.05)
+                with tracing.phase('engine.tick.wait'):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
             _DISPATCH_AHEAD.set(0)
             self._last_ready = None
@@ -3786,13 +3811,15 @@ class ContinuousBatchingEngine:
                 active = [i for i in active if slots[i] is not None]
                 if not active:
                     return
-            spec = self._spec_tick(slots, active, gen)
+            with tracing.phase('engine.tick.dispatch'):
+                spec = self._spec_tick(slots, active, gen)
             if spec is not None:
                 out, valid = spec
                 self._decode_steps += 1
                 self.step_log.append((self._decode_steps,
                                       frozenset(active)))
-                self._emit(slots, active, out, valid)
+                with tracing.phase('engine.tick.emit'):
+                    self._emit(slots, active, out, valid)
                 if self.paged_block_size:
                     # Rejected drafts: hand the over-reserved tail
                     # blocks back instead of holding them to
@@ -3828,10 +3855,11 @@ class ContinuousBatchingEngine:
                 # admission scan) overlaps its compute. _can_chain is
                 # re-checked per added dispatch: the pending horizon
                 # grows with each one.
-                while (len(ring) <= self.async_depth and
-                       self._can_chain(slots, active, k)):
-                    self._dispatch(slots, active, k, gen,
-                                   chain=ring[-1])
+                with tracing.phase('engine.tick.dispatch'):
+                    while (len(ring) <= self.async_depth and
+                           self._can_chain(slots, active, k)):
+                        self._dispatch(slots, active, k, gen,
+                                       chain=ring[-1])
                 self._consume_oldest(slots, gen)
                 _DISPATCH_AHEAD.set(len(ring))
                 return
@@ -3850,18 +3878,21 @@ class ContinuousBatchingEngine:
                     self.cfg.max_seq_len - slots[i].next_pos >= k
                     for i in active):
                 k = 1
-        out_dev = self._dispatch(slots, active, k, gen)
-        if self.async_depth:
+        with tracing.phase('engine.tick.dispatch'):
+            out_dev = self._dispatch(slots, active, k, gen)
             # Pipeline fill: chain straight up to depth — these
             # dispatches are consumed (and emitted) up to async_depth
             # ticks late; the oldest's host copy is already in flight.
             while (len(ring) < self.async_depth and
                    self._can_chain(slots, active, k)):
                 self._dispatch(slots, active, k, gen, chain=ring[-1])
+        if self.async_depth:
             return
-        out_cols = _land(out_dev)
+        with tracing.phase('engine.tick.land'):
+            out_cols = _land(out_dev)
         self._last_ready = time_lib.monotonic()
-        self._emit(slots, active, out_cols, None)
+        with tracing.phase('engine.tick.emit'):
+            self._emit(slots, active, out_cols, None)
 
     def _dispatch(self, slots, active, k, gen,
                   chain: 'Optional[_Inflight]' = None):
@@ -4021,15 +4052,17 @@ class ContinuousBatchingEngine:
         finishes while deeper entries are still pending sheds their
         columns the same way, up to async_depth steps late."""
         infl = self._ring.popleft()
-        out_cols = _land(infl.out)   # waits on the copy the dispatch
-                                     # already started async
+        with tracing.phase('engine.tick.land'):
+            out_cols = _land(infl.out)   # waits on the copy the
+                                         # dispatch already started async
         self._last_ready = time_lib.monotonic()
         # The wait above may span a watchdog recovery: never emit into
         # a successor's world.
         self._check_gen(gen)
         live = [i for i in infl.active if slots[i] is infl.reqs[i]]
         if live:
-            self._emit(slots, live, out_cols, None)
+            with tracing.phase('engine.tick.emit'):
+                self._emit(slots, live, out_cols, None)
 
     def _flush_ring(self, slots, gen: int) -> None:
         """Drain the whole pipeline oldest-first (churn, spec ticks,

@@ -39,8 +39,8 @@ continues it; ``pack_kv_chunk`` carries it inside the chunk header so
 the decode replica's ingest spans join the same trace.
 
 Span names are a CLOSED vocabulary: every ``span(...)`` /
-``start_span(...)`` / ``record_span(...)`` call site must use a
-literal name registered in ``KNOWN_SPANS`` and cataloged in
+``start_span(...)`` / ``record_span(...)`` / ``phase(...)`` call site
+must use a literal name registered in ``KNOWN_SPANS`` and cataloged in
 docs/observability.md — skylint's ``trace-discipline`` checker holds
 both directions (the KNOWN_POINTS drift-lint pattern).
 """
@@ -50,6 +50,7 @@ import collections
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -89,6 +90,18 @@ KNOWN_SPANS = (
     'engine.preempt_export',  # preemption-notice prefix export
     'engine.adapter_load',   # adapter made resident (tick thread, slot attr)
     'engine.slot_preempt',   # batch slot yielded to an interactive arrival
+    'engine.compile',        # one XLA backend compile while tracing is on
+    # Tick phases of the engine's thread (`phase()`: profiler annotation
+    # + per-name totals, never a ring entry). The children tile _tick:
+    # what none covers is the tick's self time.
+    'engine.tick',            # one whole _tick
+    'engine.tick.housekeep',  # engine work, ingest expiry, deadline scans
+    'engine.tick.admit',      # admission loop (blocks, CoW, prefix lookup)
+    'engine.tick.prefill',    # _prefill_tick: one chunk per prefilling slot
+    'engine.tick.dispatch',   # decode launch (_dispatch / _spec_tick)
+    'engine.tick.land',       # host waiting for the device's answer
+    'engine.tick.emit',       # tokens appended, callbacks, finishes
+    'engine.tick.wait',       # idle engine sleeping on its wake event
 )
 
 # Tracing metrics (docs/observability.md).
@@ -111,6 +124,7 @@ def enable() -> None:
     global _enabled, _anchor
     if _anchor is None:
         _anchor = (time.time(), time.monotonic())
+    _bind_jax()
     _enabled = True
 
 
@@ -185,9 +199,11 @@ def snapshot(window_s: Optional[float] = None) -> List[dict]:
 
 
 def reset() -> None:
-    """Drop every recorded span (tests only)."""
+    """Drop every recorded span and phase total (tests only)."""
     with _ring_lock:
         _ring.clear()
+    with _phase_lock:
+        _phase_totals.clear()
 
 
 # ---------------------------------------------------------------------
@@ -418,6 +434,96 @@ def record_span(name: str, start_mono: float, end_mono: float,
         'attrs': dict(attrs) if attrs else {},
     })
     return ctx
+
+
+# ---------------------------------------------------------------------
+# tick phases + compile spans (what tracing takes from jax)
+# ---------------------------------------------------------------------
+
+COMPILE_EVENT = '/jax/core/compile/backend_compile_duration'
+
+# jax.profiler.TraceAnnotation once bound (tests put a recorder here).
+_annotation = None
+_jax_bound = False
+
+_phase_totals: Dict[str, list] = {}   # name -> [count, seconds]
+_phase_lock = threading.Lock()
+
+
+def _bind_jax() -> None:
+    """Take `TraceAnnotation` and register the compile listener, once,
+    and only in a process that has imported jax already: this module
+    stays importable (and enable-able) without it. Called at `enable()`
+    and again from the first phase, which runs on the engine's thread
+    where jax is certainly loaded."""
+    global _annotation, _jax_bound
+    with _phase_lock:   # enable() and the engine's thread may race here
+        if _jax_bound or 'jax' not in sys.modules:
+            return
+        _jax_bound = True
+    import jax.monitoring
+    import jax.profiler
+    if _annotation is None:
+        _annotation = jax.profiler.TraceAnnotation
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _on_jax_duration(event: str, duration: float, **_kw: Any) -> None:
+    """One `engine.compile` ring span per XLA backend compile while
+    tracing is on: a compile inside a serving window is a stall that
+    no request span explains."""
+    if _enabled and event == COMPILE_EVENT:
+        end = _now()
+        record_span('engine.compile', end - duration, end,
+                    attrs={'seconds': round(duration, 6)})
+
+
+class _Phase:
+    """One live tick phase: a profiler annotation, so that under a
+    profiler session the span sits in the host plane on the clock the
+    device's events are on, and a duration added to the per-name
+    totals. Never a ring entry: seven phases at 25 ticks/s would roll
+    the ring over inside a minute and drop the request spans."""
+
+    __slots__ = ('name', '_start', '_ann')
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+
+    def __enter__(self) -> '_Phase':
+        if not _jax_bound:
+            _bind_jax()
+        self._ann = _annotation(self.name) if _annotation else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._start = _now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        seconds = _now() - self._start
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        with _phase_lock:
+            total = _phase_totals.setdefault(self.name, [0, 0.0])
+            total[0] += 1
+            total[1] += seconds
+
+
+def phase(name: str):
+    """A lexical span for a stretch of the engine's tick (context
+    manager; names are the `engine.tick*` entries of KNOWN_SPANS).
+    Disabled tracing returns the shared no-op handle."""
+    if not _enabled:
+        return NULL_SPAN
+    return _Phase(name)
+
+
+def phase_totals() -> Dict[str, Dict[str, float]]:
+    """{phase name: {'count', 'seconds'}} since the process started
+    (or `reset()`): where the engine's thread spent its time."""
+    with _phase_lock:
+        return {name: {'count': c, 'seconds': s}
+                for name, (c, s) in _phase_totals.items()}
 
 
 # ---------------------------------------------------------------------

@@ -279,6 +279,204 @@ class TestEngineSpans:
 
 
 # ---------------------------------------------------------------------
+# tick phases (`tracing.phase`) and compile spans
+# ---------------------------------------------------------------------
+
+PHASE_CHILDREN = ('housekeep', 'admit', 'prefill', 'dispatch', 'land',
+                  'emit', 'wait')
+
+
+@pytest.fixture(scope='class')
+def phase_run(paged_engine):
+    """One generation on the warmed paged engine with `TraceAnnotation`
+    replaced by a recorder, then the engine left to idle until it has
+    slept once. Yields (events, totals, ring): events are (name,
+    start_ns, end_ns, thread) in order of exit."""
+    events = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            self.start = time.perf_counter_ns()
+            return self
+
+        def __exit__(self, *exc):
+            events.append((self.name, self.start, time.perf_counter_ns(),
+                           threading.get_ident()))
+
+    # enable() first: it binds jax's own TraceAnnotation, so that what
+    # this fixture restores is the real one and not "unbound".
+    tracing.enable()
+    tracing.reset()
+    real, tracing._annotation = tracing._annotation, Recorder
+    try:
+        out, _ = paged_engine.generate(list(range(1, 21)),
+                                       max_new_tokens=4, timeout=300)
+        assert len(out) == 4
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline and not any(
+                e[0] == 'engine.tick.wait' for e in list(events)):
+            time.sleep(0.02)
+        totals, ring = tracing.phase_totals(), tracing.snapshot()
+    finally:
+        tracing._annotation = real
+        tracing.disable()
+    yield list(events), totals, ring
+
+
+def _whole_ticks(events):
+    """(ticks, children): the recorded `engine.tick` spans, and the
+    child spans that lie between the first one's start and the last
+    one's end (a tick under way when recording began or ended has
+    children and no recorded parent)."""
+    ticks = [e for e in events if e[0] == 'engine.tick']
+    assert ticks
+    first, last = min(t[1] for t in ticks), max(t[2] for t in ticks)
+    children = [e for e in events if e[0].startswith('engine.tick.')
+                and e[1] >= first and e[2] <= last]
+    return ticks, children
+
+
+class TestTickPhases:
+
+    @pytest.mark.parametrize('child', PHASE_CHILDREN)
+    def test_child_phase_sits_inside_a_tick(self, phase_run, child):
+        ticks, children = _whole_ticks(phase_run[0])
+        mine = [e for e in children if e[0] == f'engine.tick.{child}']
+        assert mine, f'no engine.tick.{child} recorded'
+        for _, start, end, thread in mine:
+            assert any(t[1] <= start and end <= t[2] and t[3] == thread
+                       for t in ticks)
+
+    def test_children_tile_a_tick_without_overlap(self, phase_run):
+        _, children = _whole_ticks(phase_run[0])
+        spans = sorted((e[1], e[2], e[0]) for e in children)
+        for (_, end, a), (start, _, b) in zip(spans, spans[1:]):
+            assert end <= start, f'{a} overlaps {b}'
+        # one thread only: the names are the engine thread's own
+        assert len({e[3] for e in children}) == 1
+
+    def test_land_comes_before_emit_in_a_tick(self, phase_run):
+        ticks, children = _whole_ticks(phase_run[0])
+        decoded = 0
+        for _, t0, t1, _ in ticks:
+            inside = sorted((e[1], e[0]) for e in children
+                            if t0 <= e[1] and e[2] <= t1)
+            names = [n.rsplit('.', 1)[1] for _, n in inside]
+            if 'emit' not in names:
+                continue
+            decoded += 1
+            assert names.index('dispatch') < names.index('land') < \
+                names.index('emit')
+        assert decoded >= 3     # four tokens: one sampled by prefill
+
+    def test_totals_hold_every_phase_and_the_ring_none(self, phase_run):
+        _, totals, ring = phase_run
+        for name in ('engine.tick',) + tuple(
+                f'engine.tick.{c}' for c in PHASE_CHILDREN):
+            assert name in tracing.KNOWN_SPANS
+            assert totals[name]['count'] >= 1
+            assert totals[name]['seconds'] >= 0
+        # each child's seconds lie inside the ticks'
+        assert sum(v['seconds'] for k, v in totals.items()
+                   if k != 'engine.tick') <= \
+            totals['engine.tick']['seconds'] + 0.1
+        assert not [s for s in ring
+                    if s['name'].startswith('engine.tick')]
+
+    def test_disabled_phase_is_the_noop_and_totals_stay_empty(
+            self, paged_engine):
+        assert tracing.phase('engine.tick') is tracing.NULL_SPAN
+        # a tick the idle engine entered while an earlier test had
+        # tracing on still lands its totals when its 50 ms wait ends
+        time.sleep(0.15)
+        tracing.reset()
+        paged_engine.generate([9, 8, 7], max_new_tokens=2, timeout=300)
+        assert tracing.phase_totals() == {}
+
+    def test_flight_extra_carries_the_phase_totals(self, paged_engine):
+        tracing.enable()
+        paged_engine.generate([3, 2, 1], max_new_tokens=2, timeout=300)
+        extra = paged_engine._flight_extra('test')
+        assert extra['phase_totals']['engine.tick.dispatch']['count'] >= 1
+        json.dumps(extra)       # a flight record is JSON
+        lines = tracing.render_flight_record(
+            {'trigger': 'x', 'extra': extra, 'spans': []})
+        assert any('phase_totals' in line for line in lines)
+
+    def test_phase_lands_in_the_profilers_host_plane(self, paged_engine,
+                                                     tmp_path):
+        """The real TraceAnnotation under a real profiler session, on
+        the CPU: the phase is an event of a host plane of the
+        .xplane.pb, where the device's events would be beside it."""
+        import glob
+        import jax
+        from jax.profiler import ProfileData
+        tracing.enable()
+        with jax.profiler.trace(str(tmp_path)):
+            paged_engine.generate([5, 4, 3, 2], max_new_tokens=3,
+                                  timeout=300)
+        (path,) = glob.glob(str(tmp_path / 'plugins' / 'profile' / '*'
+                                / '*.xplane.pb'))
+        found = {}
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith('/host:'):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith('engine.tick'):
+                        found[ev.name] = found.get(ev.name, 0) + 1
+                        assert ev.duration_ns > 0
+        assert found.get('engine.tick.dispatch', 0) >= 2, found
+        assert 'engine.tick' in found and 'engine.tick.land' in found
+
+    def test_tracing_stays_free_of_jax_until_jax_is_there(self):
+        """enable() and a phase in a process that never imported jax:
+        nothing imports it (the LB and the controller import this
+        module), the phase still counts."""
+        import subprocess
+        import sys
+        code = (
+            'import sys\n'
+            'from skypilot_tpu.observability import tracing\n'
+            'tracing.enable()\n'
+            'with tracing.phase("engine.tick"):\n'
+            '    pass\n'
+            'assert tracing.phase_totals()["engine.tick"]["count"] == 1\n'
+            'assert "jax" not in sys.modules, "jax was imported"\n'
+            'print("CLEAN")\n')
+        out = subprocess.run(
+            [sys.executable, '-c', code], capture_output=True, text=True,
+            timeout=300,
+            cwd=os.path.dirname(os.path.dirname(__file__)))
+        assert out.returncode == 0, out.stderr
+        assert 'CLEAN' in out.stdout
+
+
+class TestCompileSpans:
+
+    def test_a_compile_is_one_ring_span_only_while_tracing_is_on(self):
+        import jax
+        import jax.numpy as jnp
+        arg = jax.ShapeDtypeStruct((7,), jnp.float32)
+        tracing.enable()
+        tracing.reset()
+        jax.jit(lambda x: x * 3.25 + 1).lower(arg).compile()
+        spans = [s for s in tracing.snapshot()
+                 if s['name'] == 'engine.compile']
+        assert len(spans) == 1
+        assert spans[0]['attrs']['seconds'] > 0
+        assert spans[0]['dur_us'] == pytest.approx(
+            spans[0]['attrs']['seconds'] * 1e6, rel=1e-3)
+        tracing.disable()
+        tracing.reset()
+        jax.jit(lambda x: x * 4.5 - 2).lower(arg).compile()
+        assert tracing.snapshot() == []
+
+
+# ---------------------------------------------------------------------
 # handoff chunk context propagation (unit level; the live-HTTP 2-hop
 # round trip is tests/test_chaos.py::TestDisaggHandoff)
 # ---------------------------------------------------------------------
@@ -313,7 +511,9 @@ class TestChunkTracePropagation:
         for chunk in chunks:
             result = dec.ingest_chunk(chunk)
         assert result['final'] and result['imported_blocks'] == 3
-        spans = tracing.snapshot()
+        # (a first-time compile on the way is a span of its own trace)
+        spans = [s for s in tracing.snapshot()
+                 if s['name'] != 'engine.compile']
         names = [s['name'] for s in spans]
         assert names.count('engine.ingest_chunk') == 3
         assert names.count('engine.ingest_publish') == 1
@@ -596,6 +796,41 @@ class TestTraceDisciplineChecker:
                    for m in messages)
         # 'engine.known' is clean: literal, registered, has a site.
         assert not any("'engine.known'" in m for m in messages)
+
+    def test_phase_sites_and_the_tracers_own_sites_count(self, tmp_path):
+        """`phase(` is a call site like `span(`, a dotted phase name
+        is read from the doc catalog whole, and a span the tracer
+        records itself (its compile listener) is a live site."""
+        from skypilot_tpu.analysis import drift
+        from skypilot_tpu.analysis.core import ProjectTree
+        root = tmp_path / 'fixpkg'
+        root.mkdir()
+        (root / '__init__.py').write_text('')
+        (root / 'tracing.py').write_text(
+            "KNOWN_SPANS = ('engine.tick', 'engine.tick.emit',\n"
+            "               'engine.compile')\n"
+            'def phase(name):\n    return None\n'
+            'def record_span(name, start, end):\n    return None\n'
+            'def listener():\n'
+            "    record_span('engine.compile', 0.0, 1.0)\n")
+        (root / 'user.py').write_text(
+            'from fixpkg import tracing\n'
+            'def f():\n'
+            "    with tracing.phase('engine.tick'):\n"
+            "        with tracing.phase('engine.tick.emit'):\n"
+            "            tracing.phase('engine.tick.nowhere')\n")
+        tree = ProjectTree(str(root))
+        sites = {name for name, _, _ in drift.collect_span_sites(tree)}
+        assert sites == {'engine.tick', 'engine.tick.emit',
+                         'engine.tick.nowhere', 'engine.compile'}
+        messages = [f.message
+                    for f in drift.TraceDisciplineChecker().run(tree)]
+        assert len(messages) == 1 and 'engine.tick.nowhere' in messages[0]
+        assert drift._DOC_SPAN_ROW_RE.match(
+            '| `engine.tick.emit` | engine |').group(1) == \
+            'engine.tick.emit'
+        assert drift._DOC_SPAN_ROW_RE.match(
+            '| `lb.route` | LB |').group(1) == 'lb.route'
 
     def test_no_tracing_module_skips(self, tmp_path):
         from skypilot_tpu.analysis import drift
