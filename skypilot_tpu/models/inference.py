@@ -102,6 +102,10 @@ _PAGED_COW = obs.counter(
 _CHUNKED_PREFILL = obs.counter(
     'skytpu_engine_chunked_prefill_ticks_total',
     'Prefill chunks processed (interleaved between decode ticks)')
+_CHUNKED_PREFILL_TOKENS = obs.counter(
+    'skytpu_engine_chunked_prefill_tokens_total',
+    'Real prompt tokens dispatched in prefill chunks (pads excluded): '
+    'over chunks x prefill_chunk it is how full the chunks run')
 _PAGED_INT8_SAVED = obs.gauge(
     'skytpu_engine_paged_int8_bytes_saved',
     'HBM bytes the int8-quantized paged pool saves vs the same pool '
@@ -397,6 +401,35 @@ def _tree_bytes(tree) -> Tuple[int, int]:
             per_dev += (math.prod(sharding.shard_shape(leaf.shape))
                         * leaf.dtype.itemsize)
     return total, per_dev
+
+
+# Prompt tokens a prefill chunk carries for each byte of a weight (see
+# default_prefill_chunk; the sweep behind it is in PERF.md section 6).
+_PREFILL_TOKENS_PER_WEIGHT_BYTE = 128
+
+
+def default_prefill_chunk(block_size: int, max_seq_len: int,
+                          weight_bytes: int = 2) -> int:
+    """Width of the engine's one fixed (1, width) prefill chunk when
+    the caller gives none: 128 tokens a weight byte (256 for bf16
+    weights, 128 for int8), held to a whole number of KV blocks (at
+    least one) and to the context.
+
+    A chunk program streams every layer's weights once whatever its
+    width, so a narrow chunk pays a whole pass for a few rows of
+    matmul. A weight does 2 FLOPs a token, so the pass's compute
+    catches up with its read at ridge x weight_bytes / 2 tokens (v5e:
+    240 FLOPs a byte, so 240 tokens in bf16 and 120 in int8). Measured
+    there at 7B widths and half depth (PERF.md section 6): a 256-token
+    bf16 chunk takes 1.15x a 16-token one and less than one decode step
+    of the same engine, so a tick that carries a chunk at most doubles;
+    a 512-token one takes 1.7x and more than a decode step, and both
+    serving cells complete fewer tokens a second with it. With int8
+    weights a 256-token chunk outgrows the decode step on one of the
+    two configurations (1.24x) and a 128-token one on neither."""
+    width = min(_PREFILL_TOKENS_PER_WEIGHT_BYTE * weight_bytes,
+                max_seq_len)
+    return max(block_size, width // block_size * block_size)
 
 
 def infer_serving_tp(cfg: ModelConfig, n_devices: int) -> int:
@@ -1109,15 +1142,22 @@ class ContinuousBatchingEngine:
                 paged_num_blocks=nb)
             self._pool: 'Optional[kv_cache_lib.BlockPool]' = \
                 kv_cache_lib.BlockPool(nb, self.paged_block_size)
+            # One fixed (1, width) prefill shape per engine. 0 = the
+            # engine's rule (default_prefill_chunk): as many prompt
+            # tokens as one pass over the weights carries for free.
             self.prefill_chunk = max(1, prefill_chunk or
-                                     self.paged_block_size)
+                                     default_prefill_chunk(
+                                         self.paged_block_size,
+                                         self.cfg.max_seq_len,
+                                         1 if quantize == 'int8' else 2))
             _PAGED_CAPACITY.set(nb)
         else:
             self._blocks_per_seq = 0
             self._pool = None
             self.prefill_chunk = 0
         self.paged_stats = {'cow_copies': 0, 'blocks_reused': 0,
-                            'prefill_chunks': 0, 'prefix_evictions': 0,
+                            'prefill_chunks': 0, 'prefill_tokens': 0,
+                            'prefix_evictions': 0,
                             'spec_trimmed_blocks': 0}
         # -------- fused decode kernel (docs/performance.md) --------
         # decode_kernel='pallas' routes paged attention (and, on
@@ -1579,18 +1619,21 @@ class ContinuousBatchingEngine:
         engine — vs one per power-of-two prompt bucket on the contiguous
         path (pinned by tests/test_paged_cache.py). Returns (logits at
         chunk token true_n-1 — only meaningful on the final chunk — and
-        the updated pool). Pad-token writes land in private blocks that
-        later real writes overwrite, or clip into the table's scratch
-        column (same stale-entry masking argument as _prefill_impl)."""
+        the updated pool); only that row goes through the unembedding
+        (head_rows): the (width, vocab) logits array and its slice
+        measured 6-15% of the chunk program on the v5e. Pad-token
+        writes land in the request's last private block, where later
+        real writes overwrite them, in the scratch block behind
+        unmapped table entries, or clip into the table's scratch column
+        (same stale-entry masking argument as _prefill_impl)."""
         positions = start + jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
             tokens.shape)
         logits, mutated = self.model.apply(
             self._variables(params, cache, adapters), tokens, positions,
-            block_tables=tables, adapter_ids=aids, mutable=['cache'])
-        last = jax.lax.dynamic_index_in_dim(logits, true_n - 1, axis=1,
-                                            keepdims=False)
-        return last[0], nn.unbox(mutated['cache'])
+            block_tables=tables, adapter_ids=aids,
+            head_rows=jnp.reshape(true_n - 1, (1,)), mutable=['cache'])
+        return logits[0, 0], nn.unbox(mutated['cache'])
 
     def _cow_copy_impl(self, cache, src, dst):
         """Copy-on-write: clone physical block `src` into `dst` across
@@ -2292,9 +2335,10 @@ class ContinuousBatchingEngine:
             start = req.prefill_pos
             n = min(self.prefill_chunk, total - start)
             try:
-                self._ensure_blocks(
-                    req, min(start + self.prefill_chunk,
-                             self.cfg.max_seq_len))
+                # Blocks for the REAL tokens only: the chunk's pad
+                # positions fall on unmapped table entries, which
+                # point at the scratch block (_table_array).
+                self._ensure_blocks(req, start + n)
             except kv_cache_lib.PoolExhaustedError:
                 slots[slot] = None
                 self._release_blocks(req)
@@ -2315,7 +2359,9 @@ class ContinuousBatchingEngine:
                              lambda: setattr(self, '_cache', pool_arr))
             req.prefill_pos = start + n
             self.paged_stats['prefill_chunks'] += 1
+            self.paged_stats['prefill_tokens'] += n
             _CHUNKED_PREFILL.inc()
+            _CHUNKED_PREFILL_TOKENS.inc(n)
             self.step_log.append(('prefill', frozenset([slot])))
             if req.prefill_pos >= total:
                 req.prefilling = False

@@ -939,13 +939,18 @@ class Transformer(nn.Module):
                  positions: Optional[jax.Array] = None,
                  mode: str = 'full',
                  block_tables: Optional[jax.Array] = None,
-                 adapter_ids: Optional[jax.Array] = None) -> jax.Array:
+                 adapter_ids: Optional[jax.Array] = None,
+                 head_rows: Optional[jax.Array] = None) -> jax.Array:
         """mode: 'full' (tokens → logits, the normal path), or the two
         halves the pipeline executor (parallel/pipeline.py) sandwiches
         around its microbatched layer schedule — 'embed' (tokens →
         (hidden, positions), stops before the layer stack) and 'head'
         (`tokens` IS the hidden state [B,T,D]; final norm + unembed).
-        All modes share one param tree; init uses 'full'."""
+        All modes share one param tree; init uses 'full'.
+
+        head_rows (B,) int32: unembed only row head_rows[b] of each
+        sequence, giving (B, 1, V) logits — a prefill chunk needs one
+        row's logits, not T x V of them."""
         cfg = self.cfg
         # Tied models reuse this table as the unembed projection: init at
         # d^-1/2 so step-0 logits land at O(1) (and the Gemma sqrt(d)
@@ -1008,6 +1013,8 @@ class Transformer(nn.Module):
                                                        block_tables,
                                                        adapter_ids)
 
+        if head_rows is not None:
+            x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
         return self._head(embed, x)
 
     def _head(self, embed: nn.Embed, x: jax.Array) -> jax.Array:

@@ -1717,10 +1717,14 @@ def main(argv=None) -> int:
                              '(num_slots + prefix_cache) x max_seq_len '
                              '/ block_size + 1)')
     parser.add_argument('--prefill-chunk', type=int, default=0,
-                        help='paged mode: prompt tokens prefilled per '
-                             'tick — ONE compiled prefill shape, long '
-                             'prompts interleave with decode (default: '
-                             'block size)')
+                        help='paged mode: prompt tokens one prefill '
+                             'dispatch carries — ONE compiled prefill '
+                             'shape, long prompts interleave with decode '
+                             '(default 0: the engine\'s rule, what one '
+                             'pass over the weights carries before the '
+                             'chunk outgrows a decode step — 256 tokens '
+                             'in bf16, 128 with int8 weights, in whole '
+                             'blocks, at most the context)')
     parser.add_argument('--async-depth', type=int, default=0,
                         help='async decode pipeline: a ring of N '
                              'in-flight decode dispatches, each '

@@ -176,8 +176,10 @@ class TestAsyncPaged:
 
     @pytest.fixture(scope='class')
     def paged_pair(self):
-        s = _engine(paged_block_size=8)
-        a = _engine(paged_block_size=8, async_depth=1)
+        # One-block chunks, named: the default width would swallow the
+        # 40-token prompt of the interleaving test in one chunk.
+        s = _engine(paged_block_size=8, prefill_chunk=8)
+        a = _engine(paged_block_size=8, prefill_chunk=8, async_depth=1)
         yield s, a
         s.stop()
         a.stop()

@@ -16,9 +16,9 @@ from skypilot_tpu.models.kv_cache import (BlockPool, PoolExhaustedError,
 def _cfg(**kw):
     from skypilot_tpu.models import get_config
     cfg = get_config('test-tiny')
+    kw.setdefault('max_seq_len', 64)
     return dataclasses.replace(cfg, dtype='float32',
-                               param_dtype='float32', max_seq_len=64,
-                               remat=False, **kw)
+                               param_dtype='float32', remat=False, **kw)
 
 
 # ---------------------------------------------------------------------
@@ -318,12 +318,23 @@ class TestChunkedPrefill:
         assert bucket_compiles == 3
         assert paged_compiles == 1
 
-    def test_decode_ticks_interleave_with_long_prompt_chunks(
-            self, paged_engine):
+    def test_decode_ticks_interleave_with_long_prompt_chunks(self):
         """step_log interleaving: while a long prompt prefills chunk by
-        chunk (prefill_chunk defaults to block_size=8, so 40 tokens → 5
+        chunk (an explicit narrow prefill_chunk=8 — the default width
+        would swallow this tiny context whole — so 40 tokens → 5
         chunks), the in-flight slot keeps emitting decode ticks BETWEEN
         chunks — the TPOT-stall chunked prefill exists to remove."""
+        from skypilot_tpu.models.inference import ContinuousBatchingEngine
+        paged_engine = ContinuousBatchingEngine(_cfg(), num_slots=2,
+                                                paged_block_size=8,
+                                                prefill_chunk=8)
+        try:
+            self._check_interleaving(paged_engine)
+        finally:
+            paged_engine.stop()
+
+    @staticmethod
+    def _check_interleaving(paged_engine):
         marker = len(paged_engine.step_log)
         short_fut = paged_engine.submit([9, 9], max_new_tokens=40)
         deadline = time.time() + 30
@@ -454,6 +465,186 @@ class TestChunkedPrefill:
             engine._pool.check()  # pylint: disable=protected-access
         finally:
             engine.stop()
+
+
+# ---------------------------------------------------------------------
+# The default chunk width: one pass over the weights carries as many
+# prompt tokens as it can (default_prefill_chunk), not one KV block.
+# ---------------------------------------------------------------------
+
+
+@pytest.mark.parametrize('block,context,weight_bytes,want', [
+    (16, 768, 2, 256),   # the benchmark's serving cells
+    (16, 896, 2, 256),
+    (16, 4096, 2, 256),  # the context does not widen it
+    (16, 768, 1, 128),   # int8 weights: half the bytes, half the ridge
+    (8, 64, 2, 64),      # a context under the target: the whole of it
+    (16, 128, 2, 128),
+    (24, 240, 2, 240),   # a whole number of blocks, rounded down
+    (48, 480, 2, 240),
+    (512, 1024, 2, 512),  # a block above the target: one block
+])
+def test_default_prefill_chunk_follows_the_rule(block, context,
+                                                weight_bytes, want):
+    from skypilot_tpu.models.inference import default_prefill_chunk
+    width = default_prefill_chunk(block, context, weight_bytes)
+    assert width == want
+    assert width % block == 0 and block <= width <= context
+
+
+WIDE_CTX = 512           # default width 256 < context: prompts can span
+
+
+@pytest.fixture(scope='module')
+def wide_engine():
+    """Paged engine at the DEFAULT chunk width on a context wide enough
+    that prompts fall short of, on and past the width."""
+    from skypilot_tpu.models.inference import ContinuousBatchingEngine
+    engine = ContinuousBatchingEngine(_cfg(max_seq_len=WIDE_CTX),
+                                      num_slots=2, paged_block_size=8)
+    yield engine
+    engine.stop()
+
+
+@pytest.fixture(scope='module')
+def narrow_engine():
+    """The same engine with the old default: one block a chunk."""
+    from skypilot_tpu.models.inference import ContinuousBatchingEngine
+    engine = ContinuousBatchingEngine(_cfg(max_seq_len=WIDE_CTX),
+                                      num_slots=2, paged_block_size=8,
+                                      prefill_chunk=8)
+    yield engine
+    engine.stop()
+
+
+def _prompt(n, salt=0):
+    return [(7 * i + 3 + salt) % 509 + 1 for i in range(n)]
+
+
+class TestDefaultChunkWidth:
+
+    def test_engine_takes_the_rule_or_the_override(self, wide_engine,
+                                                   narrow_engine,
+                                                   paged_engine):
+        from skypilot_tpu.models.inference import default_prefill_chunk
+        assert wide_engine.prefill_chunk == 256 == \
+            default_prefill_chunk(8, WIDE_CTX)
+        assert isinstance(wide_engine.prefill_chunk, int)
+        assert paged_engine.prefill_chunk == 64    # context 64
+        assert narrow_engine.prefill_chunk == 8    # explicit override
+        from skypilot_tpu.models.inference import ContinuousBatchingEngine
+        int8 = ContinuousBatchingEngine(_cfg(max_seq_len=WIDE_CTX),
+                                        num_slots=1, paged_block_size=8,
+                                        quantize='int8')
+        try:
+            assert int8.prefill_chunk == 128       # a byte a weight
+        finally:
+            int8.stop()
+
+    @pytest.mark.parametrize('length', [100, 256, 300, 257, 19])
+    def test_greedy_equals_one_block_chunks(self, wide_engine,
+                                            narrow_engine, length):
+        """Shorter than, equal to and longer than the width (and one
+        token past it, and under three blocks): token for token what
+        block-sized chunks give."""
+        prompt = _prompt(length)
+        want, _ = narrow_engine.generate(prompt, max_new_tokens=8)
+        got, stats = wide_engine.generate(prompt, max_new_tokens=8)
+        assert got == want, (length, got, want)
+        assert stats['new_tokens'] == 8
+
+    def test_counters_count_dispatches_and_real_tokens(self):
+        """prefill_chunks stays one per dispatch, prefill_tokens counts
+        the real prompt tokens in them (pads excluded), and exactly one
+        prefill program compiles whatever the prompts' lengths."""
+        from skypilot_tpu.models.inference import ContinuousBatchingEngine
+        engine = ContinuousBatchingEngine(_cfg(max_seq_len=WIDE_CTX),
+                                          num_slots=2, paged_block_size=8)
+        try:
+            lengths = [19, 256, 300]
+            for n in lengths:
+                engine.generate(_prompt(n, salt=n), max_new_tokens=2)
+            occ = engine.paged_occupancy()
+            assert occ['prefill_tokens'] == sum(lengths)
+            assert occ['prefill_chunks'] == 1 + 1 + 2
+            assert occ['prefill_tokens'] < \
+                occ['prefill_chunks'] * engine.prefill_chunk
+            assert engine._prefill_chunk_fn._cache_size() == 1  # pylint: disable=protected-access
+        finally:
+            engine.stop()
+
+    @pytest.mark.parametrize('length', [5, 20, 64, 100])
+    def test_single_wide_chunk_reserves_blocks_for_real_tokens(
+            self, wide_engine, length):
+        """After a short prompt's one wide chunk the request holds
+        ceil(prompt / block) blocks — not the padded chunk's 32: a
+        sized pool must not shed requests for pad positions."""
+        seen = []
+
+        def on_token(tok):
+            if tok is not None and not seen:
+                req = next(r for r in wide_engine._slots  # pylint: disable=protected-access
+                           if r is not None and not r.prefilling)
+                seen.append(len(req.blocks))
+
+        before = wide_engine.paged_stats['prefill_chunks']
+        wide_engine.submit(_prompt(length, salt=1), max_new_tokens=3,
+                           on_token=on_token).result(timeout=120)
+        assert wide_engine.paged_stats['prefill_chunks'] == before + 1
+        assert seen == [-(-length // 8)]
+
+    def test_sized_pool_serves_short_prompts_at_the_wide_width(self):
+        """3 data blocks = 24 tokens of pool: a 10-token prompt with 6
+        new tokens fits, though its padded chunk spans 8 blocks."""
+        from skypilot_tpu.models.inference import ContinuousBatchingEngine
+        engine = ContinuousBatchingEngine(_cfg(), num_slots=1,
+                                          paged_block_size=8,
+                                          paged_num_blocks=4)
+        try:
+            assert engine.prefill_chunk == 64
+            toks, _ = engine.generate(_prompt(10), max_new_tokens=6)
+            assert len(toks) == 6
+            engine._pool.check()  # pylint: disable=protected-access
+        finally:
+            engine.stop()
+
+    def test_prefix_hit_starting_off_the_width(self, narrow_engine):
+        """A prefix-cache hit leaves the suffix starting at position
+        100: neither a multiple of the width nor of the block (CoW),
+        with a second chunk starting at 356."""
+        from skypilot_tpu.models.inference import ContinuousBatchingEngine
+        engine = ContinuousBatchingEngine(_cfg(max_seq_len=WIDE_CTX),
+                                          num_slots=2, paged_block_size=8,
+                                          prefix_cache=4)
+        try:
+            base = _prompt(100, salt=2)
+            ext = base + _prompt(300, salt=5)
+            engine.generate(base, max_new_tokens=2)
+            chunks = engine.paged_stats['prefill_chunks']
+            got, _ = engine.generate(ext, max_new_tokens=8)
+            assert engine.prefix_stats['hits'] == 1
+            assert engine.prefix_stats['tokens_reused'] == 100
+            assert engine.paged_stats['prefill_chunks'] == chunks + 2
+            assert engine.paged_stats['prefill_tokens'] == 100 + 300
+            engine._pool.check()  # pylint: disable=protected-access
+        finally:
+            engine.stop()
+        want, _ = narrow_engine.generate(ext, max_new_tokens=8)
+        assert got == want
+
+    def test_unscanned_layers_agree_at_the_default_width(self):
+        from skypilot_tpu.models.inference import ContinuousBatchingEngine
+        cfg = _cfg(max_seq_len=WIDE_CTX, scan_layers=False)
+        prompt = _prompt(300, salt=9)
+        outs = []
+        for chunk in (0, 8):
+            engine = ContinuousBatchingEngine(
+                cfg, num_slots=1, paged_block_size=8, prefill_chunk=chunk)
+            try:
+                outs.append(engine.generate(prompt, max_new_tokens=6)[0])
+            finally:
+                engine.stop()
+        assert outs[0] == outs[1]
 
 
 class TestStepLogBounded:
