@@ -74,6 +74,48 @@ class ModelConfig:
     # (x + attn(ln(x)) + mlp(ln(x))) — vs the sequential default. Pairs
     # with MQA (num_kv_heads=1) and 'layernorm' in the Falcon family.
     parallel_block: bool = False
+    # --- Parallel state-space mixer (Falcon-H1; models/ssm.py) ---
+    # ssm_heads > 0 ⇒ every block runs a Mamba-2 (SSD) mixer BESIDE
+    # attention on one shared pre-norm; both outputs add into the
+    # residual together, then a sequential MLP follows. The mixer is
+    # ssm_heads x ssm_head_dim channels wide (d_ssm), each head a
+    # (ssm_head_dim, ssm_state) recurrent state with one scalar decay;
+    # ssm_groups sets how many B/C pairs the heads share; a causal
+    # depthwise convolution of ssm_conv taps runs over x, B and C.
+    # Prefill walks a chunk in blocks of ssm_chunk positions (the
+    # chunked SSD form), decode by the one-step recurrence.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_conv_bias: bool = True
+    ssm_proj_bias: bool = False
+    # Gated grouped RMSNorm on the mixer's output (mamba_rms_norm), and
+    # whether the norm comes before the gate (mamba_norm_before_gate).
+    ssm_gated_norm: bool = True
+    ssm_norm_before_gate: bool = False
+    # The recurrent state's type in the 'cache' collection (the
+    # convolution's carried inputs are held in the compute dtype).
+    ssm_state_dtype: str = 'float32'
+    # Rows of the per-slot recurrent-state leaves. 0 ⇒ the call's batch
+    # (contiguous caches); a paged engine sets it to num_slots, because
+    # its prefill runs at batch 1 over the one shared cache tree.
+    state_slots: int = 0
+    # µP forward multipliers (Falcon-H1). All 1.0 ⇒ no multiply is
+    # traced, so every other family's program is unchanged.
+    embed_multiplier: float = 1.0
+    attn_in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attn_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    # one a segment of the mixer's input projection: z, x, B, C, dt
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_multiplier: float = 1.0
+    # (inside the gate's activation, on the MLP's output)
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+    lm_head_multiplier: float = 1.0
     # Mistral-style uniform sliding window, in keys (0 ⇒ full causal).
     # The pallas kernels skip blocks outside the window, so long-sequence
     # attention compute drops from O(S²) to O(S·window).
@@ -182,6 +224,27 @@ class ModelConfig:
     def head_dim(self) -> int:
         return self.head_dim_override or self.d_model // self.num_heads
 
+    @property
+    def has_recurrent_state(self) -> bool:
+        """True when a sequence's state is more than its K and V: the
+        mixer's scan state and convolution inputs, of fixed size, owned
+        by a slot and rewritten every step."""
+        return self.ssm_heads > 0
+
+    @property
+    def d_ssm(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """Channels the depthwise convolution runs over: x, B and C."""
+        return self.d_ssm + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_proj_width(self) -> int:
+        """Outputs of the mixer's input projection: z | x | B | C | dt."""
+        return self.d_ssm + self.ssm_conv_channels + self.ssm_heads
+
     def assert_tp_compatible(self, tp: int) -> None:
         """Raise ValueError when a tensor-parallel degree cannot shard
         this architecture evenly. Every dimension the `tp` rules in
@@ -192,6 +255,12 @@ class ModelConfig:
         footprint guarantee — so serving refuses it up front."""
         if tp <= 1:
             return
+        if self.has_recurrent_state:
+            raise NotImplementedError(
+                f'{self.name}: tp={tp} with a recurrent-state mixer: the '
+                f'scan state, the convolution and the grouped norm are '
+                f'not sharded over `tp` yet (heads and groups would '
+                f'have to split together); serve it at tp=1')
         dims = {'num_heads': self.num_heads,
                 'num_kv_heads': self.num_kv_heads,
                 'd_mlp': self.d_mlp,
@@ -237,7 +306,19 @@ class ModelConfig:
             self.d_model
         # Parallel-block layers (Falcon) share ONE pre-norm for attn+mlp.
         norms = (1 if self.parallel_block else 2) * norm_params
-        per_layer = attn + mlp + router + norms
+        mixer = 0
+        if self.ssm_heads:
+            mixer = (self.d_model * self.ssm_proj_width        # in_proj
+                     + self.ssm_conv * self.ssm_conv_channels  # conv
+                     + 3 * self.ssm_heads             # A_log, D, dt_bias
+                     + self.d_ssm * self.d_model)              # out_proj
+            if self.ssm_conv_bias:
+                mixer += self.ssm_conv_channels
+            if self.ssm_gated_norm:
+                mixer += self.d_ssm
+            if self.ssm_proj_bias:
+                mixer += self.ssm_proj_width + self.d_model
+        per_layer = attn + mlp + router + norms + mixer
         return embed + self.num_layers * per_layer + norm_params
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
@@ -436,6 +517,30 @@ FALCON_7B = _register(ModelConfig(
     num_heads=71, num_kv_heads=1, d_mlp=18176, max_seq_len=2048,
     rope_theta=10000.0, norm_style='layernorm', mlp_style='plain',
     mlp_activation='gelu', tie_embeddings=True, parallel_block=True))
+
+# --- Falcon-H1 (TII, 2025): in every block a Mamba-2 mixer runs in
+# PARALLEL with grouped-query attention on one shared pre-norm, their
+# outputs add into the residual together, and a sequential SwiGLU
+# follows; µP multipliers are folded into the forward pass. A
+# sequence's state is then two things: K and V, growing with the
+# position, and the mixer's fixed-size scan and convolution state
+# (models/ssm.py; docs/serving.md "Models with recurrent state"). The
+# published config of Falcon-H1-34B-Instruct, key for key.
+FALCON_H1_34B = _register(ModelConfig(
+    name='falcon-h1-34b', vocab_size=261120, d_model=5120, num_layers=72,
+    num_heads=20, num_kv_heads=4, head_dim_override=128, d_mlp=21504,
+    max_seq_len=262144, rope_theta=1e11, norm_eps=1e-5,
+    ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_groups=2,
+    ssm_conv=4, ssm_chunk=128, ssm_conv_bias=True, ssm_proj_bias=False,
+    ssm_gated_norm=True, ssm_norm_before_gate=False,
+    embed_multiplier=5.656854249492381, attn_in_multiplier=1.0,
+    key_multiplier=0.011048543456039804, attn_out_multiplier=0.0375,
+    ssm_in_multiplier=0.25,
+    ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+    ssm_out_multiplier=0.08838834764831845,
+    mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    lm_head_multiplier=0.0078125))
 
 GPT2_124M = _register(ModelConfig(
     name='gpt2-124m', vocab_size=50304, d_model=768, num_layers=12,

@@ -9,6 +9,8 @@ onto the mesh-first Transformer's param tree:
     Gemma / Gemma-2          (same keys; (1+w)-norm deltas map directly)
     GPT-2                    (Conv1D [in,out] weights, combined c_attn)
     Mixtral                  (block_sparse_moe expert stacks)
+    Falcon-H1                (mamba.* mixer beside self_attn.*; the
+                              depthwise conv1d [ch, 1, taps] → [taps, ch])
 
 Conventions verified against the HF implementations:
 - torch Linear stores [out, in] → our kernels are the transpose.
@@ -86,7 +88,9 @@ def from_hf(state_dict: Mapping[str, Any],
                                   'use scan_layers=True')
     sd = _TrackedDict({k: _np(v) for k, v in state_dict.items()})
     gpt2 = cfg.pos_embedding == 'learned' and cfg.mlp_style == 'plain'
-    if cfg.parallel_block and cfg.qkv_bias:
+    if cfg.ssm_heads:
+        params, layer = _falcon_h1_top(sd, cfg), _falcon_h1_layer
+    elif cfg.parallel_block and cfg.qkv_bias:
         params, layer = _phi_top(sd, cfg), _phi_layer
     elif cfg.parallel_block:
         params, layer = _falcon_top(sd, cfg), _falcon_layer
@@ -179,6 +183,8 @@ def to_hf(params: Mapping[str, Any],
     layers = p['layers']['layer']
     gpt2 = cfg.pos_embedding == 'learned' and cfg.mlp_style == 'plain'
     sd: Dict[str, np.ndarray] = {}
+    if cfg.ssm_heads:
+        return _falcon_h1_to_hf(p, cfg)
     if cfg.is_moe and cfg.norm_style == 'layernorm':
         d, nh, nkv, hd = (cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
                           cfg.head_dim)
@@ -367,6 +373,11 @@ def hf_config_for(cfg: ModelConfig):
     size when the config pads for MXU tiling (Gemma 256000, GPT-2
     50257), matching what to_hf exports and the real tokenizer."""
     import transformers
+    if cfg.ssm_heads:
+        raise NotImplementedError(
+            'no transformers config is built for the Falcon-H1 family '
+            '(to_hf gives the state_dict under the checkpoint\'s own '
+            'names; write config.json from the published one)')
     hf_vocab = (cfg.unpadded_vocab_size
                 if 0 < cfg.unpadded_vocab_size < cfg.vocab_size
                 else cfg.vocab_size)
@@ -539,6 +550,114 @@ def _llama_layer(sd, cfg: ModelConfig, i: int) -> Dict[str, Any]:
             'down_proj': {'kernel': sd[p + 'mlp.down_proj.weight'].T},
         }
     return layer
+
+
+# ---------------- Falcon-H1 (mixer beside attention) -----------------
+#
+# A `falcon_h1` checkpoint's keys, a layer (model.layers.{i}.): the
+# shared pre-norm `input_layernorm`, the mixer under `mamba.` (in_proj,
+# conv1d, A_log, D, dt_bias, norm, out_proj), attention under
+# `self_attn.`, the MLP under `feed_forward.` on `pre_ff_layernorm`;
+# whole: model.embed_tokens, model.final_layernorm, lm_head. The
+# multipliers are the config's, not the checkpoint's. (program leaf,
+# checkpoint key, transposed?) for the mixer and the MLP:
+_FALCON_H1_MIXER = (
+    (('in_proj', 'kernel'), 'mamba.in_proj.weight', True),
+    (('out_proj', 'kernel'), 'mamba.out_proj.weight', True),
+    (('A_log',), 'mamba.A_log', False),
+    (('D',), 'mamba.D', False),
+    (('dt_bias',), 'mamba.dt_bias', False),
+)
+_FALCON_H1_MLP = (('gate_proj', 'feed_forward.gate_proj.weight'),
+                  ('up_proj', 'feed_forward.up_proj.weight'),
+                  ('down_proj', 'feed_forward.down_proj.weight'))
+
+
+def _falcon_h1_top(sd, cfg: ModelConfig) -> Dict[str, Any]:
+    params: Dict[str, Any] = {
+        'embed': {'embedding': _pad_vocab(sd['model.embed_tokens.weight'],
+                                          cfg.vocab_size)},
+        'final_norm': {'scale': sd['model.final_layernorm.weight']},
+    }
+    if not cfg.tie_embeddings:
+        params['lm_head'] = {
+            'kernel': _pad_vocab(sd['lm_head.weight'], cfg.vocab_size).T}
+    return params
+
+
+def _falcon_h1_layer(sd, cfg: ModelConfig, i: int) -> Dict[str, Any]:
+    p = f'model.layers.{i}.'
+    d, nh, nkv, hd = (cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+                      cfg.head_dim)
+    mixer: Dict[str, Any] = {}
+    for path, key, transposed in _FALCON_H1_MIXER:
+        w = sd[p + key].T if transposed else sd[p + key]
+        if len(path) == 2:
+            mixer.setdefault(path[0], {})[path[1]] = w
+        else:
+            mixer[path[0]] = w
+    # torch Conv1d depthwise weight: (channels, 1, taps)
+    mixer['conv_kernel'] = sd[p + 'mamba.conv1d.weight'][:, 0, :].T
+    if cfg.ssm_conv_bias:
+        mixer['conv_bias'] = sd[p + 'mamba.conv1d.bias']
+    if cfg.ssm_gated_norm:
+        mixer['norm_scale'] = sd[p + 'mamba.norm.weight']
+    if cfg.ssm_proj_bias:
+        mixer['in_proj']['bias'] = sd[p + 'mamba.in_proj.bias']
+        mixer['out_proj']['bias'] = sd[p + 'mamba.out_proj.bias']
+    qkv = lambda name, heads: {
+        'kernel': sd[p + f'self_attn.{name}.weight'].T.reshape(
+            d, heads, hd)}
+    return {
+        'attn_norm': {'scale': sd[p + 'input_layernorm.weight']},
+        'mixer': mixer,
+        'attn': {
+            'q_proj': qkv('q_proj', nh), 'k_proj': qkv('k_proj', nkv),
+            'v_proj': qkv('v_proj', nkv),
+            'o_proj': {'kernel': sd[p + 'self_attn.o_proj.weight']
+                       .T.reshape(nh, hd, d)}},
+        'mlp_norm': {'scale': sd[p + 'pre_ff_layernorm.weight']},
+        'mlp': {name: {'kernel': sd[p + key].T}
+                for name, key in _FALCON_H1_MLP},
+    }
+
+
+def _falcon_h1_to_hf(p, cfg: ModelConfig) -> Dict[str, np.ndarray]:
+    """The inverse of _falcon_h1_top/_falcon_h1_layer (`p`: the float32
+    tree)."""
+    d, nh, nkv, hd = (cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+                      cfg.head_dim)
+    sd = {'model.embed_tokens.weight': p['embed']['embedding'],
+          'model.final_layernorm.weight': p['final_norm']['scale']}
+    if not cfg.tie_embeddings:
+        sd['lm_head.weight'] = p['lm_head']['kernel'].T
+    for i in range(cfg.num_layers):
+        li = jax_tree_index(p['layers']['layer'], i)
+        pre = f'model.layers.{i}.'
+        mixer, attn = li['mixer'], li['attn']
+        sd[pre + 'input_layernorm.weight'] = li['attn_norm']['scale']
+        sd[pre + 'pre_ff_layernorm.weight'] = li['mlp_norm']['scale']
+        for path, key, transposed in _FALCON_H1_MIXER:
+            w = mixer[path[0]]
+            w = w[path[1]] if len(path) == 2 else w
+            sd[pre + key] = w.T if transposed else w
+        sd[pre + 'mamba.conv1d.weight'] = mixer['conv_kernel'].T[:, None, :]
+        if cfg.ssm_conv_bias:
+            sd[pre + 'mamba.conv1d.bias'] = mixer['conv_bias']
+        if cfg.ssm_gated_norm:
+            sd[pre + 'mamba.norm.weight'] = mixer['norm_scale']
+        if cfg.ssm_proj_bias:
+            sd[pre + 'mamba.in_proj.bias'] = mixer['in_proj']['bias']
+            sd[pre + 'mamba.out_proj.bias'] = mixer['out_proj']['bias']
+        for name, heads in (('q_proj', nh), ('k_proj', nkv),
+                            ('v_proj', nkv)):
+            sd[pre + f'self_attn.{name}.weight'] = \
+                attn[name]['kernel'].reshape(d, heads * hd).T
+        sd[pre + 'self_attn.o_proj.weight'] = \
+            attn['o_proj']['kernel'].reshape(nh * hd, d).T
+        for name, key in _FALCON_H1_MLP:
+            sd[pre + key] = li['mlp'][name]['kernel'].T
+    return sd
 
 
 # ---------------- DBRX (fine-grained MoE + GQA + clip_qkv) -----------

@@ -351,6 +351,10 @@ def _abstract_init(model: Transformer, cfg: ModelConfig, batch: int):
     if cfg.paged_block_size:
         width = cfg.max_seq_len // cfg.paged_block_size + 1
         kw['block_tables'] = jnp.zeros((batch, width), jnp.int32)
+    if cfg.state_slots:
+        # Recurrent-state leaves are per SLOT, not per batch row: a
+        # batch-1 init names the slot it stands for.
+        kw['state_rows'] = (jnp.zeros((batch,), jnp.int32), None)
     return jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), jnp.ones((batch, 1), jnp.int32),
         jnp.zeros((batch, 1), jnp.int32), **kw))
@@ -443,13 +447,32 @@ def infer_serving_tp(cfg: ModelConfig, n_devices: int) -> int:
             continue
         try:
             cfg.assert_tp_compatible(t)
-        except ValueError:
-            continue
+        except (ValueError, NotImplementedError):
+            continue    # uneven dims, or a mixer not sharded over tp
         best = t
     return best
 
 
 ENGINE_TIERS = ('monolithic', 'prefill', 'decode')
+
+
+def _refuse_recurrent(cfg: ModelConfig, lever: str, why: str) -> None:
+    """Levers that take a request's state to be a list of KV blocks
+    refuse a model whose state is more than that (models/ssm.py), by
+    name and with the reason (docs/serving.md "Models with recurrent
+    state")."""
+    if cfg.has_recurrent_state:
+        raise NotImplementedError(
+            f'{lever} is not supported for {cfg.name}, a model with '
+            f'recurrent state: {why}')
+
+
+_NO_STATE_IN_BLOCKS = (
+    'a shared KV block carries no snapshot of the scan and convolution '
+    'state at its last position')
+_NO_STATE_IN_STREAM = (
+    'a KV chunk stream hands over blocks, and the recurrent state is in '
+    'none of them')
 
 
 class _IngestSession:
@@ -944,7 +967,7 @@ class _Request:
                  'prefilling', 'prefill_pos', 'seq', 'trace',
                  'admit_time', 'tier', 'adapter', 'adapter_slot',
                  'adapter_pool', 'context', 'preemptions',
-                 'admit_mono')
+                 'admit_mono', 'prefill_chunks')
 
     def __init__(self, ids, max_new_tokens, temperature, eos_id, future,
                  on_token=None, deadline=None, tier='standard',
@@ -976,6 +999,10 @@ class _Request:
         self.blocks: list = []
         self.prefilling = False
         self.prefill_pos = 0
+        # Prefill dispatches this request took (chunks of the paged
+        # path, 1 on the bucketed one): the `engine.prefill` span's
+        # `chunks`.
+        self.prefill_chunks = 0
         # Tracing (docs/observability.md "Tracing"): the submitting
         # request's span context, captured by submit() when tracing is
         # enabled. None otherwise — every engine-side tracing hook
@@ -1081,6 +1108,20 @@ class ContinuousBatchingEngine:
                 base_cfg, serve_adapters=self.max_adapters,
                 lora_rank=rank, lora_alpha=adapter_alpha,
                 lora_targets=adapter_targets or base_cfg.lora_targets)
+        # Levers that take a request's state to be a list of KV blocks
+        # refuse a recurrent-state model here, before any weight is
+        # made (a tp mesh is refused by assert_tp_compatible, below).
+        base_cfg = get_config(cfg) if isinstance(cfg, str) else cfg
+        for lever, on, why in (
+                (f'speculative={speculative}', speculative > 0,
+                 'a rejected draft cannot be rolled back out of a scan '
+                 'state (no snapshot a position is kept)'),
+                (f'prefix_cache={prefix_cache}', prefix_cache > 0,
+                 _NO_STATE_IN_BLOCKS),
+                (f'tier={tier!r}', tier != 'monolithic',
+                 _NO_STATE_IN_STREAM)):
+            if on:
+                _refuse_recurrent(base_cfg, lever, why)
         self.cfg, self.params = _resolve_cfg_and_params(
             cfg, params, max_seq_len, rng_seed, quantize, kv_quant,
             mesh=mesh)
@@ -1139,7 +1180,11 @@ class ContinuousBatchingEngine:
                 + 1)
             self.cfg = dataclasses.replace(
                 self.cfg, paged_block_size=self.paged_block_size,
-                paged_num_blocks=nb)
+                paged_num_blocks=nb,
+                # the pool is batch-free, the recurrent leaves are not:
+                # one row a slot, whatever a dispatch's batch
+                state_slots=(num_slots if self.cfg.has_recurrent_state
+                             else 0))
             self._pool: 'Optional[kv_cache_lib.BlockPool]' = \
                 kv_cache_lib.BlockPool(nb, self.paged_block_size)
             # One fixed (1, width) prefill shape per engine. 0 = the
@@ -1155,10 +1200,17 @@ class ContinuousBatchingEngine:
             self._blocks_per_seq = 0
             self._pool = None
             self.prefill_chunk = 0
+        # scan_positions / scan_tokens: positions the prefill scans of
+        # a recurrent-state model ran over (pads included) and those
+        # that advanced a state; state_bytes / kv_pool_bytes: device
+        # bytes of the recurrent leaves and of the block pool (filled
+        # in by the first paged_occupancy()).
         self.paged_stats = {'cow_copies': 0, 'blocks_reused': 0,
                             'prefill_chunks': 0, 'prefill_tokens': 0,
                             'prefix_evictions': 0,
-                            'spec_trimmed_blocks': 0}
+                            'spec_trimmed_blocks': 0,
+                            'scan_positions': 0, 'scan_tokens': 0,
+                            'state_bytes': 0, 'kv_pool_bytes': 0}
         # -------- fused decode kernel (docs/performance.md) --------
         # decode_kernel='pallas' routes paged attention (and, on
         # multi-LoRA engines, the adapter gather+dot) through the
@@ -1270,6 +1322,12 @@ class ContinuousBatchingEngine:
         self._engine_work: 'collections.deque' = collections.deque()
         self.model = Transformer(self.cfg)
         self._rng = jax.random.PRNGKey(rng_seed)
+        self._recurrent = self.cfg.has_recurrent_state
+        # Decode-tick valid-row cache (recurrent-state models only; see
+        # _valid_for): 1 for a decoding slot, 0 for an inert one.
+        self._valid_sig: Optional[tuple] = None
+        self._valid_cache = None
+        self._kind_bytes: Optional[Dict[str, int]] = None
         # -------- tensor-parallel serving (docs/performance.md) -----
         # mesh with tp>1 (parallel.decode_mesh): weights shard per the
         # SAME logical-axis rules training uses (heads/kv_heads/mlp/
@@ -1407,6 +1465,24 @@ class ContinuousBatchingEngine:
 
     # ---------------- jitted pieces ----------------
 
+    def _cache_bytes_by_kind(self) -> Dict[str, int]:
+        """Device bytes of this engine's cache tree by kind: the
+        recurrent leaves and the K/V substrate. Read once, from the
+        live tree, or from its shapes alone while there is none yet
+        (nothing is allocated for it)."""
+        if self._kind_bytes is None:
+            tree = self._cache
+            if tree is None:
+                tree = nn.unbox(_abstract_init(
+                    self.model, self.cfg, 1 if self.paged_block_size
+                    else self.num_slots)['cache'])
+            self._kind_bytes = kv_cache_lib.cache_bytes_by_kind(
+                ([str(getattr(k, 'key', k)) for k in path],
+                 math.prod(leaf.shape) * leaf.dtype.itemsize)
+                for path, leaf in
+                jax.tree_util.tree_flatten_with_path(tree)[0])
+        return self._kind_bytes
+
     def _single_cache_shapes(self):
         return jax.eval_shape(
             lambda: self.model.init(
@@ -1483,9 +1559,12 @@ class ContinuousBatchingEngine:
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
             tokens.shape)
+        # Recurrent state: the bucket's right pads must not advance it.
+        rows = ((None, jnp.reshape(true_len, (1,)))
+                if self._recurrent else None)
         logits, mutated = self.model.apply(
             self._variables(params, cache1, adapters), tokens, positions,
-            adapter_ids=aids, mutable=['cache'])
+            adapter_ids=aids, state_rows=rows, mutable=['cache'])
         last = jax.lax.dynamic_index_in_dim(logits, true_len - 1, axis=1,
                                             keepdims=False)
         return last[0], nn.unbox(mutated['cache'])
@@ -1528,17 +1607,22 @@ class ContinuousBatchingEngine:
         return jax.tree.map(ins, cache, cache1)
 
     def _decode_impl(self, params, cache, tokens, positions, temps, rng,
-                     tables=None, adapters=None, aids=None):
+                     tables=None, adapters=None, aids=None, valid=None):
         """One all-slots decode tick WITH in-jit sampling (one host sync
         per tick instead of one per slot). tokens/positions:
         (num_slots, 1); temps: (num_slots,) — <=0 means greedy. `tables`
         (paged mode only): per-row block tables for the shared pool.
         `aids` (multi-LoRA only): per-slot adapter-slot indices — THE
         mixed-adapter batching mechanism (one dispatch, many
-        tenants)."""
+        tenants). `valid` (recurrent-state models only): (num_slots,)
+        1 for a decoding slot, 0 for an empty or prefilling one, whose
+        recurrent state the step must leave bit for bit — unlike K and
+        V, it has no scratch block to absorb an inert row's write."""
         logits, mutated = self.model.apply(
             self._variables(params, cache, adapters), tokens, positions,
-            block_tables=tables, adapter_ids=aids, mutable=['cache'])
+            block_tables=tables, adapter_ids=aids,
+            state_rows=None if valid is None else (None, valid),
+            mutable=['cache'])
         last = logits[:, -1, :].astype(jnp.float32)
         greedy = jnp.argmax(last, axis=-1)
         scaled = apply_logit_filters(
@@ -1549,7 +1633,8 @@ class ContinuousBatchingEngine:
         return out, nn.unbox(mutated['cache'])
 
     def _decode_multi_impl(self, params, cache, tokens, positions, temps,
-                           rngs, tables=None, adapters=None, aids=None):
+                           rngs, tables=None, adapters=None, aids=None,
+                           valid=None):
         """K all-slots decode steps in one dispatch (K = rngs' leading
         dim): returns ((num_slots, K) tokens, cache). tokens/positions:
         (num_slots,). Paged mode: the engine pre-allocates blocks to
@@ -1560,7 +1645,7 @@ class ContinuousBatchingEngine:
             cache, toks, pos = carry
             out, cache = self._decode_impl(params, cache, toks[:, None],
                                            pos[:, None], temps, rng,
-                                           tables, adapters, aids)
+                                           tables, adapters, aids, valid)
             return (cache, out, pos + 1), out
 
         (cache, _, _), toks = jax.lax.scan(
@@ -1568,7 +1653,8 @@ class ContinuousBatchingEngine:
         return toks.swapaxes(0, 1), cache
 
     def _decode_step_impl(self, params, cache, tokens, positions, temps,
-                          rng, tables=None, adapters=None, aids=None):
+                          rng, tables=None, adapters=None, aids=None,
+                          valid=None):
         """One all-slots step from 1-D feed arrays; returns
         ((num_slots, 1) emit columns, the NEXT step's (tokens,
         positions) feed, cache). The feed is computed in-graph — the
@@ -1581,19 +1667,20 @@ class ContinuousBatchingEngine:
         read."""
         out, cache = self._decode_impl(params, cache, tokens[:, None],
                                        positions[:, None], temps, rng,
-                                       tables, adapters, aids)
+                                       tables, adapters, aids, valid)
         out = self._repl_constrain(out)
         return (out[:, None],
                 (out, self._repl_constrain(positions + 1)), cache)
 
     def _decode_multi_feed_impl(self, params, cache, tokens, positions,
                                 temps, rngs, tables=None, adapters=None,
-                                aids=None):
+                                aids=None, valid=None):
         """K-step variant of _decode_step_impl (K = rngs' leading dim):
         ((num_slots, K) columns, next feed, cache)."""
         toks, cache = self._decode_multi_impl(params, cache, tokens,
                                               positions, temps, rngs,
-                                              tables, adapters, aids)
+                                              tables, adapters, aids,
+                                              valid)
         toks = self._repl_constrain(toks)
         return toks, (toks[:, -1],
                       self._repl_constrain(positions + rngs.shape[0])), \
@@ -1611,7 +1698,7 @@ class ContinuousBatchingEngine:
         return jax.lax.with_sharding_constraint(x, self._repl)
 
     def _prefill_chunk_impl(self, params, cache, tokens, tables, start,
-                            true_n, adapters=None, aids=None):
+                            true_n, adapters=None, aids=None, slot=None):
         """One chunked-prefill step on the PAGED pool: process the
         (1, prefill_chunk) right-padded chunk at positions
         [start, start+chunk) through the slot's block table. The chunk
@@ -1625,14 +1712,22 @@ class ContinuousBatchingEngine:
         writes land in the request's last private block, where later
         real writes overwrite them, in the scratch block behind
         unmapped table entries, or clip into the table's scratch column
-        (same stale-entry masking argument as _prefill_impl)."""
+        (same stale-entry masking argument as _prefill_impl). `slot`
+        (recurrent-state models only) names the row of the state leaves
+        this request owns: the chunk reads it (a zero state when start
+        is 0), advances it over the true_n real positions only — a pad
+        that advanced a scan state could not be masked afterwards — and
+        writes it back."""
         positions = start + jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
             tokens.shape)
+        rows = None if slot is None else (jnp.reshape(slot, (1,)),
+                                          jnp.reshape(true_n, (1,)))
         logits, mutated = self.model.apply(
             self._variables(params, cache, adapters), tokens, positions,
             block_tables=tables, adapter_ids=aids,
-            head_rows=jnp.reshape(true_n - 1, (1,)), mutable=['cache'])
+            head_rows=jnp.reshape(true_n - 1, (1,)), state_rows=rows,
+            mutable=['cache'])
         return logits[0, 0], nn.unbox(mutated['cache'])
 
     def _cow_copy_impl(self, cache, src, dst):
@@ -2028,6 +2123,7 @@ class ContinuousBatchingEngine:
             'engine.prefill', req.admit_time or req.submit_time,
             req.first_token_time, parent=req.trace,
             attrs={'slot': slot, 'prompt_tokens': len(req.ids),
+                   'chunks': req.prefill_chunks,
                    'ttft_s': round(
                        req.first_token_time - req.submit_time, 6)})
 
@@ -2214,6 +2310,21 @@ class ContinuousBatchingEngine:
             self._aids_sig = sig
         return self._aids_cache
 
+    def _valid_for(self, active_set):
+        """(num_slots,) int32, 1 for each decoding slot (recurrent-state
+        models only; None otherwise so other models' jit signatures
+        stay unchanged). Cached under the active set, which changes
+        only with slot churn: steady-state ticks upload nothing."""
+        if not self._recurrent:
+            return None
+        sig = tuple(sorted(active_set))
+        if sig != self._valid_sig:
+            self._valid_cache = _upload(
+                [int(i in active_set) for i in range(self.num_slots)],
+                jnp.int32, self._repl)
+            self._valid_sig = sig
+        return self._valid_cache
+
     def _aids_single(self, req: '_Request'):
         """(1,) adapter-index vector for a batch-1 prefill dispatch."""
         if not self.max_adapters:
@@ -2354,12 +2465,18 @@ class ContinuousBatchingEngine:
                 self._table_array([req]),
                 _upload(start, jnp.int32, self._repl),
                 _upload(n, jnp.int32, self._repl),
-                self._adapters, self._aids_single(req))
+                self._adapters, self._aids_single(req),
+                _upload(slot, jnp.int32, self._repl)
+                if self._recurrent else None)
             self._commit_gen(gen,
                              lambda: setattr(self, '_cache', pool_arr))
             req.prefill_pos = start + n
+            req.prefill_chunks += 1
             self.paged_stats['prefill_chunks'] += 1
             self.paged_stats['prefill_tokens'] += n
+            if self._recurrent:
+                self.paged_stats['scan_positions'] += self.prefill_chunk
+                self.paged_stats['scan_tokens'] += n
             _CHUNKED_PREFILL.inc()
             _CHUNKED_PREFILL_TOKENS.inc(n)
             self.step_log.append(('prefill', frozenset([slot])))
@@ -2566,6 +2683,7 @@ class ContinuousBatchingEngine:
         pin ceil(L/block_size) prefix-entry costs against it)."""
         if not self.paged_block_size:
             return {}
+        self.paged_stats.update(self._cache_bytes_by_kind())
         occ = {
             'block_size': self.paged_block_size,
             'blocks_capacity': self._pool.num_blocks,
@@ -2573,6 +2691,9 @@ class ContinuousBatchingEngine:
             'peak_blocks_used': self._pool.peak_used,
             'prefix_entries': len(self._prefix_entries),
             **self.paged_stats,
+            # slots whose recurrent state belongs to a request now
+            'state_slots_used': (sum(r is not None for r in self._slots)
+                                 if self._recurrent else 0),
         }
         if self._tp > 1 and self._cache is not None:
             # Per-device view: each device holds its kv-head shard of
@@ -2599,8 +2720,11 @@ class ContinuousBatchingEngine:
             'tp': self._tp,
             'weight_bytes': weight,
             'weight_bytes_per_device': weight_dev,
+            # the whole cache tree: K and V and, for a recurrent-state
+            # model, the per-slot state leaves (`state_bytes` of it)
             'kv_bytes': kv,
             'kv_bytes_per_device': kv_dev,
+            'state_bytes': self._cache_bytes_by_kind()['state_bytes'],
             'total_bytes': weight + kv,
             'total_bytes_per_device': weight_dev + kv_dev,
         }
@@ -2717,6 +2841,7 @@ class ContinuousBatchingEngine:
         export first and the artifact is published partially; a fault
         or kill mid-export publishes nothing (atomic rename).
         Returns the kv_cache stats dict."""
+        _refuse_recurrent(self.cfg, 'export_prefixes', _NO_STATE_IN_BLOCKS)
         empty = {'exported': 0, 'blocks': 0, 'skipped': 0,
                  'truncated': False, 'path': path}
         if not (self.paged_block_size and self.prefix_cache):
@@ -2775,6 +2900,7 @@ class ContinuousBatchingEngine:
         is skipped; a full pool stops the pre-warm partially; an
         artifact from an incompatible pool (block_size / cache layout)
         raises kv_cache.ArtifactError without mutating anything."""
+        _refuse_recurrent(self.cfg, 'import_prefixes', _NO_STATE_IN_BLOCKS)
         if not (self.paged_block_size and self.prefix_cache):
             raise ValueError('prefix import requires paged_block_size '
                              'and prefix_cache')
@@ -2949,6 +3075,7 @@ class ContinuousBatchingEngine:
         discarded). Returns {'prompt_tokens', 'ttft_s', 'cached'} —
         cached=False means the index evicted the entry already (storm
         pressure) and a subsequent export will fail retryably."""
+        _refuse_recurrent(self.cfg, 'prefill_prefix', _NO_STATE_IN_STREAM)
         ids = [int(t) for t in ids]
         if not (self.paged_block_size and self.prefix_cache):
             raise ValueError('prefill_prefix requires paged_block_size '
@@ -2973,6 +3100,7 @@ class ContinuousBatchingEngine:
         `trace_header` (an X-SkyTPU-Trace value) rides every chunk's
         header so the decode replica's ingest spans join the sender's
         trace (docs/observability.md "Tracing")."""
+        _refuse_recurrent(self.cfg, 'export_prefix_chunks', _NO_STATE_IN_STREAM)
         if not (self.paged_block_size and self.prefix_cache):
             raise ValueError('export_prefix_chunks requires '
                              'paged_block_size and prefix_cache')
@@ -3079,6 +3207,7 @@ class ContinuousBatchingEngine:
         the partial stream rolled back to refcount-0. The final chunk's
         batched scatter + index publish run in the engine tick thread.
         """
+        _refuse_recurrent(self.cfg, 'ingest_chunk', _NO_STATE_IN_STREAM)
         fault_injection.point('engine.ingest')
         if not (self.paged_block_size and self.prefix_cache):
             raise ValueError('KV ingest requires paged_block_size and '
@@ -3365,6 +3494,7 @@ class ContinuousBatchingEngine:
             # holding it is safe.
             self._store_prefix(context, cache1)
         first = self._sample(logits, req.temperature)
+        req.prefill_chunks += 1
         self._note_first_token(req, slot)
         req.tokens.append(first)
         _TOKENS_TOTAL.inc()  # the first token lands here, not in _emit
@@ -4018,16 +4148,17 @@ class ContinuousBatchingEngine:
             gap = (time_lib.monotonic() - self._last_ready
                    if self._last_ready is not None else None)
         aids = self._aids_for(slots, active_set)
+        valid = self._valid_for(active_set)
         self._rng, rng = jax.random.split(self._rng)
         if k == 1:
             out_cols, feed_next, cache = self._decode(
                 self.params, self._cache, tok_dev, pos_dev, temps, rng,
-                tables, self._adapters, aids)
+                tables, self._adapters, aids, valid)
         else:
             rngs = jax.random.split(rng, k)
             out_cols, feed_next, cache = self._decode_multi(
                 self.params, self._cache, tok_dev, pos_dev, temps,
-                rngs, tables, self._adapters, aids)
+                rngs, tables, self._adapters, aids, valid)
         self._commit_gen(gen, lambda: setattr(self, '_cache', cache))
         self._decode_steps += k
         self.step_log.append((self._decode_steps, frozenset(active)))

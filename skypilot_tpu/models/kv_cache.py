@@ -52,6 +52,28 @@ from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 
+# Two kinds of per-sequence state share the engine's one cache tree.
+# K and V grow with the position and live in the blocks this module
+# hands out. A model with a recurrent mixer (models/ssm.py) also keeps
+# leaves of FIXED size a slot, named here, which no block holds: they
+# are indexed by slot, rewritten at every step, and reset by the first
+# chunk of the slot's next request. The engine counts the two apart.
+STATE_LEAVES = ('ssm_state', 'conv_state')
+
+
+def cache_bytes_by_kind(leaves) -> Dict[str, int]:
+    """Device bytes of a cache tree, split by kind. `leaves`: (path
+    names, nbytes) of every leaf. The recurrent leaves are
+    `state_bytes`, everything else (K, V, their int8 scale rows) is
+    `kv_pool_bytes`."""
+    out = {'state_bytes': 0, 'kv_pool_bytes': 0}
+    for names, nbytes in leaves:
+        kind = ('state_bytes' if names and names[-1] in STATE_LEAVES
+                else 'kv_pool_bytes')
+        out[kind] += int(nbytes)
+    return out
+
+
 class PoolExhaustedError(Exception):
     """No free block: the caller should evict cached prefixes (refcount
     drops free their blocks) or shed the request."""

@@ -32,6 +32,10 @@ _QUANT_MODULES = {
     'o_proj': (2, 1),                        # (heads, head_dim) → embed
     'gate_proj': (1, 1), 'up_proj': (1, 1), 'down_proj': (1, 1),
     'lm_head': (1, 1),
+    # The state-space mixer's two projections (models/ssm.py). Its
+    # decay, step bias, skip, convolution and norm stay float: a few
+    # thousand numbers a layer, and the recurrence is sensitive to them.
+    'in_proj': (1, 1), 'out_proj': (1, 1),
 }
 
 
@@ -70,7 +74,7 @@ def quantize_params(params: Any, cfg: ModelConfig) -> Any:
             feat = _QUANT_MODULES.get(name)
             if (feat is not None and isinstance(sub, dict)
                     and 'kernel' in sub):
-                q, scale = quantize_kernel(sub['kernel'], *feat)
+                q, scale = _quantize_kernel_jit(sub['kernel'], *feat)
                 new_sub = {k: v for k, v in sub.items() if k != 'kernel'}
                 new_sub['kernel_q'] = q
                 new_sub['kernel_scale'] = scale
@@ -79,6 +83,14 @@ def quantize_params(params: Any, cfg: ModelConfig) -> Any:
                 out[name] = walk(sub)
         return out
 
-    # One jitted dispatch for the whole tree: eager per-leaf quantize
-    # compiles and launches every op on its own.
-    return jax.jit(walk)(params)
+    # One jitted dispatch a kernel (a program a distinct shape, a dozen
+    # in all), not one for the whole tree: that one holds the float
+    # tree, the int8 tree and a copy of every leaf that stays float at
+    # once, which a 10.5 GB tree on a 16 GB chip cannot (the embedding
+    # alone is 2.7 GB passed through). Leaves that stay float are
+    # returned as they are. Op by op would compile and launch every op
+    # of every leaf on its own.
+    return walk(params)
+
+
+_quantize_kernel_jit = jax.jit(quantize_kernel, static_argnums=(1, 2))

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from skypilot_tpu.models.configs import ModelConfig
+from skypilot_tpu.models.ssm import Mamba2Mixer, scaled
 from skypilot_tpu.ops.flash_attention import flash_attention
 from skypilot_tpu.ops.fused_lora import fused_multi_lora
 from skypilot_tpu.ops.paged_attention import paged_decode_attention
@@ -524,6 +525,9 @@ class Attention(nn.Module):
         cfg = self.cfg
         dense = lambda feats, axes, name: dense_general(
             cfg, feats, axes, name, use_bias=cfg.qkv_bias)
+        # µP multipliers (Falcon-H1): on the input, the keys and the
+        # output. 1.0 traces nothing.
+        x = scaled(x, cfg.attn_in_multiplier)
         q = _apply_proj(dense((cfg.num_heads, cfg.head_dim),
                               ('embed', 'heads', 'qkv_dim'), 'q_proj'),
                         x, adapter_ids)
@@ -533,6 +537,7 @@ class Attention(nn.Module):
         v = _apply_proj(dense((cfg.num_kv_heads, cfg.head_dim),
                               ('embed', 'kv_heads', 'qkv_dim'),
                               'v_proj'), x, adapter_ids)
+        k = scaled(k, cfg.key_multiplier)
         if cfg.qkv_clip:
             # DBRX clip_qkv: clamp projections to ±clip (training
             # stability; must match at inference for logit parity).
@@ -573,6 +578,7 @@ class Attention(nn.Module):
                           ('heads', 'qkv_dim', 'embed'), 'o_proj',
                           axis=(-2, -1), use_bias=cfg.o_bias),
             out, adapter_ids)
+        out = scaled(out, cfg.attn_out_multiplier)
         return sharding.constrain(out, 'batch', 'seq', 'act_embed')
 
     def _decode_attention(self, q: jax.Array, k: jax.Array,
@@ -874,12 +880,13 @@ class SwiGLU(nn.Module):
             gate = _apply_proj(
                 dense(cfg.d_mlp, ('embed', 'mlp'), 'gate_proj'),
                 x, adapter_ids)
-            h = act(gate) * up
+            h = act(scaled(gate, cfg.mlp_multipliers[0])) * up
         else:
             h = act(up)
         h = sharding.constrain(h, 'batch', 'seq', 'mlp')
         out = _apply_proj(dense(cfg.d_model, ('mlp', 'embed'),
                                 'down_proj'), h, adapter_ids)
+        out = scaled(out, cfg.mlp_multipliers[1])
         return sharding.constrain(out, 'batch', 'seq', 'act_embed')
 
 
@@ -890,9 +897,25 @@ class DecoderLayer(nn.Module):
     def __call__(self, x: jax.Array,
                  positions: jax.Array,
                  block_tables: Optional[jax.Array] = None,
-                 adapter_ids: Optional[jax.Array] = None) -> jax.Array:
+                 adapter_ids: Optional[jax.Array] = None,
+                 state_rows: Optional[Tuple] = None) -> jax.Array:
         cfg = self.cfg
         h = RMSNorm(cfg, name='attn_norm')(x)
+        if cfg.ssm_heads:
+            if cfg.is_moe or cfg.parallel_block:
+                raise NotImplementedError(
+                    'the parallel state-space mixer is modeled with a '
+                    'sequential dense MLP only (Falcon-H1)')
+            # Falcon-H1: the mixer and attention read ONE pre-norm and
+            # add into the residual together; its state (`state_rows`
+            # says whose, and how many positions are real) sits beside
+            # K and V in the cache (models/ssm.py).
+            x = (x + Mamba2Mixer(cfg, name='mixer')(h, positions,
+                                                    state_rows)
+                 + Attention(cfg, name='attn')(h, positions,
+                                               block_tables, adapter_ids))
+            h = RMSNorm(cfg, name='mlp_norm')(x)
+            return x + SwiGLU(cfg, name='mlp')(h, adapter_ids)
         if cfg.parallel_block:
             if cfg.is_moe:
                 raise NotImplementedError(
@@ -924,11 +947,11 @@ class _ScannedLayer(nn.Module):
 
     @nn.compact
     def __call__(self, carry, _):
-        x, positions, block_tables, adapter_ids = carry
+        x, positions, block_tables, adapter_ids, state_rows = carry
         x = DecoderLayer(self.cfg, name='layer')(x, positions,
                                                  block_tables,
-                                                 adapter_ids)
-        return (x, positions, block_tables, adapter_ids), None
+                                                 adapter_ids, state_rows)
+        return (x, positions, block_tables, adapter_ids, state_rows), None
 
 
 class Transformer(nn.Module):
@@ -940,7 +963,8 @@ class Transformer(nn.Module):
                  mode: str = 'full',
                  block_tables: Optional[jax.Array] = None,
                  adapter_ids: Optional[jax.Array] = None,
-                 head_rows: Optional[jax.Array] = None) -> jax.Array:
+                 head_rows: Optional[jax.Array] = None,
+                 state_rows: Optional[Tuple] = None) -> jax.Array:
         """mode: 'full' (tokens → logits, the normal path), or the two
         halves the pipeline executor (parallel/pipeline.py) sandwiches
         around its microbatched layer schedule — 'embed' (tokens →
@@ -950,7 +974,12 @@ class Transformer(nn.Module):
 
         head_rows (B,) int32: unembed only row head_rows[b] of each
         sequence, giving (B, 1, V) logits — a prefill chunk needs one
-        row's logits, not T x V of them."""
+        row's logits, not T x V of them.
+
+        state_rows = (slots, valid), each (B,) int32 or None, for models
+        with recurrent state (models/ssm.py): the slot whose state each
+        batch row reads and writes, and how many of its T positions are
+        real (the rest are right pads, or the row is inert)."""
         cfg = self.cfg
         # Tied models reuse this table as the unembed projection: init at
         # d^-1/2 so step-0 logits land at O(1) (and the Gemma sqrt(d)
@@ -970,7 +999,7 @@ class Transformer(nn.Module):
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
                 tokens.shape)
-        x = embed(tokens)
+        x = scaled(embed(tokens), cfg.embed_multiplier)
         if cfg.scale_embed_by_dim:
             x = x * jnp.asarray(cfg.d_model**0.5, dtype=x.dtype)
         if cfg.pos_embedding == 'learned':
@@ -1001,8 +1030,9 @@ class Transformer(nn.Module):
                 length=cfg.num_layers,
                 metadata_params={nn.PARTITION_NAME: 'layers'},
             )(cfg, name='layers')
-            (x, _, _, _), _ = scanned(
-                (x, positions, block_tables, adapter_ids), None)
+            (x, _, _, _, _), _ = scanned(
+                (x, positions, block_tables, adapter_ids, state_rows),
+                None)
         else:
             # Remat is an execution knob: the param tree keys must not
             # depend on it (checkpoint compatibility).
@@ -1011,7 +1041,8 @@ class Transformer(nn.Module):
             for i in range(cfg.num_layers):
                 x = layer_ctor(cfg, name=f'layer_{i}')(x, positions,
                                                        block_tables,
-                                                       adapter_ids)
+                                                       adapter_ids,
+                                                       state_rows)
 
         if head_rows is not None:
             x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
@@ -1029,6 +1060,7 @@ class Transformer(nn.Module):
             logits = dense_general(cfg, cfg.vocab_size,
                                    ('embed', 'vocab'), 'lm_head',
                                    use_bias=cfg.lm_head_bias)(x)
+        logits = scaled(logits, cfg.lm_head_multiplier)
         if cfg.final_logit_softcap:
             cap = cfg.final_logit_softcap
             logits = (cap * jnp.tanh(
