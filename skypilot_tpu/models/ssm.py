@@ -85,28 +85,6 @@ def _rebox(var, box, arr) -> None:
                  else arr)
 
 
-def _read_rows(state_leaf, conv_leaf, slots):
-    """Each batch row's state out of the per-slot leaves. One row (a
-    paged prefill chunk) is a dynamic slice, which XLA updates in place
-    where a gather and scatter copy the layer's whole leaf."""
-    if slots is None:
-        return state_leaf, conv_leaf
-    if slots.shape[0] == 1:
-        row = lambda leaf: jax.lax.dynamic_slice_in_dim(leaf, slots[0], 1)
-        return row(state_leaf), row(conv_leaf)
-    return state_leaf[slots], conv_leaf[slots]
-
-
-def _write_rows(state_leaf, conv_leaf, slots, state, conv):
-    if slots is None:
-        return state, conv
-    if slots.shape[0] == 1:
-        put = lambda leaf, new: jax.lax.dynamic_update_slice_in_dim(
-            leaf, new, slots[0], 0)
-        return put(state_leaf, state), put(conv_leaf, conv)
-    return state_leaf.at[slots].set(state), conv_leaf.at[slots].set(conv)
-
-
 def ssd_step(state, xs, b, c, delta, a_log, d_skip):
     """The one-step recurrence. state: (B, G, R, P, N) float32; xs:
     (B, G, R, P); b, c: (B, G, N); delta: (B, G, R), already masked.
@@ -247,7 +225,13 @@ class Mamba2Mixer(nn.Module):
                 raise ValueError(
                     f'the state leaves hold {rows} rows and the call '
                     f'{batch}: pass state_rows with each row\'s slot')
-            s_old, c_old = _read_rows(state_leaf, conv_leaf, slots)
+            # Late import: only a decoding model loads the module. Rows
+            # are read and written at (layer, slot) of the leaf itself
+            # (the stacked one under the cache-carrying layer loop).
+            from skypilot_tpu.models import cache_carry
+            layer = cache_carry.current_layer()
+            s_old = cache_carry.read_rows(state_leaf, slots, layer)
+            c_old = cache_carry.read_rows(conv_leaf, slots, layer)
             fresh = positions[:, 0] == 0
             s0 = jnp.where(fresh[:, None, None, None], 0,
                            s_old).astype(F32)
@@ -293,10 +277,10 @@ class Mamba2Mixer(nn.Module):
                            s1.reshape(batch, heads, p_dim, n).astype(
                                state_leaf.dtype), s_old)
             c1 = jnp.where(touched[:, None, None], c1, c_old)
-            state_leaf, conv_leaf = _write_rows(state_leaf, conv_leaf,
-                                                slots, s1, c1)
-            _rebox(state_var, state_box, state_leaf)
-            _rebox(conv_var, conv_box, conv_leaf)
+            _rebox(state_var, state_box, cache_carry.write_rows(
+                state_leaf, slots, layer, s1))
+            _rebox(conv_var, conv_box, cache_carry.write_rows(
+                conv_leaf, slots, layer, c1))
 
         # ---- gate, grouped norm, output projection ----
         gate = nn.silu(z)

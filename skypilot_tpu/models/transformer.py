@@ -617,8 +617,20 @@ class Attention(nn.Module):
         writes land in their own row (contiguous — overwritten whole by
         the next _insert) or the scratch block (paged), and their
         outputs are never read (models/inference.py, async pipeline).
+
+        How a layer reaches its part of a leaf: under the cache-carrying
+        layer loop (`cache_carry.current_layer()` is this layer's index)
+        every leaf is the stacked (L, B, S, ...) one that the loop
+        carries, the chunk is written at (layer, row, start) by one
+        scatter on it and the window is read at `layer`, where it lies;
+        no layer's slice is taken out and none is written back. With no
+        index (init, the unrolled model) the leaf is this layer's own
+        and the same scatter runs under a leading axis of one.
         """
+        # Late import: only a decoding model loads the module.
+        from skypilot_tpu.models import cache_carry
         cfg = self.cfg
+        layer = cache_carry.current_layer()
         batch, cur_len, _, _ = q.shape
         if cur_len > cfg.max_seq_len:
             raise ValueError(
@@ -626,7 +638,7 @@ class Attention(nn.Module):
                 f'{cfg.max_seq_len}')
         if cfg.paged_block_size:
             return self._paged_decode_attention(q, k, v, positions,
-                                                block_tables)
+                                                block_tables, layer)
         kv_heads = k.shape[2]
         kv_quant = cfg.kv_cache_quant == 'int8'
         cache_dtype = jnp.int8 if kv_quant else k.dtype
@@ -668,43 +680,40 @@ class Attention(nn.Module):
 
         key_arr, key_box = unbox(cached_key)
         value_arr, value_box = unbox(cached_value)
-        start_pos = positions[:, 0].astype(jnp.int32)
-        # Per-row contiguous write at positions[:, 0] (vmapped DUS lowers
-        # to a scatter; rows at different depths write independently).
-        write = jax.vmap(
-            lambda cache, new, start: jax.lax.dynamic_update_slice(
-                cache, new, (start, 0, 0)))
+        start_pos = positions[:, 0]
+        # Per-row contiguous write at positions[:, 0] (one scatter; rows
+        # at different depths write independently), on the carried leaf
+        # itself at (layer, row, start) under the layer loop.
+        write = cache_carry.write_window
         if kv_quant:
-            k_q, k_s = _int8_quantize(k)
-            v_q, v_s = _int8_quantize(v)
-            key_arr = write(key_arr, k_q, start_pos)
-            value_arr = write(value_arr, v_q, start_pos)
-            write_s = jax.vmap(
-                lambda cache, new, start: jax.lax.dynamic_update_slice(
-                    cache, new, (start, 0)))
+            k, k_s = _int8_quantize(k)
+            v, v_s = _int8_quantize(v)
             ks_arr, ks_box = unbox(key_scale)
             vs_arr, vs_box = unbox(value_scale)
-            ks_arr = write_s(ks_arr, k_s, start_pos)
-            vs_arr = write_s(vs_arr, v_s, start_pos)
+            ks_arr = write(ks_arr, k_s, start_pos, layer)
+            vs_arr = write(vs_arr, v_s, start_pos, layer)
             rebox(key_scale, ks_box, ks_arr)
             rebox(value_scale, vs_box, vs_arr)
-        else:
-            key_arr = write(key_arr, k, start_pos)
-            value_arr = write(value_arr, v, start_pos)
+        key_arr = write(key_arr, k, start_pos, layer)
+        value_arr = write(value_arr, v, start_pos, layer)
         rebox(cached_key, key_box, key_arr)
         rebox(cached_value, value_box, value_arr)
 
+        # This layer's window, read where it lies.
+        mine = lambda arr: cache_carry.read_layer(arr, layer)
         # Score/softmax/weighted-sum over the full contiguous window:
         # ONE shared op-order definition with the paged path
         # (_attend_window), so the layouts' bit-identity contract holds
         # by construction.
-        return _attend_window(cfg, q, key_arr, value_arr,
-                              ks_arr if kv_quant else None,
-                              vs_arr if kv_quant else None, positions)
+        return _attend_window(cfg, q, mine(key_arr), mine(value_arr),
+                              mine(ks_arr) if kv_quant else None,
+                              mine(vs_arr) if kv_quant else None,
+                              positions)
 
     def _paged_decode_attention(self, q: jax.Array, k: jax.Array,
                                 v: jax.Array, positions: jax.Array,
-                                block_tables: Optional[jax.Array]
+                                block_tables: Optional[jax.Array],
+                                layer: Optional[jax.Array] = None
                                 ) -> jax.Array:
         """Paged variant of _decode_attention: K/V live in a SHARED pool
         of `cfg.paged_num_blocks` blocks of `cfg.paged_block_size`
@@ -738,6 +747,20 @@ class Attention(nn.Module):
         (shared prefix blocks are stored ONCE and referenced by many
         rows' tables), not slots × max_seq_len. Engine-side allocation,
         refcounts, and copy-on-write live in models/kv_cache.py.
+
+        How a layer reaches its part of a leaf: under the cache-carrying
+        layer loop (`layer` given, models/cache_carry.py) each leaf is
+        the stacked (L, nblocks, bs, ...) one that the loop CARRIES, and
+        this layer is rows [layer * nblocks * bs, (layer + 1) * nblocks
+        * bs) of its flat view: the chunk is scattered and the window
+        gathered there, on the carried buffer itself, which XLA then
+        updates in place. Taking `leaf[layer]` first and scattering into
+        that is what a scanned cache does, and costs a slice out, a
+        write back and a whole-leaf copy after the loop. With no `layer`
+        (init, the unrolled model) the leaf is this layer's own and its
+        first block is block 0. The Pallas kernel takes no slice
+        either: it is handed every layer's blocks as one pool and tables
+        offset to this layer's (run by no cell; not measured).
         """
         cfg = self.cfg
         if block_tables is None:
@@ -794,38 +817,33 @@ class Attention(nn.Module):
 
         key_arr, key_box = unbox(cached_key)
         value_arr, value_box = unbox(cached_value)
+        # This layer's first block in the leaf's flat view: the carried
+        # leaf holds `nblocks` blocks a layer, the layer's own starts
+        # at 0.
+        first = 0 if layer is None else layer * nblocks
         # ---- write the current chunk through the table ----
         # Pad tokens past max_seq_len clip into the table's extra last
         # column, which the engine pins to the scratch block.
         log_block = jnp.clip(positions // bs, 0, block_tables.shape[1] - 1)
         phys = jnp.take_along_axis(block_tables, log_block, axis=1)
-        flat_idx = phys * bs + positions % bs          # (B, cur)
-        kf = key_arr.reshape(nblocks * bs, kv_heads, cfg.head_dim)
-        vf = value_arr.reshape(nblocks * bs, kv_heads, cfg.head_dim)
+        flat_idx = ((first + phys) * bs + positions % bs).reshape(-1)
+        kf = key_arr.reshape(-1, kv_heads, cfg.head_dim)
+        vf = value_arr.reshape(-1, kv_heads, cfg.head_dim)
         if kv_quant:
-            k_q, k_s = _int8_quantize(k)
-            v_q, v_s = _int8_quantize(v)
-            kf = kf.at[flat_idx.reshape(-1)].set(
-                k_q.reshape(-1, kv_heads, cfg.head_dim))
-            vf = vf.at[flat_idx.reshape(-1)].set(
-                v_q.reshape(-1, kv_heads, cfg.head_dim))
+            k, k_s = _int8_quantize(k)
+            v, v_s = _int8_quantize(v)
             ks_arr, ks_box = unbox(key_scale)
             vs_arr, vs_box = unbox(value_scale)
-            ksf = ks_arr.reshape(nblocks * bs, kv_heads, 1)
-            vsf = vs_arr.reshape(nblocks * bs, kv_heads, 1)
-            ksf = ksf.at[flat_idx.reshape(-1)].set(
+            ksf = ks_arr.reshape(-1, kv_heads, 1).at[flat_idx].set(
                 k_s.reshape(-1, kv_heads, 1))
-            vsf = vsf.at[flat_idx.reshape(-1)].set(
+            vsf = vs_arr.reshape(-1, kv_heads, 1).at[flat_idx].set(
                 v_s.reshape(-1, kv_heads, 1))
-            rebox(key_scale, ks_box, ksf.reshape(scale_shape))
-            rebox(value_scale, vs_box, vsf.reshape(scale_shape))
-        else:
-            kf = kf.at[flat_idx.reshape(-1)].set(
-                k.reshape(-1, kv_heads, cfg.head_dim))
-            vf = vf.at[flat_idx.reshape(-1)].set(
-                v.reshape(-1, kv_heads, cfg.head_dim))
-        rebox(cached_key, key_box, kf.reshape(cache_shape))
-        rebox(cached_value, value_box, vf.reshape(cache_shape))
+            rebox(key_scale, ks_box, ksf.reshape(ks_arr.shape))
+            rebox(value_scale, vs_box, vsf.reshape(vs_arr.shape))
+        kf = kf.at[flat_idx].set(k.reshape(-1, kv_heads, cfg.head_dim))
+        vf = vf.at[flat_idx].set(v.reshape(-1, kv_heads, cfg.head_dim))
+        rebox(cached_key, key_box, kf.reshape(key_arr.shape))
+        rebox(cached_value, value_box, vf.reshape(value_arr.shape))
         if cfg.decode_kernel in ('pallas', 'pallas_interpret'):
             # Fused kernel: the block-table walk happens IN KERNEL
             # (scalar-prefetched indices drive the K/V tile fetches),
@@ -836,17 +854,19 @@ class Attention(nn.Module):
             # equivalence against the XLA twin below, not bit identity
             # (tests/test_paged_attention.py, test_composition_matrix).
             # Unsupported combos (softcap; non-paged) were refused at
-            # engine construction, never here mid-trace.
+            # engine construction, never here mid-trace. Its pool is
+            # every layer's blocks, its tables offset to this layer's.
+            blocks = lambda flat: flat.reshape((-1, bs) + flat.shape[1:])
             return paged_decode_attention(
-                q, kf.reshape(cache_shape), vf.reshape(cache_shape),
-                block_tables[:, :bps], positions,
-                k_scale=ksf.reshape(scale_shape) if kv_quant else None,
-                v_scale=vsf.reshape(scale_shape) if kv_quant else None,
+                q, blocks(kf), blocks(vf),
+                first + block_tables[:, :bps], positions,
+                k_scale=blocks(ksf) if kv_quant else None,
+                v_scale=blocks(vsf) if kv_quant else None,
                 window=cfg.sliding_window,
                 logit_softcap=cfg.attn_logit_softcap,
                 interpret=cfg.decode_kernel == 'pallas_interpret')
         # ---- gather each row's logical window and attend (XLA) ----
-        gidx = (block_tables[:, :bps, None] * bs +
+        gidx = ((first + block_tables[:, :bps, None]) * bs +
                 jnp.arange(bs)[None, None, :]).reshape(batch, bps * bs)
         k_full = kf[gidx]                              # (B, S, KV, D)
         v_full = vf[gidx]
@@ -979,7 +999,18 @@ class Transformer(nn.Module):
         state_rows = (slots, valid), each (B,) int32 or None, for models
         with recurrent state (models/ssm.py): the slot whose state each
         batch row reads and writes, and how many of its T positions are
-        real (the rest are right pads, or the row is inert)."""
+        real (the rest are right pads, or the row is inert).
+
+        The layers run as one loop over stacked weights. Training scans
+        them and nothing else. A decoding model (`cfg.decode`) whose
+        cache exists takes `cache_carry.carry_layers` instead: the same
+        layer applied to x, with the cache leaves (stacked (L, ...) like
+        the weights) CARRIED by the loop and each layer reading and
+        writing its own part of a leaf in place, at its index. Were the
+        cache scanned like the weights, as it is while init creates it,
+        every leaf would be a scanned input and output: a slice out, a
+        write back into a new buffer and a whole-leaf copy after the
+        loop, three passes over the cache a program."""
         cfg = self.cfg
         # Tied models reuse this table as the unembed projection: init at
         # d^-1/2 so step-0 logits land at O(1) (and the Gemma sqrt(d)
@@ -1013,6 +1044,22 @@ class Transformer(nn.Module):
         x = sharding.constrain(x, 'batch', 'seq', 'act_embed')
         if mode == 'embed':
             return x, positions
+
+        if (cfg.decode and cfg.scan_layers
+                and self.has_variable('cache', 'layers')):
+            # A decoding model whose cache exists: the loop CARRIES the
+            # stacked (L, ...) cache leaves and every layer writes its
+            # part in place, by its index (models/cache_carry.py says
+            # why). A branch of its own, so that the loop below stays
+            # the trainer's, line for line; late import, so that a
+            # trainer never loads it.
+            from skypilot_tpu.models.cache_carry import carry_layers
+            x = carry_layers(cfg, x, positions, block_tables, adapter_ids,
+                             state_rows)
+            if head_rows is not None:
+                x = jnp.take_along_axis(x, head_rows[:, None, None],
+                                        axis=1)
+            return self._head(embed, x)
 
         if cfg.scan_layers:
             layer_cls = _ScannedLayer
