@@ -214,9 +214,8 @@ class LintResult:
 
     def to_dict(self) -> dict:
         """The stable `skytpu lint --json` row (schema pinned by
-        tests/test_skylint.py; bench-harness style: one JSON object on
-        one line, `ok` + `summary` up front for the dryrun
-        supervisor)."""
+        tests/test_skylint.py): one JSON object on one line, `ok` +
+        `summary` up front."""
         by_checker: Dict[str, int] = {}
         for f in self.findings:
             if not f.waived:

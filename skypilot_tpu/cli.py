@@ -586,8 +586,8 @@ def lint(select, as_json, root):
             click.secho(f'skylint error: {e}', fg='red', err=True)
         sys.exit(2)
     if as_json:
-        # Bench-harness style: ONE JSON object on one line, so the
-        # dryrun supervisor / CI can json.loads the last stdout line.
+        # ONE JSON object on one line, so CI can json.loads the last
+        # stdout line.
         click.echo(json_lib.dumps(result.to_dict()))
     else:
         for finding in result.findings:

@@ -177,7 +177,8 @@ class ModelConfig:
     remat_policy: str = 'dots'
     attention_impl: str = 'auto'      # 'auto'|'pallas'|'xla'|'ring'
     # Pallas flash-attention tile sizes (0 ⇒ the kernel's default).
-    # Exposed for per-chip tuning: bench.py sweeps these on real hardware.
+    # Exposed for per-chip tuning; no sweep on the chip has chosen them
+    # yet (ROADMAP S2).
     attn_block_q: int = 0
     attn_block_k: int = 0
     dtype: str = 'bfloat16'           # activation/compute dtype
@@ -366,8 +367,8 @@ TEST_TINY_MOE = _register(ModelConfig(
     num_experts=4, experts_per_token=2, attention_impl='xla', remat=False))
 
 # Flagship architecture at a size that trains on ONE v5e chip (16 GB HBM):
-# ~0.94B params ⇒ ~11 GB for fp32 params + Adam moments. This is the bench
-# model; the 8B/70B configs below are the multi-chip targets.
+# ~0.94B params ⇒ ~11 GB for fp32 params + Adam moments. chip_smoke.py
+# trains it; the 8B/70B configs below are the multi-chip targets.
 LLAMA3_1B = _register(ModelConfig(
     name='llama3-1b', vocab_size=32768, d_model=2048, num_layers=16,
     num_heads=16, num_kv_heads=8, d_mlp=6144, max_seq_len=2048))
@@ -382,8 +383,8 @@ LLAMA3_70B = _register(ModelConfig(
 
 # --- Llama-3.1: same weights shape as Llama-3, 128k context via llama3
 # rope scaling (factor 8 over the 8192-token original window). The
-# flagship long-context serving/finetune target (BASELINE.json names
-# Llama-3.1-8B); pairs with `attention_impl: ring` for sequence
+# flagship long-context serving/finetune target
+# (llm/llama-3_1-finetuning); pairs with `attention_impl: ring` for sequence
 # parallelism past one chip's HBM.
 LLAMA31_8B = _register(ModelConfig(
     name='llama31-8b', vocab_size=128256, d_model=4096, num_layers=32,

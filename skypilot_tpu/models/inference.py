@@ -120,14 +120,6 @@ _DISPATCH_AHEAD_DEPTH = obs.histogram(
     'In-flight decode dispatches (ring depth) observed as each '
     'dispatch is issued — how deep the async lookahead actually runs',
     buckets=(0.0, 1.0, 2.0, 3.0, 4.0, 6.0, 8.0))
-_HOST_GAP_HIST = obs.histogram(
-    'skytpu_engine_tick_host_gap_seconds',
-    'Per decode dispatch: host time between consuming the previous '
-    'dispatch result and issuing the next dispatch — the window in '
-    'which the device has no queued decode work. Chained lookahead '
-    'dispatches (async_depth>0) record 0 by construction.',
-    buckets=(0.00001, 0.00003, 0.0001, 0.0003, 0.001, 0.003, 0.01,
-             0.03, 0.1, 0.3, 1.0))
 _DISPATCH_AHEAD = obs.gauge(
     'skytpu_engine_dispatch_ahead',
     'Decode dispatches in flight beyond the last consumed result '
@@ -1048,8 +1040,7 @@ class ContinuousBatchingEngine:
     positions in Attention._decode_attention.
 
     (The reference gets this from vLLM — SURVEY §2.9; here it is the
-    in-tree TTFT-critical path behind serve replicas and
-    `bench.py --serve`.)
+    in-tree TTFT-critical path behind serve replicas.)
     """
 
     def __init__(self, cfg: 'ModelConfig | str',
@@ -1276,12 +1267,7 @@ class ContinuousBatchingEngine:
         # Lookahead ring: dispatched-but-unconsumed decode steps,
         # oldest first (≤ async_depth after each tick consumes one).
         self._ring: 'collections.deque[_Inflight]' = collections.deque()
-        # Host-gap accounting: monotonic stamp of the last consumed
-        # dispatch result; None after idle/admission ticks so the
-        # histogram records steady-state decode gaps only.
-        self._last_ready: Optional[float] = None
-        self.tick_stats = {'dispatches': 0, 'chained': 0, 'flushes': 0,
-                           'host_gap_s': 0.0, 'gap_samples': 0}
+        self.tick_stats = {'dispatches': 0, 'chained': 0, 'flushes': 0}
         self._prefix_entries = self._new_prefix_index()
         # Cached routing-digest header value, keyed on (index identity,
         # index epoch) — see prefix_digest().
@@ -2001,7 +1987,6 @@ class ContinuousBatchingEngine:
             self._table_cache = None
             self._aids_sig = None
             self._aids_cache = None
-            self._last_ready = None
             if self.max_adapters:
                 # Adapter pool resets WHOLESALE: residency/refcounts die
                 # with the generation (the registry of host weights
@@ -2679,8 +2664,9 @@ class ContinuousBatchingEngine:
         return value
 
     def paged_occupancy(self) -> Dict[str, Any]:
-        """Pool accounting snapshot (bench.py --serve reports it; tests
-        pin ceil(L/block_size) prefix-entry costs against it)."""
+        """Pool accounting snapshot (the benchmark's readers under
+        `perf/` report it; tests pin ceil(L/block_size) prefix-entry
+        costs against it)."""
         if not self.paged_block_size:
             return {}
         self.paged_stats.update(self._cache_bytes_by_kind())
@@ -2741,7 +2727,7 @@ class ContinuousBatchingEngine:
         (or populate) the jit dispatch cache, so the first call pays
         one full extra decode-step compile. The result is cached on
         the engine, and callers keep it off the serving path (server
-        warmup before ready, bench rows, the dryrun)."""
+        warmup before ready, tests)."""
         from skypilot_tpu.parallel import hlo_probe
         if self._hlo_probe_cache is not None:
             return self._hlo_probe_cache
@@ -2785,8 +2771,9 @@ class ContinuousBatchingEngine:
         (parallel/hlo_probe.gather_stats) — the compile-time proxy
         showing the fused pallas call REPLACES the gathered-window
         cluster: a decode_kernel='pallas' engine's program carries
-        fewer gather ops than its XLA twin's (the bench
-        --dryrun-serve-kernel row builds both and diffs the counts).
+        fewer gather ops than its XLA twin's
+        (tests/test_composition_matrix.py builds both and diffs the
+        counts).
         Same AOT-compile cost caveat as decode_hlo_stats; cached."""
         from skypilot_tpu.parallel import hlo_probe
         if self._kernel_probe_cache is not None:
@@ -3647,7 +3634,6 @@ class ContinuousBatchingEngine:
                         self._ring.clear()
                         _DISPATCH_AHEAD.set(0)
                         self._feed = None
-                        self._last_ready = None
                         self._aids_sig = None
                         self._aids_cache = None
                         if self.max_adapters:
@@ -3893,12 +3879,6 @@ class ContinuousBatchingEngine:
         # not freshen the heartbeat and mask a successor's wedge.
         if self._generation == gen:
             self._heartbeat = time_lib.monotonic()
-        if self._admitting_tick:
-            # Admission/prefill work (and its possible compiles) sits
-            # between result consumption and this tick's dispatch:
-            # exclude the tick from the steady-state host-gap
-            # histogram rather than record a bring-up outlier.
-            self._last_ready = None
         self._admitting_tick = False
         active = [i for i, r in enumerate(slots)
                   if r is not None and not r.prefilling]
@@ -3967,7 +3947,6 @@ class ContinuousBatchingEngine:
                     self._wake.wait(timeout=0.05)
                 self._wake.clear()
             _DISPATCH_AHEAD.set(0)
-            self._last_ready = None
             return
         # Chaos harness: tests/SKYTPU_FAULTS can fail or wedge the
         # decode step here; disarmed this is a single boolean check.
@@ -4066,7 +4045,6 @@ class ContinuousBatchingEngine:
             return
         with tracing.phase('engine.tick.land'):
             out_cols = _land(out_dev)
-        self._last_ready = time_lib.monotonic()
         with tracing.phase('engine.tick.emit'):
             self._emit(slots, active, out_cols, None)
 
@@ -4123,7 +4101,6 @@ class ContinuousBatchingEngine:
         temps = self._temps_cache
         if chain is not None:
             tok_dev, pos_dev = chain.feed
-            gap = 0.0   # the device never ran dry: N+1 queued behind N
             self.tick_stats['chained'] += 1
         else:
             cur_sig = tuple(
@@ -4145,8 +4122,6 @@ class ContinuousBatchingEngine:
                                     if i in active_set else 0)
                                    for i in range(self.num_slots)],
                                   jnp.int32, self._repl)
-            gap = (time_lib.monotonic() - self._last_ready
-                   if self._last_ready is not None else None)
         aids = self._aids_for(slots, active_set)
         valid = self._valid_for(active_set)
         self._rng, rng = jax.random.split(self._rng)
@@ -4170,10 +4145,6 @@ class ContinuousBatchingEngine:
             for i in range(self.num_slots))
         self._feed = (feed_next[0], feed_next[1], pred_sig)
         self.tick_stats['dispatches'] += 1
-        if gap is not None:
-            _HOST_GAP_HIST.observe(gap)
-            self.tick_stats['host_gap_s'] += gap
-            self.tick_stats['gap_samples'] += 1
         if self.async_depth:
             out_cols.copy_to_host_async()
             self._ring.append(_Inflight(out_cols, feed_next,
@@ -4232,7 +4203,6 @@ class ContinuousBatchingEngine:
         with tracing.phase('engine.tick.land'):
             out_cols = _land(infl.out)   # waits on the copy the
                                          # dispatch already started async
-        self._last_ready = time_lib.monotonic()
         # The wait above may span a watchdog recovery: never emit into
         # a successor's world.
         self._check_gen(gen)

@@ -101,8 +101,7 @@ def int8_pool_bytes_saved(num_blocks: int, block_size: int,
     per layer. Positive for any head_dim > 4/(fp_bytes-1); at bf16
     with head_dim 128 the pool holds ~1.94x the tokens per byte
     (docs/performance.md has the sizing table). The engine publishes
-    this as the skytpu_engine_paged_int8_bytes_saved gauge and
-    bench.py --serve reports it in the serve row."""
+    this as the skytpu_engine_paged_int8_bytes_saved gauge."""
     per_elem_saved = (fp_bytes - 1) * head_dim - 4
     return (2 * num_layers * num_blocks * block_size * kv_heads
             * per_elem_saved)

@@ -10,9 +10,10 @@ not the gathered whole. These are counts and shapes, not times.
 Consumed by the inference engines (`decode_hlo_stats`, which feeds the
 `skytpu_engine_tp_allreduce_bytes` / `skytpu_engine_tp_collectives`
 gauges), by the trainer (`compiled_step_collectives`, behind
-`train.run --probe-hlo`) and by `bench.py --dryrun-serve-sharded` (the
-MULTICHIP_serve row). Pure text parsing — no jax import, so it is
-testable without a device and adds nothing to engine import time.
+`train.run --probe-hlo`) and by the fake-device drivers under `tests/`
+(`sharded_driver.py`, `zero1_driver.py`). Pure text parsing — no jax
+import, so it is testable without a device and adds nothing to engine
+import time.
 """
 from __future__ import annotations
 
@@ -150,7 +151,7 @@ def gather_stats(hlo_text: str) -> Dict[str, Any]:
     only (after the '=' like collective_stats), so fused-computation
     BODIES still count their ops — on CPU the interpreter-mode pallas
     program and the XLA program both print flat entry computations and
-    the diff is what the bench row pins."""
+    the diff is what tests/test_composition_matrix.py pins."""
     stats: Dict[str, Any] = {op.replace('-', '_'): 0
                              for op in _GATHER_OPS}
     patterns = [(op, re.compile(r'(?<![\w-])' + re.escape(op) + r'\('))
@@ -167,6 +168,12 @@ def gather_stats(hlo_text: str) -> Dict[str, Any]:
     return stats
 
 
+# `%name = f32[512,64]{1,0} op(`: an array-valued definition (a tuple's
+# result starts with '(' and does not match).
+_DEF_RE = re.compile(r'\s*(?:ROOT )?(%[\w.-]+) = ([a-z]\w*)\[([0-9,]*)\]')
+_OPERAND_RE = re.compile(r'%[\w.-]+')
+
+
 def partition_scatter_count(hlo_text: str,
                             shards: Optional[int] = None) -> int:
     """Count partition-addressed scatter slices: ops whose result is an
@@ -179,19 +186,29 @@ def partition_scatter_count(hlo_text: str,
     all-reduce + dynamic-slice(partition-id); TPU/GPU pipelines then run
     the ReduceScatterCreator rewrite that fuses the pair into a native
     `reduce-scatter` op, but the CPU pipeline (the 8-fake-device proxy
-    environment) does not, so the dryrun pins count BOTH forms:
+    environment) does not, so the ZeRO-1 pins count BOTH forms:
     `collective_stats()['reduce_scatter']` for the fused op and this
-    pattern for the unfused one. The ZeRO-1 weight-update-sharding row
-    (`bench.py --dryrun-train-zero1`) is the consumer.
+    pattern for the unfused one (`trainer.compiled_step_collectives`,
+    tests/zero1_driver.py).
 
     Text heuristic, deliberately narrow: a line counts when it has a
-    `%partition-id` operand and the largest same-line operand carries
+    `%partition-id` operand and its largest array operand carries
     exactly `k x` the result's elements — gather-style index plumbing
     (embedding scatter-adds also consult partition-id under a dp-sharded
     batch) never slices a tensor down by the shard count, so it does not
-    match."""
+    match. The CPU pipeline duplicates the slice into each fusion that
+    reads the shard, so the count is of slicing ops, not of gradient
+    leaves; the plain step has none."""
+    # An older XLA printed each operand's shape in the consuming line;
+    # the installed one prints the name alone, so operands with no
+    # inline shape are looked up where they were defined (above their
+    # use: a computation prints in dependency order).
+    defined = {}
     count = 0
     for line in hlo_text.splitlines():
+        m = _DEF_RE.match(line)
+        if m:
+            defined[m.group(1)] = _shape_elems(m.group(2), m.group(3))
         if '%partition-id' not in line or '=' not in line:
             continue
         _lhs, _, rhs = line.partition('=')
@@ -204,6 +221,10 @@ def partition_scatter_count(hlo_text: str,
         if result <= 0:
             continue
         operands = [_shape_elems(dt, dims) for dt, dims in shapes[1:]]
+        if not operands:
+            args = rhs.partition('(')[2].partition(')')[0]
+            operands = [defined.get(name, 0)
+                        for name in _OPERAND_RE.findall(args)]
         biggest = max(operands, default=0)
         if biggest <= result or biggest % result:
             continue

@@ -625,8 +625,8 @@ class InferenceServer:
             # corrected text was WITHHELD by push — emit it now, as
             # the diff against what was actually sent, instead of
             # dropping it: the stale marker cannot be retracted, but
-            # the replacement must not be lost with it (round-5
-            # ADVICE item; regression-pinned).
+            # the replacement must not be lost with it
+            # (regression-pinned, tests/test_fleet_routing.py).
             already = sent['text']
             common = 0
             for a, b in zip(already, full):

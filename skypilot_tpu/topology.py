@@ -39,8 +39,9 @@ class TpuGeneration:
     queued_resources: bool = False
 
 
-# Peak-compute and HBM figures are public datasheet numbers; they feed the MFU
-# math in train/metrics.py and bench.py.
+# Peak-compute and HBM figures are public datasheet numbers, summed a
+# slice by `TpuSlice.bf16_tflops` / `.hbm_gb`. The benchmark reads its
+# peaks from perf/peaks.json, not from here.
 GENERATIONS: Dict[str, TpuGeneration] = {
     g.name: g for g in [
         TpuGeneration('v2', ('v2',), True, 4, 8.0, 45.0, 0.0, 2, 512, (4,)),

@@ -1,4 +1,4 @@
-"""Regression tests for the round-1/2 advisor findings (ADVICE.md):
+"""Regression tests for the round-1/2 advisor findings:
 
 1. (high) replica env race: per-replica tasks built via copy.copy shared
    one _envs dict with the base task — concurrent launch threads raced.

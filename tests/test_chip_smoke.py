@@ -133,23 +133,6 @@ class TestCompileCacheHelper:
         assert out['returned'] is None and out['dir'] is None
 
 
-class TestNoDefaultPeak:
-
-    def test_unknown_device_kind_raises(self):
-        """The CPU (any kind not in topology.GENERATIONS) has no
-        published peak: MFU against a guessed one is refused; a caller
-        that has a peak passes it."""
-        from skypilot_tpu.models import get_config
-        from skypilot_tpu.train import metrics
-        with pytest.raises(ValueError, match='no published bf16 peak'):
-            metrics.detect_chip_peak_tflops()
-        cfg = get_config('test-tiny')
-        with pytest.raises(ValueError, match='no published bf16 peak'):
-            metrics.mfu(cfg, 8, 128, 0.1, num_chips=1)
-        assert metrics.mfu(cfg, 8, 128, 0.1, num_chips=1,
-                           peak_tflops_per_chip=197.0) > 0
-
-
 class TestEngineThatCannotStart:
 
     def test_tp2_cache_init_failure_fails_generate_at_once(self):

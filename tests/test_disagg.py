@@ -391,6 +391,87 @@ class TestEngineHandoff:
             dec._draining = False  # pylint: disable=protected-access
 
 
+_STORM_LONGS = [list(range(300, 320)), list(range(330, 363)),
+                list(range(400, 448))]
+_STORM_CHUNK_BLOCKS = 2
+
+
+class TestHandoffStorm:
+    """Several long prompts through the policy's two-stage handoff onto
+    real engines, one run; each test holds one count of it against what
+    the prompts' lengths predict."""
+
+    @pytest.fixture(scope='class')
+    def storm(self, handoff_engines):
+        from skypilot_tpu.serve.load_balancing_policies import \
+            PrefixAwarePolicy
+        pre, dec, mono = handoff_engines
+        policy = PrefixAwarePolicy(clock=lambda: 0.0)
+        policy.set_ready_replicas(['replica://pre', 'replica://dec'])
+        policy.set_replica_tiers({'replica://pre': 'prefill',
+                                  'replica://dec': 'decode'})
+        rejected0 = dec.ingest_stats['chunks_rejected']
+        prewarm0 = dec.prefix_stats['prewarm_hits']
+        out = {'handoffs': 0, 'chunks': 0, 'blocks': 0,
+               'payload_bytes': 0, 'mismatches': 0}
+        for i, ids in enumerate(_STORM_LONGS):
+            expect, _ = mono.generate(ids, max_new_tokens=4, timeout=300)
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setenv('SKYTPU_SERVE_LB_DISAGG_THRESHOLD', '16')
+                url, info = policy.select(hint={'token_ids': ids,
+                                                'prompt_len': len(ids)})
+            out['handoffs'] += (info['result'] == 'handoff' and
+                                url == 'replica://dec' and
+                                info['prefill_url'] == 'replica://pre')
+            pre.prefill_prefix(ids, timeout=300)
+            chunks = pre.export_prefix_chunks(
+                ids, f'storm-{i}', chunk_blocks=_STORM_CHUNK_BLOCKS)
+            for chunk in chunks:
+                result = dec.ingest_chunk(chunk)
+                out['payload_bytes'] += len(kv.unpack_kv_chunk(chunk)[1])
+            out['chunks'] += len(chunks)
+            out['blocks'] += result['imported_blocks']
+            got, _ = dec.generate(ids, max_new_tokens=4, timeout=300)
+            out['mismatches'] += got != expect
+        out['rejected'] = dec.ingest_stats['chunks_rejected'] - rejected0
+        out['prewarm_hits'] = dec.prefix_stats['prewarm_hits'] - prewarm0
+        out['per_block_bytes'] = sum(
+            int(np.prod(m['shape'], dtype=np.int64)) *
+            np.dtype(m['dtype']).itemsize
+            for m in pre._expected_leaf_meta())  # pylint: disable=protected-access
+        dec._pool.check()  # pylint: disable=protected-access
+        return out
+
+    @staticmethod
+    def _blocks(ids):
+        return -(-len(ids) // 8)
+
+    def test_every_long_prompt_routed_as_a_handoff(self, storm):
+        assert storm['handoffs'] == len(_STORM_LONGS)
+
+    def test_chunk_count_is_what_the_lengths_predict(self, storm):
+        assert storm['chunks'] == sum(
+            -(-self._blocks(ids) // _STORM_CHUNK_BLOCKS)
+            for ids in _STORM_LONGS)
+
+    def test_block_count_is_what_the_lengths_predict(self, storm):
+        assert storm['blocks'] == sum(map(self._blocks, _STORM_LONGS))
+
+    def test_payload_bytes_are_blocks_times_the_leaf_math(self, storm):
+        assert storm['per_block_bytes'] > 0
+        assert storm['payload_bytes'] == storm['per_block_bytes'] * sum(
+            map(self._blocks, _STORM_LONGS))
+
+    def test_no_chunk_rejected(self, storm):
+        assert storm['rejected'] == 0
+
+    def test_every_handoff_admits_as_a_prewarm_hit(self, storm):
+        assert storm['prewarm_hits'] >= len(_STORM_LONGS)
+
+    def test_outputs_equal_the_monolithic_replica(self, storm):
+        assert storm['mismatches'] == 0
+
+
 # ---------------------------------------------------------------------
 # two-stage routing policy
 # ---------------------------------------------------------------------

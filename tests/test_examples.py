@@ -50,7 +50,7 @@ def test_pipeline_example_parses(path):
 
 
 def test_llm_recipes_exist():
-    """The BASELINE.json acceptance recipes (llm/ tree)."""
+    """The acceptance recipes (llm/ tree)."""
     names = {os.path.relpath(p, _REPO) for p in _LLM}
     assert 'llm/llama-3_1-finetuning/sft.yaml' in names
     assert 'llm/jetstream/serve.yaml' in names

@@ -1,5 +1,6 @@
-"""Fleet routing + metrics-driven autoscaling (tier-1, CPU, no engine
-compiles): the routing brain of ROADMAP item 3, unit-level.
+"""Fleet routing + metrics-driven autoscaling (tier-1, CPU; engines are
+built only by TestFleetOverRealEngines): the routing brain of ROADMAP
+item 3, unit-level.
 
 - kv_cache digest: stable cross-process hashes, chunk-aligned prefix
   coverage, epoch bumps on content mutation only;
@@ -11,13 +12,13 @@ compiles): the routing brain of ROADMAP item 3, unit-level.
   full-exclusion → None (the LB-policy satellite);
 - prefix-aware vs round-robin on a shared-prefix workload: strictly
   more prefix hits, simulated with deterministic PrefixIndex-backed
-  fake replicas (the engine-level version runs in bench.py
-  --dryrun-serve-fleet);
+  fake replicas, then over three real engines with a dead replica and
+  a corrupt digest on the wire (TestFleetOverRealEngines);
 - MetricsAutoscaler: pressure math, hysteresis, flap damping,
   DRAINING-awareness, decision-log replay;
 - serve/server satellites: fleet-intel response headers
   (X-SkyTPU-Queue-Depth / X-SkyTPU-Prefix-Digest) and the
-  _delta_decoder flush() corrected-tail fix (round-5 ADVICE item).
+  _delta_decoder flush() corrected-tail fix (a round-5 review finding).
 """
 import threading
 import types
@@ -347,6 +348,126 @@ class TestPrefixAwareBeatsRoundRobin:
         assert rr_hits == 0                   # 5 groups never re-land
 
 
+_FLEET_GROUPS = [list(range(s, s + 24)) for s in (1, 60, 120, 180, 240)]
+_FLEET_ROUNDS = 3
+_DEAD_URL = 'replica://zombie'
+
+
+def _fleet_prompts():
+    for round_i in range(_FLEET_ROUNDS):
+        for gi, group in enumerate(_FLEET_GROUPS):
+            yield gi, round_i, group + [400 + round_i]
+
+
+def _fleet_engine():
+    import dataclasses
+
+    from skypilot_tpu.models import get_config
+    from skypilot_tpu.models.inference import ContinuousBatchingEngine
+    cfg = dataclasses.replace(
+        get_config('test-tiny'), dtype='float32', param_dtype='float32',
+        max_seq_len=64, remat=False)
+    return ContinuousBatchingEngine(cfg, num_slots=2, paged_block_size=8,
+                                    prefix_cache=6)
+
+
+def _route_through_real_engines(policy, reference) -> dict:
+    """The shared-prefix workload over three REAL engines (paged,
+    prefix cache on), digests and queue depths fed back as the LB
+    learns them in-band; a fourth replica is dead while advertising
+    the most attractive digest for group 4, and one digest arrives
+    corrupt. Counts only."""
+    engines = [_fleet_engine() for _ in range(3)]
+    urls = [f'replica://{i}' for i in range(3)]
+    policy.set_ready_replicas(urls + [_DEAD_URL])
+    policy.observe_response(_DEAD_URL, {
+        'X-SkyTPU-Queue-Depth': '0',
+        'X-SkyTPU-Prefix-Digest': 'v1:8:1:' + ','.join(
+            prefix_route_hash(_FLEET_GROUPS[4][:k * 8])
+            for k in range(1, 4)),
+    })
+    attempts = served = rejected = mismatches = 0
+    try:
+        for gi, round_i, ids in _fleet_prompts():
+            tried = set()
+            while True:
+                attempts += 1
+                url, _info = policy.select(
+                    exclude=tried,
+                    hint={'token_ids': ids, 'prompt_len': len(ids)})
+                assert url is not None, 'routing failed closed'
+                if url == _DEAD_URL:
+                    # A transport error: the client retries elsewhere.
+                    tried.add(url)
+                    continue
+                engine = engines[urls.index(url)]
+                policy.note_routed(url)
+                toks, _stats = engine.generate(ids, max_new_tokens=4,
+                                               timeout=300)
+                policy.note_done(url)
+                headers = {'X-SkyTPU-Queue-Depth':
+                           str(engine.queue_load())}
+                digest = engine.prefix_digest()
+                if digest:
+                    headers['X-SkyTPU-Prefix-Digest'] = digest
+                if gi == 0 and round_i == 1:
+                    headers['X-SkyTPU-Prefix-Digest'] = 'garbage!!'
+                if policy.observe_response(url, headers) == 'rejected':
+                    rejected += 1
+                mismatches += toks != reference[(gi, round_i)]
+                served += 1
+                break
+        hits = sum(e.prefix_stats['hits'] for e in engines)
+        misses = sum(e.prefix_stats['misses'] for e in engines)
+    finally:
+        for engine in engines:
+            engine.stop()
+    return {'hits': hits, 'misses': misses, 'attempts': attempts,
+            'served': served, 'rejected': rejected,
+            'mismatches': mismatches}
+
+
+class TestFleetOverRealEngines:
+    """One run a policy; each test reads one count of it."""
+
+    @pytest.fixture(scope='class')
+    def runs(self):
+        oracle = _fleet_engine()
+        try:
+            reference = {
+                (gi, ri): oracle.generate(ids, max_new_tokens=4,
+                                          timeout=300)[0]
+                for gi, ri, ids in _fleet_prompts()}
+        finally:
+            oracle.stop()
+        return {
+            'round_robin': _route_through_real_engines(
+                RoundRobinPolicy(), reference),
+            'prefix_aware': _route_through_real_engines(
+                PrefixAwarePolicy(), reference),
+        }
+
+    def test_prefix_aware_hit_ratio_above_round_robin(self, runs):
+        def ratio(run):
+            return run['hits'] / max(1, run['hits'] + run['misses'])
+        assert ratio(runs['prefix_aware']) > ratio(runs['round_robin']), \
+            runs
+
+    @pytest.mark.parametrize('policy', ['round_robin', 'prefix_aware'])
+    def test_outputs_equal_a_single_healthy_replica(self, runs, policy):
+        assert runs[policy]['served'] == \
+            _FLEET_ROUNDS * len(_FLEET_GROUPS)
+        assert runs[policy]['mismatches'] == 0, runs[policy]
+
+    def test_corrupt_digest_is_rejected_and_counted(self, runs):
+        assert runs['prefix_aware']['rejected'] >= 1, runs
+
+    @pytest.mark.parametrize('policy', ['round_robin', 'prefix_aware'])
+    def test_dead_replica_bounds_retry_amplification(self, runs, policy):
+        run = runs[policy]
+        assert run['attempts'] / run['served'] <= 2.0, run
+
+
 # ---------------------------------------------------------------------
 # metrics-driven autoscaler
 # ---------------------------------------------------------------------
@@ -593,7 +714,7 @@ class TestDeltaDecoderResync:
 
     def test_flush_emits_corrected_tail_after_stale_replacement_char(
             self):
-        """The round-5 ADVICE item: a stale '�' was emitted, then the
+        """The round-5 review finding: a stale '�' was emitted, then the
         canonical decode replaced it — flush must emit the corrected
         tail (diff against what was actually sent), not drop it."""
         table = {(1,): '�', (1, 2): '��', (1, 2, 3): '€x'}

@@ -311,6 +311,29 @@ class TestMixedAdapterBatching:
         finally:
             engine.stop()
 
+    def test_mixed_batch_across_slo_tiers_bit_identity(
+            self, adapter_trees, references):
+        """Tenancy's two axes at once: each adapter under another SLO
+        tier in one batch; every request still equals its dedicated
+        engine, whatever order the tiers admit them in."""
+        base_params, refs = references
+        engine = ContinuousBatchingEngine(
+            _cfg(), params=base_params, num_slots=4, max_adapters=3,
+            **LORA_KW, **CELLS['paged'])
+        try:
+            for name, tree in adapter_trees.items():
+                engine.load_adapter(name, tree)
+            tiers = ('interactive', 'standard', 'batch')
+            futures = {'base': engine.submit(PROMPT, max_new_tokens=8)}
+            for i, name in enumerate(adapter_trees):
+                futures[name] = engine.submit(
+                    PROMPT, max_new_tokens=8, adapter=name,
+                    priority=tiers[i % len(tiers)])
+            for name, future in futures.items():
+                assert future.result(timeout=300)[0] == refs[name], name
+        finally:
+            engine.stop()
+
     def test_adapter_requests_bypass_prefix_cache(self, adapter_trees,
                                                   references):
         """Cached prefix KV is adapter-dependent (v is a LoRA target):

@@ -9,7 +9,7 @@ behind `skytpu lint` — docs/static-analysis.md has the catalog.
 - waivers: honored, expired-resurfaces, unmatched-resurfaces,
   malformed-file → LintError;
 - the CLI contract: exit codes 0/1/2 and the stable skylint/1 --json
-  row (bench-harness style: one JSON object on one line);
+  row (one JSON object on one line);
 - the tier-1 pin: the REAL tree holds zero unwaived findings in
   bounded time — the debt this analyzer surfaced is fixed or waived,
   and stays that way.
@@ -637,22 +637,6 @@ class TestCliContract:
                           'waived', 'waiver_reason'}
         assert f['checker'] == 'wall-clock-duration'
         assert f['path'] == 'pkg/timing.py'
-
-    def test_bench_dryrun_lint_row(self):
-        """The dryrun-supervisor surface: `bench.py --dryrun-lint`
-        emits ONE bench-contract JSON row (metric/value/unit/ok) with
-        value == unwaived findings == 0 on the pinned tree."""
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO_ROOT, 'bench.py'),
-             '--dryrun-lint'],
-            capture_output=True, text=True, cwd=REPO_ROOT, timeout=180,
-            env={**os.environ, 'JAX_PLATFORMS': 'cpu'})
-        assert proc.returncode == 0, (proc.stdout, proc.stderr)
-        row = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert row['metric'] == 'SKYLINT dryrun'
-        assert row['ok'] is True and row['value'] == 0.0
-        assert row['unit'] == 'unwaived findings'
-        assert row['checkers'] >= 5
 
     def test_exit_2_on_internal_error(self, tmp_path):
         proc = run_cli(['--select', 'no-such-checker'])

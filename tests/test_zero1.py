@@ -162,6 +162,38 @@ class TestPartitionScatterProbe:
         assert hlo_probe.partition_scatter_count(
             '%r = f32[2] add(%a, %b)') == 0
 
+    # The installed XLA names its operands and prints no shape beside
+    # them; a tuple-valued definition (the all-reduce) has no one shape.
+    HLO_NAMES_ONLY = '''
+  %partition-id.4 = u32[] partition-id()
+  %all-reduce.60 = (f32[512,64]{1,0}, f32[512,64]{1,0}) all-reduce(%a, %b), replica_groups={}
+  %gte.1 = f32[512,64]{1,0} get-tuple-element(%all-reduce.60), index=1
+  %norm = f32[] fusion(%w.1, %w.2), kind=kLoop
+  %param.21 = f32[64,64]{1,0} parameter(20)
+  %scatter = f32[64,64]{1,0} fusion(%norm, %gte.1, %partition-id.4), kind=kLoop
+  %update = f32[64,64]{1,0} fusion(%param.21, %scatter, %norm, %partition-id.4), kind=kLoop
+  %plain = f32[64,64]{1,0} fusion(%norm, %gte.1), kind=kLoop
+  %halver = f32[256,64]{1,0} fusion(%gte.1, %partition-id.4), kind=kLoop
+  ROOT %out = (f32[64,64]{1,0}) tuple(%update)
+'''
+
+    def test_operands_named_without_shapes_are_looked_up(self):
+        from skypilot_tpu.parallel import hlo_probe
+        # %scatter keeps 1/8 of %gte.1; %update's operands are all its
+        # own size; %plain has no partition-id; %halver is k=2.
+        assert hlo_probe.partition_scatter_count(
+            self.HLO_NAMES_ONLY, shards=8) == 1
+        assert hlo_probe.partition_scatter_count(self.HLO_NAMES_ONLY) == 2
+
+    def test_tuple_valued_operand_is_not_a_shape(self):
+        from skypilot_tpu.parallel import hlo_probe
+        # An op that consumes the all-reduce's TUPLE beside partition-id
+        # slices nothing: the tuple's first element is not its size.
+        text = self.HLO_NAMES_ONLY + (
+            '  %t = f32[64,64]{1,0} fusion(%all-reduce.60, '
+            '%partition-id.4), kind=kLoop\n')
+        assert hlo_probe.partition_scatter_count(text, shards=8) == 1
+
 
 @pytest.mark.sharded
 @pytest.mark.deadline(900)
