@@ -506,22 +506,25 @@ class _Inflight:
 
     Lives in the engine's lookahead RING (oldest first, at most
     async_depth entries after each tick consumes one): every entry was
-    chained in-graph off the previous one's feed, so all entries share
-    one slot snapshot — churn flushes the whole ring. `out` is the
-    device array of sampled columns (num_slots, k) with
-    copy_to_host_async already started; `feed` is the NEXT step's
-    device-resident input (tokens, positions) returned in-graph by the
-    dispatch; `reqs` snapshots slot→request identity at dispatch time so
+    fed in-graph off the previous one's feed, with the rows of slots
+    that joined since laid over it (`_join_feed`). Entries need not
+    share a slot population: a slot whose finish the host foresees
+    drops out of later entries, a slot that joins appears in them.
+    `out` is the device array of sampled columns (num_slots, k) with
+    copy_to_host_async already started (the NEXT step's input it
+    returned in-graph is the engine's `_feed`); `reqs` snapshots
+    slot→request identity at dispatch time so
     emission up to async_depth ticks later can discard columns whose
     slot changed hands (EOS overshoot, deadline kills, admission
-    churn); `gen` ties the dispatch to the engine generation that
-    issued it — a watchdog recovery discards the whole ring."""
+    churn; a preemption blanks its slot's entry, because the same
+    request object may come back); `gen` ties the dispatch to the
+    engine generation that issued it — a watchdog recovery discards
+    the whole ring."""
 
-    __slots__ = ('out', 'feed', 'reqs', 'active', 'k', 'gen')
+    __slots__ = ('out', 'reqs', 'active', 'k', 'gen')
 
-    def __init__(self, out, feed, reqs, active, k, gen):
+    def __init__(self, out, reqs, active, k, gen):
         self.out = out
-        self.feed = feed
         self.reqs = reqs
         self.active = active
         self.k = k
@@ -959,7 +962,7 @@ class _Request:
                  'prefilling', 'prefill_pos', 'seq', 'trace',
                  'admit_time', 'tier', 'adapter', 'adapter_slot',
                  'adapter_pool', 'context', 'preemptions',
-                 'admit_mono', 'prefill_chunks')
+                 'admit_mono', 'prefill_chunks', 'inflight')
 
     def __init__(self, ids, max_new_tokens, temperature, eos_id, future,
                  on_token=None, deadline=None, tier='standard',
@@ -977,6 +980,10 @@ class _Request:
         self.first_token_time: Optional[float] = None
         self.tokens: list = []
         self.next_pos = 0  # cache position the NEXT input token writes to
+        # Decode steps dispatched for this request whose tokens the host
+        # has not consumed yet (the lookahead ring's share of it): the
+        # next dispatch feeds position next_pos + inflight.
+        self.inflight = 0
         # Streaming hook: called from the ENGINE thread with each token
         # as it lands, then once with None after the future resolves.
         self.on_token = on_token
@@ -1061,7 +1068,7 @@ class ContinuousBatchingEngine:
                  paged_block_size: int = 0,
                  paged_num_blocks: Optional[int] = None,
                  prefill_chunk: int = 0,
-                 async_depth: int = 0,
+                 async_depth: int = 1,
                  tier: str = 'monolithic',
                  ingest_ttl: float = 60.0,
                  max_adapters: int = 0,
@@ -1232,20 +1239,26 @@ class ContinuousBatchingEngine:
             _PAGED_INT8_SAVED.set(self.paged_int8_bytes_saved)
         # -------- async decode pipeline (docs/performance.md) --------
         # async_depth=N ⇒ a RING of up to N in-flight decode
-        # dispatches: each chains in-graph off the previous one's
-        # device feed before the host has seen any of their tokens
-        # (JAX async dispatch queues them back to back);
-        # copy_to_host_async lands the oldest while the device computes
-        # the rest, and all host work — deadlines, queue purge,
-        # admission, _emit, metrics — overlaps device compute.
-        # EOS/termination is detected up to N steps late; overshoot
-        # columns are discarded by request identity (causally masked
-        # stale cache, same argument as speculative rejects). Any
-        # churn flushes the whole ring — one sync tick per churn
-        # event. 0 = synchronous ticks. Deeper rings pay where one
-        # host round-trip spans several device steps; they also
-        # multiply EOS-overshoot waste (docs/performance.md: when
-        # deeper lookahead pays).
+        # dispatches: a tick queues the next step off the newest one's
+        # in-graph device feed BEFORE it waits on any result (JAX async
+        # dispatch queues them back to back); copy_to_host_async lands
+        # the oldest while the device computes the rest, and all host
+        # work — deadlines, queue purge, admission, _emit, metrics —
+        # overlaps device compute. The ring rides through churn: a slot
+        # whose finish the host can foresee (max_new_tokens, the
+        # window) is left out of later dispatches while its last step
+        # is still pending (_spent); a slot that joins has its first
+        # token and position laid over the feed on the device
+        # (_join_feed), and that token is landed after the step is
+        # queued. What cannot be foreseen — EOS, a deadline kill, a
+        # cancellation, a preemption — is detected up to N steps late
+        # and its columns discarded by request identity (causally
+        # masked stale cache, same argument as speculative rejects).
+        # Speculative engines still flush around every spec tick and
+        # sample first tokens on the host. 1 is the default; 0 =
+        # synchronous ticks. Deeper rings pay where one host round-trip
+        # spans several device steps; they also multiply EOS-overshoot
+        # waste (docs/performance.md: when deeper lookahead pays).
         self.async_depth = max(0, async_depth)
         # Decode-tick block-table cache (see _tick): rebuilt only when
         # the per-slot fingerprint changes.
@@ -1255,18 +1268,29 @@ class ContinuousBatchingEngine:
         # GRAPH, the next step's (tokens, positions) so a steady-state
         # tick feeds the device from the device — no np.asarray on the
         # critical path, no host→device re-upload of tokens/positions.
-        # `sig` keys the feed to the exact host state it predicts
-        # ((req.seq, next_pos) per active slot); any churn —
-        # admission, finish, deadline kill, spec tick — misses and
-        # rebuilds from host. Temps change only with slot occupancy, so
-        # they cache under their own value signature (the _table_sig
-        # pattern). Steady state uploads nothing (pinned by test).
+        # `sig` keys each row of the feed to the host state it
+        # predicts ((req.seq, next_pos + inflight) for a slot the
+        # dispatch carried, None otherwise): a dispatch takes the feed
+        # when every row it carries matches. With the ring up a joining
+        # slot's row is written on the device (_join_feed); a
+        # synchronous engine rebuilds the feed from host lists instead.
+        # Temps change only with slot occupancy, so they cache under
+        # their own value signature (the _table_sig pattern). Steady
+        # state uploads nothing (pinned by test).
         self._feed: Optional[tuple] = None          # (tok, pos, sig)
         self._temps_sig: Optional[tuple] = None
         self._temps_cache = None
         # Lookahead ring: dispatched-but-unconsumed decode steps,
         # oldest first (≤ async_depth after each tick consumes one).
         self._ring: 'collections.deque[_Inflight]' = collections.deque()
+        # First tokens sampled on the device this tick and not landed
+        # yet: (the tick's slot table, slot, request, device scalar).
+        # Landed after the tick's decode step is queued (_land_joins);
+        # empty between ticks.
+        self._joins: list = []
+        # A spec tick drafts from host tokens and emits in its own tick,
+        # so a speculative engine keeps the host-side first token.
+        self._join_ahead = self.async_depth > 0 and speculative <= 0
         self.tick_stats = {'dispatches': 0, 'chained': 0, 'flushes': 0}
         self._prefix_entries = self._new_prefix_index()
         # Cached routing-digest header value, keyed on (index identity,
@@ -1402,6 +1426,7 @@ class ContinuousBatchingEngine:
                                          donate_argnames=('cache',))
         self._cow_fn = jax.jit(self._cow_copy_impl,
                                donate_argnames=('cache',))
+        self._join_feed = jax.jit(self._join_feed_impl)
         # Adapter slot write: donate the old stack (one device-side
         # dynamic_update_slice per leaf; runs in the tick thread only).
         self._adapter_write = jax.jit(self._adapter_write_impl,
@@ -1733,6 +1758,29 @@ class ContinuousBatchingEngine:
 
         return jax.tree.map(cp, cache)
 
+    def _join_feed_impl(self, tokens, positions, logits_row, temp, rng,
+                        where):
+        """Lay a joining slot over the device feed: sample its first
+        token from the last prefill logits (as `_sample` does on the
+        host: argmax, or a filtered categorical draw above temperature
+        0) and write token and position into row where[0] of the
+        (tokens, positions) the next decode step is fed with; where[1]
+        is the position. Returns (first token, tokens, positions). The
+        first token never visits the host on its way into the step: the
+        step is queued behind this program and the token is landed
+        after it. One small program an engine, run on joining ticks
+        only; the decode program keeps its signature."""
+        scaled = apply_logit_filters(
+            logits_row.astype(jnp.float32) / jnp.maximum(temp, 1e-6),
+            self.top_k, self.top_p)
+        first = jnp.where(temp <= 0, jnp.argmax(logits_row),
+                          jax.random.categorical(rng, scaled)
+                          ).astype(jnp.int32)
+        slot = where[0]
+        return (self._repl_constrain(first),
+                self._repl_constrain(tokens.at[slot].set(first)),
+                self._repl_constrain(positions.at[slot].set(where[1])))
+
     def _verify_impl(self, params, cache, tokens, positions, temps, rng,
                      tables=None, adapters=None, aids=None):
         """Speculative verification: ONE forward over (num_slots, K+1)
@@ -1979,6 +2027,7 @@ class ContinuousBatchingEngine:
             # chain from any of them. (The stale thread also re-checks
             # generation before emitting, so this is belt and braces.)
             self._ring.clear()
+            self._joins = []
             _DISPATCH_AHEAD.set(0)
             self._feed = None
             self._temps_sig = None
@@ -2166,6 +2215,62 @@ class ContinuousBatchingEngine:
             logits_row.astype(jnp.float32) / max(temperature, 1e-6),
             self.top_k, self.top_p)
         return int(_land(jax.random.categorical(rng, scaled)))
+
+    def _first_token(self, slots, slot: int, req: '_Request',
+                     logits_row, position: int) -> None:
+        """A prompt's last prefill logits are in: seed the request's
+        first token and its decode position. With the ring up the
+        token is sampled on the device and laid over the decode feed
+        (`_join_feed`), so the tick can queue the next decode step
+        before it waits for it; `_land_joins` hands it to the client
+        later in the same tick. A synchronous or speculative engine
+        samples and lands it here."""
+        req.next_pos = position
+        req.inflight = 0
+        if not self._join_ahead:
+            self._deliver_first(req, slot,
+                                self._sample(logits_row, req.temperature))
+            return
+        rng = self._rng     # a greedy row never reads it
+        if req.temperature > 0:
+            self._rng, rng = jax.random.split(self._rng)
+        feed = self._feed
+        if feed is None:
+            blank = _upload([0] * self.num_slots, jnp.int32, self._repl)
+            feed = (blank, blank, (None,) * self.num_slots)
+        first, tok, pos = self._join_feed(
+            feed[0], feed[1], logits_row,
+            _upload(req.temperature, jnp.float32, self._repl), rng,
+            _upload([slot, position], jnp.int32, self._repl))
+        first.copy_to_host_async()
+        sig = feed[2][:slot] + ((req.seq, position),) + feed[2][slot + 1:]
+        self._feed = (tok, pos, sig)
+        self._joins.append((slots, slot, req, first))
+
+    def _deliver_first(self, req: '_Request', slot: int,
+                       first: int) -> None:
+        self._note_first_token(req, slot)
+        req.tokens.append(first)
+        _TOKENS_TOTAL.inc()  # the first token lands here, not in _emit
+        self._notify(req, first)
+
+    def _land_joins(self, gen: int) -> None:
+        """Land the first tokens this tick sampled on the device and
+        send each to its client: after the tick's decode step is
+        queued, or before anything that reads a request's tokens on the
+        host (a flush, a rebuild of the feed)."""
+        if not self._joins:
+            return
+        joins, self._joins = self._joins, []
+        with tracing.phase('engine.tick.land'):
+            landed = [int(_land(first)) for *_, first in joins]
+        # The wait above may span a watchdog recovery: never emit into
+        # a successor's world.
+        self._check_gen(gen)
+        with tracing.phase('engine.tick.emit'):
+            for (slots, slot, req, _), first in zip(joins, landed):
+                if slots[slot] is req:  # its admission held
+                    self._deliver_first(req, slot, first)
 
     def _bucket(self, length: int) -> int:
         bucket = 16
@@ -2468,12 +2573,7 @@ class ContinuousBatchingEngine:
             if req.prefill_pos >= total:
                 req.prefilling = False
                 self._store_prefix_paged(req)
-                first = self._sample(logits, req.temperature)
-                self._note_first_token(req, slot)
-                req.tokens.append(first)
-                _TOKENS_TOTAL.inc()
-                self._notify(req, first)
-                req.next_pos = total
+                self._first_token(slots, slot, req, logits, total)
 
     # ------------- multi-LoRA adapter pool (serve/tenancy) -------------
 
@@ -2680,6 +2780,12 @@ class ContinuousBatchingEngine:
             # slots whose recurrent state belongs to a request now
             'state_slots_used': (sum(r is not None for r in self._slots)
                                  if self._recurrent else 0),
+            # how often the lookahead engages (tick_stats): decode
+            # dispatches, those queued off the device feed while an
+            # earlier one was still unconsumed, and ring flushes
+            'decode_dispatches': self.tick_stats['dispatches'],
+            'decode_chained': self.tick_stats['chained'],
+            'ring_flushes': self.tick_stats['flushes'],
         }
         if self._tp > 1 and self._cache is not None:
             # Per-device view: each device holds its kv-head shard of
@@ -3480,13 +3586,7 @@ class ContinuousBatchingEngine:
             # (chat turns append); cache1 is not donated anywhere, so
             # holding it is safe.
             self._store_prefix(context, cache1)
-        first = self._sample(logits, req.temperature)
         req.prefill_chunks += 1
-        self._note_first_token(req, slot)
-        req.tokens.append(first)
-        _TOKENS_TOTAL.inc()  # the first token lands here, not in _emit
-        self._notify(req, first)
-        req.next_pos = true_len
         cache = self._insert(self._cache, cache1,
                              _upload(slot, jnp.int32, self._repl))
 
@@ -3498,6 +3598,7 @@ class ContinuousBatchingEngine:
             self._commit_gen(gen, _commit)
         else:
             _commit()
+        self._first_token(self._slots, slot, req, logits, true_len)
 
     @staticmethod
     def _notify(req: '_Request', token) -> None:
@@ -3632,6 +3733,7 @@ class ContinuousBatchingEngine:
                         # never be emitted — its requests were just
                         # failed above.
                         self._ring.clear()
+                        self._joins = []
                         _DISPATCH_AHEAD.set(0)
                         self._feed = None
                         self._aids_sig = None
@@ -3766,6 +3868,12 @@ class ContinuousBatchingEngine:
                              else 0.0)
                     slots[slot] = None
                     self._release_blocks(req)
+                    # Its pending columns are shed: the same request
+                    # object comes back, maybe to this very slot, so
+                    # identity alone would not tell them stale.
+                    for entry in self._ring:
+                        entry.reqs[slot] = None
+                    req.inflight = 0
                     req.prefilling = False
                     req.prefill_pos = 0
                     req.next_pos = 0
@@ -3880,11 +3988,10 @@ class ContinuousBatchingEngine:
         if self._generation == gen:
             self._heartbeat = time_lib.monotonic()
         self._admitting_tick = False
-        active = [i for i, r in enumerate(slots)
-                  if r is not None and not r.prefilling]
         # Saturation signals, refreshed once per tick (cheap: gauge sets
         # behind the enabled-check).
-        _ACTIVE_SLOTS.set(len(active))
+        _ACTIVE_SLOTS.set(sum(r is not None and not r.prefilling
+                              for r in slots))
         _QUEUE_DEPTH.set(queue.qsize())
         if obs.enabled():
             # Per-tier ADMISSION-QUEUE depth (matching the global
@@ -3936,17 +4043,20 @@ class ContinuousBatchingEngine:
             # nothing from the ring may ever be emitted.
             ring.clear()
             _DISPATCH_AHEAD.set(0)
+        # A slot whose last step is already queued (_spent) decodes no
+        # further: it rides inert until that step is consumed.
+        active = self._decodable(slots)
         if not active:
             if ring:
-                # Lookahead overshoot for requests that all finished
-                # (or were killed) at the previous emits: consume the
-                # columns so nothing dangles, discarding by identity.
-                self._flush_ring(slots, gen)
+                # Steps still pending for slots that are spent (or
+                # overshoot for requests that finished or were killed):
+                # land the oldest, discarding by identity.
+                self._consume_oldest(slots, gen)
             elif not prefilling:
                 with tracing.phase('engine.tick.wait'):
                     self._wake.wait(timeout=0.05)
                 self._wake.clear()
-            _DISPATCH_AHEAD.set(0)
+            _DISPATCH_AHEAD.set(len(ring))
             return
         # Chaos harness: tests/SKYTPU_FAULTS can fail or wedge the
         # decode step here; disarmed this is a single boolean check.
@@ -3963,7 +4073,7 @@ class ContinuousBatchingEngine:
                 # per-request stream would reorder.
                 self._flush_ring(slots, gen)
                 self.tick_stats['flushes'] += 1
-                active = [i for i in active if slots[i] is not None]
+                active = self._decodable(slots)
                 if not active:
                     return
             with tracing.phase('engine.tick.dispatch'):
@@ -3984,99 +4094,119 @@ class ContinuousBatchingEngine:
                             self._trim_blocks(slots[i])
                 return
             # else: a slot is near the cache window — single-step tick.
-        # All-slots decode: K scanned steps per dispatch when nothing is
-        # waiting to be admitted (admission latency stays bounded by one
-        # chunk), a single step otherwise.
-        k = 1
-        if self.decode_chunk > 1 and self._queue.empty() \
-                and not prefilling:
-            # Full chunks only: k ∈ {1, decode_chunk} so serving never
-            # JIT-compiles a new scan length mid-stream. Slots whose
-            # cache window can't absorb a full chunk finish on single
-            # steps; a mid-prefill slot also forces single steps so its
-            # next chunk isn't delayed by a whole decode scan.
-            window_ok = all(
-                self.cfg.max_seq_len - slots[i].next_pos
-                >= self.decode_chunk for i in active)
-            if window_ok:
-                k = self.decode_chunk
-        if ring:
-            if self._can_chain(slots, active, k):
-                # Steady state: top the ring up to async_depth+1
-                # chained dispatches off the newest in-graph feed
-                # BEFORE consuming the oldest — the device queues them
-                # back to back while every line of host work below
-                # (emit, metrics, and the next tick's deadline/queue/
-                # admission scan) overlaps its compute. _can_chain is
-                # re-checked per added dispatch: the pending horizon
-                # grows with each one.
-                with tracing.phase('engine.tick.dispatch'):
-                    while (len(ring) <= self.async_depth and
-                           self._can_chain(slots, active, k)):
-                        self._dispatch(slots, active, k, gen,
-                                       chain=ring[-1])
-                self._consume_oldest(slots, gen)
-                _DISPATCH_AHEAD.set(len(ring))
-                return
-            # Perturbation (admission/finish/EOS churn, window edge,
-            # predictable termination): drain the whole pipeline, then
-            # dispatch this tick normally off host state.
+        if ring and not self._feed_fits(slots, active):
+            # The device feed does not hold what this dispatch needs
+            # (a first token that was sampled on the host: a
+            # speculative engine's join): drain the whole pipeline,
+            # then dispatch off host state.
+            self._land_joins(gen)
             self._flush_ring(slots, gen)
             self.tick_stats['flushes'] += 1
-            # The flushed emits may have finished slots / advanced
-            # positions: recompute the dispatch set.
-            active = [i for i in active if slots[i] is not None]
+            active = self._decodable(slots)
             if not active:
-                _DISPATCH_AHEAD.set(0)
                 return
-            if k > 1 and not all(
-                    self.cfg.max_seq_len - slots[i].next_pos >= k
-                    for i in active):
-                k = 1
+        # Queue the next step BEFORE waiting on any result: off the
+        # newest pending step's in-graph feed when there is one, and on
+        # up to async_depth ahead of the oldest — the device runs them
+        # back to back while every line of host work below (landing,
+        # emit, metrics, and the next tick's deadline/queue/admission
+        # scan) overlaps its compute. The first dispatch carries the
+        # slots that joined this tick; their first tokens are landed
+        # right behind it, so every later count of a request's tokens
+        # is whole.
         with tracing.phase('engine.tick.dispatch'):
-            out_dev = self._dispatch(slots, active, k, gen)
-            # Pipeline fill: chain straight up to depth — these
-            # dispatches are consumed (and emitted) up to async_depth
-            # ticks late; the oldest's host copy is already in flight.
-            while (len(ring) < self.async_depth and
-                   self._can_chain(slots, active, k)):
-                self._dispatch(slots, active, k, gen, chain=ring[-1])
-        if self.async_depth:
-            return
-        with tracing.phase('engine.tick.land'):
-            out_cols = _land(out_dev)
-        with tracing.phase('engine.tick.emit'):
-            self._emit(slots, active, out_cols, None)
+            while active and len(ring) <= self.async_depth:
+                self._dispatch(
+                    slots, active, self._step_count(slots, active,
+                                                    prefilling),
+                    gen, chain=ring[-1] if ring else None)
+                if self._joins:
+                    break
+                active = self._decodable(slots)
+        self._land_joins(gen)
+        # A synchronous engine (async_depth=0) lands the step it just
+        # queued; with the ring up the oldest pending one is landed,
+        # while the device runs the rest.
+        if len(ring) > self.async_depth:
+            self._consume_oldest(slots, gen)
+        _DISPATCH_AHEAD.set(len(ring))
+
+    def _spent(self, req: '_Request') -> bool:
+        """True iff the steps already queued for `req` are known to
+        finish it (max_new_tokens, or the window's end): its tokens are
+        emitted and the slot freed when the last of them is consumed,
+        and no further step is dispatched for it meanwhile. EOS cannot
+        be foreseen and costs up to async_depth discarded steps."""
+        ahead = req.inflight
+        return ahead > 0 and (
+            len(req.tokens) + ahead >= req.max_new_tokens or
+            req.next_pos + ahead + 1 >= self.cfg.max_seq_len)
+
+    def _decodable(self, slots) -> list:
+        """Slots the next decode dispatch carries: past prefill and not
+        yet provided with their last step."""
+        return [i for i, r in enumerate(slots)
+                if r is not None and not r.prefilling
+                and not self._spent(r)]
+
+    def _step_count(self, slots, active, prefilling) -> int:
+        """Steps the next dispatch scans: decode_chunk of them when
+        nothing is waiting to be admitted (admission latency stays
+        bounded by one chunk), a single step otherwise. Full chunks
+        only: k ∈ {1, decode_chunk} so serving never JIT-compiles a new
+        scan length mid-stream. Slots whose cache window can't absorb a
+        full chunk finish on single steps; a mid-prefill slot also
+        forces single steps so its next chunk isn't delayed by a whole
+        decode scan."""
+        if self.decode_chunk > 1 and self._queue.empty() \
+                and not prefilling and all(
+                    self.cfg.max_seq_len - slots[i].next_pos
+                    - slots[i].inflight >= self.decode_chunk
+                    for i in active):
+            return self.decode_chunk
+        return 1
+
+    @staticmethod
+    def _feed_key(req: '_Request') -> tuple:
+        """What a row of the decode feed is keyed by: the request and
+        the position its next dispatched step writes."""
+        return (req.seq, req.next_pos + req.inflight)
+
+    def _feed_fits(self, slots, active) -> bool:
+        """True iff the device feed holds token and position of every
+        slot in `active` (rows of other slots may hold anything)."""
+        feed = self._feed
+        return feed is not None and all(
+            feed[2][i] == self._feed_key(slots[i]) for i in active)
 
     def _dispatch(self, slots, active, k, gen,
                   chain: 'Optional[_Inflight]' = None):
         """Issue one k-step decode dispatch for `active` slots and
         return its device output columns (num_slots, k).
 
-        Inputs are device-resident whenever possible: with `chain`
-        (the newest still-unconsumed dispatch in the ring) the feed
-        arrays it returned in-graph are used directly — zero uploads;
-        otherwise the cached feed is reused when its signature matches
-        the host state, else rebuilt from host lists (slot churn). The
-        temps array caches under a value signature the same way. In
-        async mode the result is appended to the lookahead ring with
-        its host copy started."""
-        # `base` = tokens already dispatched but not yet emitted for
-        # every active slot (the whole ring's pending columns):
-        # positions in this dispatch start at next_pos + base.
-        base = sum(e.k for e in self._ring)
+        Inputs are device-resident whenever possible: the feed the
+        newest dispatch returned in-graph, with the rows of joining
+        slots laid over it on the device (`_join_feed`), is used when
+        it holds every active row — zero uploads; otherwise it is
+        rebuilt from host lists (a synchronous engine's slot churn).
+        `chain` is the newest still-unconsumed dispatch in the ring,
+        whose tokens the host has not seen: the tick gives it only
+        once it knows that the feed fits. The temps array caches under
+        a value signature the same way. The result is appended to the
+        lookahead ring with its host copy started."""
         active_set = set(active)
         tables = None
         if self.paged_block_size:
             # Cover every position this dispatch writes (k steps past
-            # ALL pending columns — ahead of the deepest lookahead
-            # position) so the table stays fixed across the scanned
-            # chunk and across every chained step.
+            # the request's own pending columns — ahead of the deepest
+            # lookahead position) so the table stays fixed across the
+            # scanned chunk and across every chained step.
             try:
                 for i in active:
                     self._ensure_blocks(req=slots[i],
                                         upto_pos=min(
-                                            slots[i].next_pos + base + k,
+                                            slots[i].next_pos
+                                            + slots[i].inflight + k,
                                             self.cfg.max_seq_len))
             except kv_cache_lib.PoolExhaustedError as e:
                 # Can only happen with an undersized explicit pool:
@@ -4100,28 +4230,23 @@ class ContinuousBatchingEngine:
             self._temps_sig = tsig
         temps = self._temps_cache
         if chain is not None:
-            tok_dev, pos_dev = chain.feed
+            tok_dev, pos_dev = self._feed[0], self._feed[1]
             self.tick_stats['chained'] += 1
+        elif self._feed_fits(slots, active):
+            tok_dev, pos_dev = self._feed[0], self._feed[1]
         else:
-            cur_sig = tuple(
-                (slots[i].seq, slots[i].next_pos)
-                if i in active_set else None
-                for i in range(self.num_slots))
-            feed = self._feed
-            if feed is not None and feed[2] == cur_sig:
-                tok_dev, pos_dev = feed[0], feed[1]
-            else:
-                # Slot churn (or cold start): rebuild from host state —
-                # every value here is already host-resident, so this
-                # costs two small uploads, never a device sync.
-                tok_dev = _upload([(slots[i].tokens[-1]
-                                    if i in active_set else 0)
-                                   for i in range(self.num_slots)],
-                                  jnp.int32, self._repl)
-                pos_dev = _upload([(slots[i].next_pos
-                                    if i in active_set else 0)
-                                   for i in range(self.num_slots)],
-                                  jnp.int32, self._repl)
+            # Slot churn on a synchronous engine, or a start from host
+            # state: every value here is host-resident (the ring is
+            # empty), so this costs two small uploads, never a device
+            # sync.
+            tok_dev = _upload([(slots[i].tokens[-1]
+                                if i in active_set else 0)
+                               for i in range(self.num_slots)],
+                              jnp.int32, self._repl)
+            pos_dev = _upload([(slots[i].next_pos
+                                if i in active_set else 0)
+                               for i in range(self.num_slots)],
+                              jnp.int32, self._repl)
         aids = self._aids_for(slots, active_set)
         valid = self._valid_for(active_set)
         self._rng, rng = jax.random.split(self._rng)
@@ -4137,19 +4262,18 @@ class ContinuousBatchingEngine:
         self._commit_gen(gen, lambda: setattr(self, '_cache', cache))
         self._decode_steps += k
         self.step_log.append((self._decode_steps, frozenset(active)))
+        for i in active:
+            slots[i].inflight += k
         # The feed predicts host state AFTER every pending emit lands:
-        # (seq, next_pos + base + k) per active slot.
-        pred_sig = tuple(
-            (slots[i].seq, slots[i].next_pos + base + k)
-            if i in active_set else None
-            for i in range(self.num_slots))
-        self._feed = (feed_next[0], feed_next[1], pred_sig)
+        # (seq, next_pos + inflight) per active slot.
+        self._feed = (feed_next[0], feed_next[1], tuple(
+            self._feed_key(slots[i]) if i in active_set else None
+            for i in range(self.num_slots)))
         self.tick_stats['dispatches'] += 1
+        out_cols.copy_to_host_async()
+        self._ring.append(_Inflight(out_cols, list(slots), list(active),
+                                    k, gen))
         if self.async_depth:
-            out_cols.copy_to_host_async()
-            self._ring.append(_Inflight(out_cols, feed_next,
-                                        tuple(slots), list(active), k,
-                                        gen))
             depth = len(self._ring)
             _DISPATCH_AHEAD.set(depth)
             _DISPATCH_AHEAD_DEPTH.observe(depth)
@@ -4162,40 +4286,11 @@ class ContinuousBatchingEngine:
         predate async_depth=N)."""
         return self._ring[-1] if self._ring else None
 
-    def _can_chain(self, slots, active, k: int) -> bool:
-        """True iff the newest ring entry's in-graph feed is a valid
-        input for the next dispatch: the slot population is exactly as
-        dispatched for EVERY pending entry and no active request
-        predictably terminates anywhere in the pending horizon
-        (max-tokens or window; EOS is unpredictable by design and
-        costs up to async_depth discarded dispatches). `k` is the NEXT
-        dispatch's step count; the horizon is the sum of all pending
-        entries' step counts."""
-        ring = self._ring
-        pending = 0
-        for entry in ring:
-            if active != entry.active:
-                return False
-            pending += entry.k
-        msl = self.cfg.max_seq_len
-        for i in active:
-            req = slots[i]
-            for entry in ring:
-                if req is not entry.reqs[i]:
-                    return False    # finished/killed, maybe re-admitted
-            if len(req.tokens) + pending >= req.max_new_tokens:
-                return False    # finishes within the pending emits
-            if req.next_pos + pending + 1 >= msl:
-                return False    # window termination within the horizon
-            if req.next_pos + pending + k > msl:
-                return False    # lookahead would write past the window
-        return True
-
     def _consume_oldest(self, slots, gen: int) -> None:
         """Land the OLDEST pending dispatch's tokens (its host copy
         started at dispatch) and emit them. Columns whose slot changed
         hands since dispatch — EOS overshoot after a finish, a
-        deadline kill, admission churn — are discarded by request
+        deadline kill, a preemption — are discarded by request
         IDENTITY, never by position arithmetic; a request that
         finishes while deeper entries are still pending sheds their
         columns the same way, up to async_depth steps late."""
@@ -4207,14 +4302,16 @@ class ContinuousBatchingEngine:
         # a successor's world.
         self._check_gen(gen)
         live = [i for i in infl.active if slots[i] is infl.reqs[i]]
+        for i in live:
+            slots[i].inflight -= infl.k
         if live:
             with tracing.phase('engine.tick.emit'):
                 self._emit(slots, live, out_cols, None)
 
     def _flush_ring(self, slots, gen: int) -> None:
-        """Drain the whole pipeline oldest-first (churn, spec ticks,
-        all-finished overshoot): after this the ring is empty and every
-        surviving request's host state reflects every dispatched
+        """Drain the whole pipeline oldest-first (spec ticks, a feed
+        the device does not hold): after this the ring is empty and
+        every surviving request's host state reflects every dispatched
         token."""
         while self._ring:
             self._consume_oldest(slots, gen)

@@ -189,7 +189,7 @@ class InferenceServer:
                  paged_block_size: int = 0,
                  paged_num_blocks: Optional[int] = None,
                  prefill_chunk: int = 0,
-                 async_depth: int = 0,
+                 async_depth: int = 1,
                  prefix_store: Optional[str] = None,
                  preempt_drain_timeout: float = 10.0,
                  tp: int = 1,
@@ -1725,16 +1725,20 @@ def main(argv=None) -> int:
                              'chunk outgrows a decode step — 256 tokens '
                              'in bf16, 128 with int8 weights, in whole '
                              'blocks, at most the context)')
-    parser.add_argument('--async-depth', type=int, default=0,
+    parser.add_argument('--async-depth', type=int, default=1,
                         help='async decode pipeline: a ring of N '
                              'in-flight decode dispatches, each '
-                             'chained off the previous one\'s device '
-                             'output, so host scheduling overlaps '
-                             'device compute (EOS detected up to N '
-                             'steps late, overshoot discarded — token '
+                             'queued off the previous one\'s device '
+                             'output before the host waits on any '
+                             'result, so host scheduling overlaps '
+                             'device compute. The ring rides through '
+                             'finishes by length and through joins; '
+                             'EOS is detected up to N steps late, '
+                             'overshoot discarded — greedy token '
                              'streams stay bit-identical; composes '
                              'with --paged-block-size, --kv-quant and '
-                             '--speculative, see docs/performance.md). '
+                             '--speculative (which still flushes), '
+                             'see docs/performance.md. Default 1; '
                              '0 = synchronous ticks')
     parser.add_argument('--max-queue', type=int, default=64,
                         help='admission control: queued-request cap; '

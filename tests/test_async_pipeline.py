@@ -1,12 +1,16 @@
 """Async decode pipeline (tier-1, CPU): device-resident token
-feedback + one-step lookahead dispatch (models/inference.py,
-async_depth=1).
+feedback + lookahead dispatch (models/inference.py, async_depth=1, the
+default).
 
-Pins the acceptance bar of the async-pipeline issue:
+Pins the acceptance bar of the async-pipeline issues:
   - greedy token streams BIT-IDENTICAL between sync and async modes
     across every termination (EOS / max_new_tokens / cache window),
     under admission/finish churn, with chunked prefill interleaving,
     in paged mode, and with decode_chunk scans;
+  - the ring rides through churn: a finish the host can foresee and a
+    slot that joins flush nothing, no step is dispatched for a slot
+    that is known to be done, and what cannot be foreseen (EOS, a
+    deadline kill, a preemption) is shed by request identity;
   - a steady-state decode tick performs at most ONE host→device upload
     (a transfer-counting shim around the module's jnp entry points —
     the zero-upload device-feedback property cannot silently regress);
@@ -114,9 +118,9 @@ class TestAsyncBitIdentity:
 
     def test_window_termination(self, sync_engine, async_engine):
         """prompt 32 + 32 new tokens lands exactly on max_seq_len=64:
-        the request terminates on the cache window, which _can_chain
-        must treat as a predictable termination (no chained dispatch
-        may write past the window)."""
+        the request terminates on the cache window, which _spent must
+        treat as a finish the host foresees (no chained dispatch may
+        write past the window)."""
         prompt = list(range(2, 34))
         want, _ = sync_engine.generate(prompt, max_new_tokens=32)
         got, stats = async_engine.generate(prompt, max_new_tokens=32)
@@ -126,9 +130,10 @@ class TestAsyncBitIdentity:
     def test_mixed_churn_streams_identical(self, sync_engine,
                                            async_engine, ref_tokens):
         """Staggered concurrent requests with different lengths force
-        admission/finish churn mid-pipeline (every perturbation flushes
-        the lookahead); each per-request stream must still equal the
-        solo sync reference — including the on_token streaming order."""
+        admission/finish churn mid-pipeline (which the ring rides
+        through: no flush); each per-request stream must still equal
+        the solo sync reference — including the on_token streaming
+        order."""
         streams = {}
 
         def _tap(key):
@@ -151,6 +156,7 @@ class TestAsyncBitIdentity:
             assert results[i] == ref_tokens[:n], (i, n, results[i])
             assert streams[i] == ref_tokens[:n], (i, n, streams[i])
         assert async_engine.tick_stats['chained'] > 0
+        assert async_engine.tick_stats['flushes'] == 0
 
     def test_decode_chunk_identical(self, ref_tokens):
         engine = _engine(decode_chunk=4, async_depth=1)
@@ -217,6 +223,222 @@ class TestAsyncPaged:
         assert any(prefill[j] < d < prefill[j + 1]
                    for d in decode
                    for j in range(len(prefill) - 1)), log
+
+
+def _closed_loop(engine, work, clients, **submit_kw):
+    """`clients` callers, each sending the next item of `work`
+    ((prompt, max_new_tokens) pairs, in order) when its last answer is
+    in. Returns every request's tokens and its on_token stream, by
+    index in `work`."""
+    lock = threading.Lock()
+    futures, streams, ended = {}, {}, []
+    done = threading.Event()
+
+    def send_next():
+        with lock:
+            index = len(futures)
+            if index >= len(work):
+                return
+            futures[index] = None
+        prompt, new = work[index]
+        streams[index] = []
+
+        def on_token(tok):
+            if tok is not None:
+                streams[index].append(tok)
+                return
+            # the stream's end: the request's client sends its next
+            ended.append(index)
+            send_next()
+            if len(ended) == len(work):
+                done.set()
+
+        futures[index] = engine.submit(prompt, max_new_tokens=new,
+                                       on_token=on_token, **submit_kw)
+
+    for _ in range(clients):
+        send_next()
+    assert done.wait(timeout=300), 'closed loop did not finish'
+    return ({i: f.result(timeout=60)[0] for i, f in futures.items()},
+            streams)
+
+
+def _decode_steps_by_slot_count(engine, marker=0):
+    """Slot-steps the engine dispatched: a decode step counts once for
+    every slot it carried."""
+    return sum(len(slots) for tag, slots in list(engine.step_log)[marker:]
+               if tag != 'prefill')
+
+
+# Staggered lengths, prompts of one chunk (8 tokens and fewer) and of
+# two, a request of one token and one of two among them.
+CHURN_WORK = [((PROMPT + PROMPT[:5]) if i % 3 == 0 else PROMPT[:3 + i % 5],
+               n)
+              for i, n in enumerate((4, 16, 7, 12, 5, 9, 1, 2, 11, 3, 14,
+                                     6, 8, 10, 13, 5, 2, 9))]
+
+
+class TestRingRidesThroughChurn:
+    """A closed loop with more clients than slots, at depth 0 and at
+    the default: the same tokens, and at the default not one flush."""
+
+    @pytest.fixture(scope='class', params=['paged', 'contiguous'])
+    def pair(self, request):
+        kw = (dict(paged_block_size=8, prefill_chunk=8)
+              if request.param == 'paged' else {})
+        from skypilot_tpu.models.inference import ContinuousBatchingEngine
+        sync = ContinuousBatchingEngine(_cfg(), num_slots=3,
+                                        async_depth=0, **kw)
+        ring = ContinuousBatchingEngine(_cfg(), num_slots=3, **kw)
+        out = {}
+        try:
+            out['want'] = _closed_loop(sync, CHURN_WORK, clients=5)
+            out['sync_steps'] = _decode_steps_by_slot_count(sync)
+            out['got'] = _closed_loop(ring, CHURN_WORK, clients=5)
+            out['ring_steps'] = _decode_steps_by_slot_count(ring)
+            out['stats'] = dict(ring.tick_stats)
+            out['sync_stats'] = dict(sync.tick_stats)
+            out['depth'] = ring.async_depth
+            out['programs'] = (ring._decode._cache_size(),  # pylint: disable=protected-access
+                               ring._join_feed._cache_size())  # pylint: disable=protected-access
+            out['occupancy'] = ring.paged_occupancy()
+        finally:
+            sync.stop()
+            ring.stop()
+        return out
+
+    def test_the_default_is_the_ring(self, pair):
+        assert pair['depth'] == 1
+        assert pair['sync_stats']['chained'] == 0
+
+    def test_tokens_and_stream_order_equal(self, pair):
+        want, want_streams = pair['want']
+        got, got_streams = pair['got']
+        assert sorted(got) == list(range(len(CHURN_WORK)))
+        for i, (_, n) in enumerate(CHURN_WORK):
+            assert got[i] == want[i], (i, n)
+            assert got_streams[i] == got[i], (i, n)
+            assert want_streams[i] == want[i], (i, n)
+            assert len(got[i]) == max(n, 2)   # the off-by-one at n == 1
+
+    def test_no_flush_and_nearly_every_dispatch_ahead(self, pair):
+        stats = pair['stats']
+        assert stats['flushes'] == 0, stats
+        assert stats['chained'] > 0.9 * stats['dispatches'], stats
+
+    def test_no_step_for_a_slot_known_to_be_done(self, pair):
+        """Without EOS a run dispatches exactly the steps the
+        synchronous tick does: one a token after the first."""
+        want = sum(max(n - 1, 1) for _, n in CHURN_WORK)
+        assert pair['sync_steps'] == want
+        assert pair['ring_steps'] == want
+
+    def test_one_decode_program_and_one_join_program(self, pair):
+        assert pair['programs'] == (1, 1)
+
+    def test_occupancy_reports_the_ring(self, pair):
+        occ = pair['occupancy']
+        if not occ:     # the contiguous layout has no pool to report
+            return
+        assert occ['decode_dispatches'] == pair['stats']['dispatches']
+        assert occ['decode_chained'] == pair['stats']['chained']
+        assert occ['ring_flushes'] == 0
+
+
+class TestUnforeseenIsShed:
+    """EOS, a deadline kill and a preemption with the ring up: nothing
+    is waited for, the columns already queued are dropped by request
+    identity, and every surviving stream is exact."""
+
+    @pytest.fixture(scope='class')
+    def ring(self):
+        from skypilot_tpu.models.inference import ContinuousBatchingEngine
+        engine = ContinuousBatchingEngine(
+            _cfg(), num_slots=2, paged_block_size=8, prefill_chunk=8)
+        yield engine
+        engine.stop()
+
+    def test_eos_overshoot_shed_while_the_slot_is_reused(
+            self, ring, sync_engine, ref_tokens):
+        eos = ref_tokens[5]
+        want, _ = sync_engine.generate(PROMPT, max_new_tokens=24,
+                                       eos_id=eos)
+        other = PROMPT[:5]
+        want_other, _ = sync_engine.generate(other, max_new_tokens=20)
+        flushes = ring.tick_stats['flushes']
+        futs = [ring.submit(PROMPT, max_new_tokens=24, eos_id=eos),
+                ring.submit(other, max_new_tokens=20),
+                ring.submit(PROMPT, max_new_tokens=24, eos_id=eos),
+                ring.submit(other, max_new_tokens=20)]
+        got = [f.result(timeout=120)[0] for f in futs]
+        assert got == [want, want_other, want, want_other]
+        assert want == ref_tokens[:6]       # EOS really fired
+        assert ring.tick_stats['flushes'] == flushes
+        ring._pool.check()  # pylint: disable=protected-access
+
+    def test_deadline_kill_sheds_and_the_neighbour_is_exact(
+            self, ring, ref_tokens):
+        ring.generate(PROMPT, max_new_tokens=2)     # compiled
+        flushes = ring.tick_stats['flushes']
+        seen = threading.Event()
+
+        def slowly(_tok):
+            # on the engine's thread: 50 tokens take 2.5 s at the
+            # least, so the deadline falls mid-decode on any machine
+            seen.set()
+            time.sleep(0.05)
+
+        killed = ring.submit(PROMPT[:5], max_new_tokens=50,
+                             deadline=time.time() + 1.0, on_token=slowly)
+        neighbour = ring.submit(PROMPT, max_new_tokens=24)
+        assert seen.wait(timeout=60)
+        with pytest.raises(exceptions.RequestDeadlineExceededError):
+            killed.result(timeout=120)
+        # the freed slot is joined again while the neighbour decodes
+        again = ring.submit(PROMPT, max_new_tokens=9)
+        assert neighbour.result(timeout=120)[0] == ref_tokens[:24]
+        assert again.result(timeout=120)[0] == ref_tokens[:9]
+        assert ring.tick_stats['flushes'] == flushes
+        ring._pool.check()  # pylint: disable=protected-access
+
+    def test_preempted_request_resumes_exact_beside_a_decoding_slot(
+            self, ring, ref_tokens):
+        """The same request object comes back to a slot: its pending
+        columns were blanked, not told apart by identity."""
+        flushes = ring.tick_stats['flushes']
+        started, seen = threading.Event(), []
+        batch = ring.submit(
+            PROMPT, max_new_tokens=24, priority='batch',
+            on_token=lambda t: (seen.append(t),
+                                len(seen) >= 4 and started.set(),
+                                time.sleep(0.01)))  # still decoding when
+        neighbour = ring.submit(PROMPT, max_new_tokens=24)  # the urgent one comes
+        assert started.wait(timeout=60)
+        preempts = ring.tenancy_stats['slot_preempts']
+        urgent = ring.submit(PROMPT[:5], max_new_tokens=4,
+                             priority='interactive')
+        urgent.result(timeout=120)
+        assert batch.result(timeout=120)[0] == ref_tokens[:24]
+        assert neighbour.result(timeout=120)[0] == ref_tokens[:24]
+        assert [t for t in seen if t is not None] == ref_tokens[:24]
+        assert ring.tenancy_stats['slot_preempts'] > preempts
+        assert ring.tick_stats['flushes'] == flushes
+        ring._pool.check()  # pylint: disable=protected-access
+
+    def test_sampled_stream_equals_the_synchronous_one(self):
+        """One sampled request alone draws its keys in the same order
+        at either depth: first token (on the host, or in the join
+        program), then a key a step."""
+        outs = []
+        for depth in (0, 1):
+            engine = _engine(async_depth=depth, rng_seed=7, top_k=8)
+            try:
+                outs.append(engine.generate(PROMPT, max_new_tokens=12,
+                                            temperature=0.9)[0])
+            finally:
+                engine.stop()
+        assert outs[0] == outs[1]
+        assert len(set(outs[0])) > 1
 
 
 class _CountingJnp:
@@ -310,6 +532,46 @@ class TestSteadyStateUploads:
         # cross at most 2), plus the installation-boundary allowance.
         assert uploads <= 4, (
             f'{uploads} uploads over {window} paged ticks')
+
+
+class TestJoiningTickUploads:
+
+    def test_a_join_uploads_its_own_row_and_never_the_feed(
+            self, monkeypatch):
+        """With the ring up a joining slot's token and position are
+        written on the device: the tick uploads the join's (slot,
+        position) pair and temperature beside the chunk's own inputs
+        and the changed table, and never the full-width token and
+        position lists a rebuild from host state would."""
+        from skypilot_tpu.models import inference
+        from skypilot_tpu.models.inference import ContinuousBatchingEngine
+        slots = 5
+        engine = ContinuousBatchingEngine(
+            _cfg(), num_slots=slots, paged_block_size=8, prefill_chunk=8)
+        try:
+            engine.generate(PROMPT, max_new_tokens=2)   # compile
+            uploads = []
+            real = inference._upload  # pylint: disable=protected-access
+
+            def counting(value, dtype=None, sharding=None):
+                uploads.append(value)
+                return real(value, dtype, sharding)
+
+            monkeypatch.setattr(inference, '_upload', counting)
+            got, _ = _closed_loop(engine, CHURN_WORK[:8], clients=slots + 2)
+            monkeypatch.setattr(inference, '_upload', real)
+            stats = dict(engine.tick_stats)
+        finally:
+            engine.stop()
+        assert len(got) == 8
+        assert stats['flushes'] == 0
+        full_width = [u for u in uploads
+                      if isinstance(u, list) and len(u) == slots
+                      and all(isinstance(x, int) for x in u)]
+        assert not full_width, full_width
+        joins = [u for u in uploads if isinstance(u, list) and len(u) == 2
+                 and all(isinstance(x, int) for x in u)]
+        assert len(joins) == 8, joins
 
 
 @pytest.mark.chaos

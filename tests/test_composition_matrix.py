@@ -136,9 +136,10 @@ class TestDeepRingChurn:
 
     def test_staggered_churn_streams_identical(self, refs, deep_engine):
         """Staggered concurrent requests with different lengths force
-        admission/finish churn mid-pipeline: every perturbation must
-        flush the WHOLE ring, and each per-request stream (including
-        the on_token order) must equal the solo baseline."""
+        admission/finish churn mid-pipeline: the deep ring rides
+        through every finish by length and every join without a flush,
+        and each per-request stream (including the on_token order)
+        must equal the solo baseline."""
         ref = refs['int8']
         streams = {}
 
@@ -162,7 +163,7 @@ class TestDeepRingChurn:
             assert results[i] == ref[:n], (i, n, results[i])
             assert streams[i] == ref[:n], (i, n, streams[i])
         assert deep_engine.tick_stats['chained'] > 0
-        assert deep_engine.tick_stats['flushes'] > 0
+        assert deep_engine.tick_stats['flushes'] == 0
         deep_engine._pool.check()  # pylint: disable=protected-access
 
 

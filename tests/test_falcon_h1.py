@@ -334,6 +334,21 @@ def _engine(cfg, params, paged: bool, slots: int = 4, **kw):
         paged_block_size=16 if paged else 0, **kw)
 
 
+def _tap_first_logits(engine, into: list):
+    """Copy every prompt's last prefill logits into `into` where the
+    engine seeds a first token, sampled on the host (a synchronous
+    engine) or on the device (the default). Returns the untapped
+    method."""
+    real = engine._first_token
+
+    def tapped(slots, slot, req, row, position):
+        into.append(np.asarray(row))
+        return real(slots, slot, req, row, position)
+
+    engine._first_token = tapped
+    return real
+
+
 @pytest.mark.parametrize('paged', [True, False],
                          ids=['paged', 'contiguous'])
 def test_the_batching_engine_serves_what_the_reference_would(model, paged):
@@ -344,9 +359,7 @@ def test_the_batching_engine_serves_what_the_reference_would(model, paged):
     cfg, params, oracle = model
     engine = _engine(cfg, params, paged, slots=3)
     first_logits = []
-    sample = engine._sample
-    engine._sample = lambda logits, temp: (
-        first_logits.append(np.asarray(logits)), sample(logits, temp))[1]
+    _tap_first_logits(engine, first_logits)
     try:
         prompts = [prompt_of(n) for n in (5, 100, 256, 257, 300, 2, 40)]
         futs = [engine.submit(p, max_new_tokens=10) for p in prompts]
@@ -389,9 +402,7 @@ def _serve(engine, first, others=(), other_new: int = 6,
     recurrent state once it is done."""
     import threading
     logits = []
-    sample = engine._sample
-    engine._sample = lambda row, temp: (
-        logits.append(np.asarray(row)), sample(row, temp))[1]
+    untapped = _tap_first_logits(engine, logits)
     seen, enough = [], threading.Event()
 
     def on_token(tok):
@@ -406,7 +417,7 @@ def _serve(engine, first, others=(), other_new: int = 6,
     toks = fut.result(timeout=600)[0]
     for f in futs:
         f.result(timeout=600)
-    engine._sample = sample
+    engine._first_token = untapped
     mixer = engine._cache['layers']['layer']['mixer']
     state = {n: np.asarray(mixer[n])[:, 0] for n in ('ssm_state',
                                                      'conv_state')}
@@ -441,6 +452,36 @@ def test_a_request_is_bit_identical_alone_and_in_a_full_batch(model,
     finally:
         engine.stop()
     _same(alone, crowd)
+
+
+@pytest.mark.parametrize('paged', [True, False],
+                         ids=['paged', 'contiguous'])
+def test_the_ring_rides_through_churn_with_the_state_exact(model, paged):
+    """Depth 0 against the default. Slot 0's request finishes by length
+    while its neighbours decode on, so with the ring up its row rides
+    inert through steps queued before its last token was read: its
+    first logits, its tokens and the state it leaves are the same bits,
+    more requests than slots reuse every slot (a rejoined slot's state
+    is reset), and nothing is flushed."""
+    cfg, params, _ = model
+    prompt = prompt_of(21)
+    crowd = [prompt_of(n, 3) for n in (33, 70, 300, 5, 130, 18)]
+    runs, tokens = [], []
+    for depth in (0, 1):
+        engine = _engine(cfg, params, paged, slots=3, async_depth=depth)
+        try:
+            runs.append(_serve(engine, prompt, crowd[:2], other_new=20,
+                               first_new=5))
+            futs = [engine.submit(p, max_new_tokens=4 + 3 * i)
+                    for i, p in enumerate(crowd)]
+            tokens.append([f.result(timeout=600)[0] for f in futs])
+            stats = dict(engine.tick_stats)
+        finally:
+            engine.stop()
+    _same(runs[0], runs[1])
+    assert tokens[0] == tokens[1]
+    assert stats['flushes'] == 0, stats
+    assert stats['chained'] > 0.8 * stats['dispatches'], stats
 
 
 def test_a_reused_slot_never_sees_the_last_request(model):
@@ -590,9 +631,7 @@ def test_int8_weights_run_and_differ(model):
                      'norm_scale'):
             assert mixer[name].dtype == jnp.float32
         first = []
-        sample = engine._sample
-        engine._sample = lambda row, t: (first.append(np.asarray(row)),
-                                         sample(row, t))[1]
+        _tap_first_logits(engine, first)
         prompt = prompt_of(90)
         toks = engine.generate(prompt, max_new_tokens=6)[0]
     finally:

@@ -672,12 +672,17 @@ class TestSLOTiers:
         retryably (zero non-retryable losses)."""
         engine = ContinuousBatchingEngine(_cfg(), num_slots=2)
         try:
+            started = threading.Event()
             batch_futs = [
                 engine.submit(list(range(1, 9)), max_new_tokens=16,
-                              priority='batch')
+                              priority='batch',
+                              on_token=lambda _t: started.set())
                 for _ in range(6)
             ]
-            time.sleep(0.3)
+            # The storm arrives while the first wave decodes (its first
+            # token is out, fifteen are to come, two waves wait): a
+            # fixed sleep left that to the machine's speed.
+            assert started.wait(timeout=120)
             t0 = time.monotonic()
             int_futs = [
                 engine.submit([40 + i, 41, 42], max_new_tokens=4,

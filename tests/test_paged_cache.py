@@ -580,16 +580,21 @@ class TestDefaultChunkWidth:
         ceil(prompt / block) blocks — not the padded chunk's 32: a
         sized pool must not shed requests for pad positions."""
         seen = []
+        first_token = wide_engine._first_token  # pylint: disable=protected-access
 
-        def on_token(tok):
-            if tok is not None and not seen:
-                req = next(r for r in wide_engine._slots  # pylint: disable=protected-access
-                           if r is not None and not r.prefilling)
-                seen.append(len(req.blocks))
+        def after_the_chunk(slots, slot, req, row, position):
+            # before the decode step is queued, which reserves the
+            # block of the position it writes
+            seen.append(len(req.blocks))
+            return first_token(slots, slot, req, row, position)
 
         before = wide_engine.paged_stats['prefill_chunks']
-        wide_engine.submit(_prompt(length, salt=1), max_new_tokens=3,
-                           on_token=on_token).result(timeout=120)
+        wide_engine._first_token = after_the_chunk  # pylint: disable=protected-access
+        try:
+            wide_engine.submit(_prompt(length, salt=1),
+                               max_new_tokens=3).result(timeout=120)
+        finally:
+            wide_engine._first_token = first_token  # pylint: disable=protected-access
         assert wide_engine.paged_stats['prefill_chunks'] == before + 1
         assert seen == [-(-length // 8)]
 
