@@ -28,6 +28,16 @@ dynamic-update-slice and scatter is updated in place: no second buffer,
 no copy after the loop (pinned on the compiled programs by
 tests/test_cache_in_place.py).
 
+A model whose layers differ by position (`cfg.has_layer_pattern`:
+leading dense layers before expert layers, an attention kind a layer;
+models/layer_pattern.py) takes the same loop once a GROUP of layers of
+one shape: 'dense_layers', then 'layers'. Beside the weights and the
+index each loop scans the layers' kinds, `(window, rope)` a layer, which
+a layer reads through `current_kind()`: the pattern is data of the one
+program, not a program a kind. Such a model comes here for every apply,
+init and whole sequences too (`carried` false: the cache, if the apply
+makes one, is scanned like the weights and a leaf is the layer's own).
+
 The index travels in a context variable, not in an argument:
 `DecoderLayer` and everything it calls are the training path's too, and
 their signatures and call lines stay as they are. Only code that runs
@@ -56,6 +66,12 @@ from skypilot_tpu.models.transformer import DecoderLayer
 
 _LAYER: contextvars.ContextVar = contextvars.ContextVar(
     'carried_cache_layer', default=None)
+_KIND: contextvars.ContextVar = contextvars.ContextVar(
+    'carried_layer_kind', default=None)
+
+# The window of a layer that has none, as the loop carries it: no
+# context reaches it, so `q_pos - k_pos < window` holds for every key.
+NO_WINDOW = 1 << 30
 
 
 def current_layer() -> Optional[jax.Array]:
@@ -65,13 +81,21 @@ def current_layer() -> Optional[jax.Array]:
     return _LAYER.get()
 
 
+def current_kind() -> Optional[Tuple[jax.Array, jax.Array]]:
+    """(window, rope) of the layer being traced inside a layer
+    pattern's loop, two traced int32 scalars (the window in keys,
+    `NO_WINDOW` for none; rope 1 or 0); None anywhere else."""
+    return _KIND.get()
+
+
 @contextlib.contextmanager
-def _at_layer(layer: jax.Array):
-    token = _LAYER.set(layer)
+def _at_layer(layer: Optional[jax.Array], kind=None):
+    tokens = _LAYER.set(layer), _KIND.set(kind)
     try:
         yield
     finally:
-        _LAYER.reset(token)
+        _LAYER.reset(tokens[0])
+        _KIND.reset(tokens[1])
 
 
 def layer_view(leaf: jax.Array, layer: Optional[jax.Array]
@@ -157,15 +181,102 @@ class CarriedLayer(nn.Module):
         return (x, positions, block_tables, adapter_ids, state_rows), None
 
 
+class CarriedPatternLayer(nn.Module):
+    """`layer_pattern.PatternLayer` under nn.scan's signature: beside
+    its index the loop hands a layer its kind. `carried` false: the
+    cache is scanned with the weights (or there is none), so a leaf is
+    the layer's own and the index says nothing."""
+    cfg: ModelConfig
+    dense: bool
+    carried: bool
+
+    @nn.compact
+    def __call__(self, carry, xs, stacks):
+        # Late import: only a model with a pattern loads the module.
+        from skypilot_tpu.models.layer_pattern import PatternLayer
+        layer, window, rope = xs
+        x, positions, block_tables, adapter_ids, state_rows = carry
+        with _at_layer(layer if self.carried else None, (window, rope)):
+            x = PatternLayer(self.cfg, self.dense, name='layer')(
+                x, positions, block_tables, adapter_ids, state_rows,
+                None if stacks is None else (stacks, layer))
+        return (x, positions, block_tables, adapter_ids, state_rows), None
+
+
+def layer_groups(cfg: ModelConfig) -> Tuple[Tuple[str, bool, int, int], ...]:
+    """The groups of a layer pattern, each (name, dense, first layer,
+    one past its last): the leading dense layers of an expert model,
+    then its expert layers; a model without experts is one dense group.
+    The last group is always 'layers'."""
+    if not cfg.is_moe:
+        return (('layers', True, 0, cfg.num_layers),)
+    n = cfg.num_dense_layers
+    if not 0 <= n < cfg.num_layers:
+        raise ValueError(
+            f'{cfg.name}: {n} leading dense layers leave no expert '
+            f'layer of {cfg.num_layers}')
+    experts = ('layers', False, n, cfg.num_layers)
+    return (('dense_layers', True, 0, n), experts) if n else (experts,)
+
+
+def _carry_pattern(cfg: ModelConfig, carry: Tuple, carried: bool) -> Tuple:
+    if not cfg.scan_layers:
+        raise NotImplementedError(
+            f'{cfg.name}: a layer pattern runs as stacked groups only '
+            f'(scan_layers=True)')
+    kinds = cfg.layer_kinds or ((cfg.sliding_window,
+                                 cfg.pos_embedding == 'rope'),
+                                ) * cfg.num_layers
+    windows = jnp.asarray([w or NO_WINDOW for w, _ in kinds], jnp.int32)
+    ropes = jnp.asarray([int(r) for _, r in kinds], jnp.int32)
+    # what a dropless expert layer counted, stacked a layer (a no-op
+    # unless the apply makes 'moe_stats' mutable)
+    variable_axes = {'params': 0, 'moe_stats': 0}
+    if cfg.serve_adapters > 0:
+        variable_axes['adapters'] = 0
+    if not carried:
+        variable_axes['cache'] = 0
+    for name, dense, start, stop in layer_groups(cfg):
+        # The experts' weights are the one thing a layer is NOT handed
+        # its own slice of: scanned, each (held, in, out) slice would be
+        # copied out of its stack before a grouped product could read
+        # it. They are declared here, whole, and broadcast into the loop
+        # (models/moe.py reads a layer's groups out of the stack).
+        stacks = None
+        if not dense and cfg.moe_impl == 'dropless':
+            from skypilot_tpu.models.moe import ExpertStacks
+            stacks = ExpertStacks(cfg, stop - start, name='experts')()
+        scanned = nn.scan(
+            CarriedPatternLayer,
+            variable_axes=variable_axes,
+            variable_carry='cache' if carried else False,
+            split_rngs={'params': True},
+            in_axes=(0, nn.broadcast),
+            length=stop - start,
+            metadata_params={nn.PARTITION_NAME: 'layers'},
+        )(cfg, dense, carried, name=name)
+        carry, _ = scanned(carry, (
+            jnp.arange(stop - start, dtype=jnp.int32),
+            windows[start:stop], ropes[start:stop]), stacks)
+    return carry
+
+
 def carry_layers(cfg: ModelConfig, x: jax.Array, positions: jax.Array,
                  block_tables: Optional[jax.Array],
                  adapter_ids: Optional[jax.Array],
-                 state_rows: Optional[Tuple]) -> jax.Array:
+                 state_rows: Optional[Tuple],
+                 carried: bool = True) -> jax.Array:
     """All layers applied to x, the 'cache' collection carried. Called
     from inside `Transformer.__call__` (the scanned module becomes its
     child 'layers', so the parameter tree is the training loop's).
-    Weights and adapter stacks stay scanned inputs. No remat: a decoding
-    model keeps nothing for a backward pass."""
+    Weights and adapter stacks stay scanned inputs (a pattern's expert
+    stacks alone are handed to the loop whole). No remat: a decoding
+    model keeps nothing for a backward pass. A layer pattern runs a loop
+    a group, and with `carried` false (no cache yet, or none at all)
+    scans what cache there is like the weights."""
+    if cfg.has_layer_pattern:
+        return _carry_pattern(cfg, (x, positions, block_tables,
+                                    adapter_ids, state_rows), carried)[0]
     variable_axes = {'params': 0}
     if cfg.serve_adapters > 0:
         variable_axes['adapters'] = 0

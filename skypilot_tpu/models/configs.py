@@ -157,17 +157,64 @@ class ModelConfig:
     # MoE (0 ⇒ dense SwiGLU MLP).
     num_experts: int = 0
     experts_per_token: int = 2
+    # Three formulations (models/moe.py says which is whose):
     # 'dispatch' = capacity-based token dispatch (GShard-style: only the
     # chosen k experts compute each token; the dispatch einsum reshapes
     # tokens expert-major, which under `ep` sharding lowers to an
     # all-to-all over ICI). 'dense' = every expert computes every token
     # with a one-hot combine (exact, simple, E/k× more FLOPs — kept as
-    # the reference implementation and for tiny configs).
+    # the reference implementation and for tiny configs). 'dropless' =
+    # the (token, choice) pairs sorted by expert and grouped matrix
+    # products over the expert stacks: no capacity, nothing dropped, a
+    # token's output does not depend on its batch: what the engine
+    # serves.
     moe_impl: str = 'dispatch'
     # Per-expert buffer = ceil(tokens·k/E) · capacity_factor; tokens over
     # capacity are dropped (their combine weight contributes nothing —
     # standard GShard/Switch semantics).
     moe_capacity_factor: float = 1.25
+    # --- The dropless expert layer (moe_impl='dropless'; serving) ---
+    # experts_held > 0 ⇒ this program holds experts [first_expert,
+    # first_expert + experts_held) of the num_experts the router scores:
+    # one chip's share of an expert-parallel layer. The router keeps
+    # its whole width, and selection, normalisation and scale are over
+    # the token's whole top k, whoever holds them; the layer adds what
+    # ITS experts give and leaves the rest out (models/moe.py). 0 ⇒
+    # all of them.
+    experts_held: int = 0
+    first_expert: int = 0
+    # An expert's hidden width where it is not d_mlp (0 ⇒ d_mlp), and
+    # the width of one shared expert that every token passes through
+    # beside its routed ones (0 ⇒ none).
+    d_expert: int = 0
+    d_shared_expert: int = 0
+    # 'softmax' over the chosen k (Mixtral), or 'sigmoid' over all
+    # experts, in float32; router_bias adds a per-expert bias to the
+    # scores for SELECTION only (the weights are the chosen scores);
+    # route_norm divides the chosen scores by their sum, route_scale
+    # multiplies them.
+    router_score: str = 'softmax'
+    router_bias: bool = False
+    route_norm: bool = True
+    route_scale: float = 1.0
+    # --- A stack that is not one layer type (models/layer_pattern.py) ---
+    # The first num_dense_layers layers of an expert model carry a dense
+    # MLP of width d_mlp; they are a stacked group of their own
+    # ('dense_layers') before the expert group ('layers').
+    num_dense_layers: int = 0
+    # One (window, rope) pair a layer: the keys a query looks back over
+    # (0 ⇒ all) and whether rotary position is applied. () ⇒ every
+    # layer is of the one kind that sliding_window and pos_embedding
+    # give. The layer loop carries the pairs beside the stacked weights,
+    # so there is still one program (models/cache_carry.py).
+    layer_kinds: Tuple[Tuple[int, bool], ...] = ()
+    # RMSNorm over head_dim on every q and k head, before rotary.
+    qk_norm: bool = False
+    # o_proj(attn_out * sigmoid(gate_proj(x))), gate_proj as wide as q.
+    attn_gate: bool = False
+    # Sandwich norms: a second RMSNorm on each branch's OUTPUT before it
+    # is added to the residual.
+    post_norms: bool = False
     # Execution knobs.
     scan_layers: bool = True          # lax.scan over stacked layers
     remat: bool = True                # checkpoint each layer
@@ -221,9 +268,36 @@ class ModelConfig:
     # and tp=1) — see models/inference.py _resolve_decode_kernel.
     decode_kernel: str = 'xla'
 
+    def __post_init__(self):
+        # A JSON override hands lists: keep the config hashable.
+        kinds = tuple((int(w), bool(r)) for w, r in self.layer_kinds)
+        object.__setattr__(self, 'layer_kinds', kinds)
+        if kinds and len(kinds) != self.num_layers:
+            raise ValueError(
+                f'{self.name}: layer_kinds names {len(kinds)} layers, '
+                f'num_layers is {self.num_layers}')
+
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.d_model // self.num_heads
+
+    @property
+    def has_layer_pattern(self) -> bool:
+        """True when the stack is not one layer type, or its layers
+        carry what only models/layer_pattern.py's layer has (q/k norms,
+        the output gate, post-norms): such a model takes the grouped
+        layer loop, training's plain scan never sees it."""
+        return bool(self.layer_kinds or self.num_dense_layers
+                    or self.qk_norm or self.attn_gate or self.post_norms)
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights this program holds."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_expert or self.d_mlp
 
     @property
     def has_recurrent_state(self) -> bool:
@@ -262,6 +336,18 @@ class ModelConfig:
                 f'scan state, the convolution and the grouped norm are '
                 f'not sharded over `tp` yet (heads and groups would '
                 f'have to split together); serve it at tp=1')
+        if self.moe_impl == 'dropless' and self.is_moe:
+            raise NotImplementedError(
+                f'{self.name}: tp={tp} with a dropless expert layer: '
+                f'serving has no expert axis yet (the held experts '
+                f'would have to split over the mesh and the sorted '
+                f'pairs with them); serve it at tp=1, one share a chip '
+                f'(experts_held, first_expert)')
+        if self.has_layer_pattern:
+            raise NotImplementedError(
+                f'{self.name}: tp={tp} with a layer pattern: the q/k '
+                f'norms and the output gate of models/layer_pattern.py '
+                f'carry no tp rule yet; serve it at tp=1')
         dims = {'num_heads': self.num_heads,
                 'num_kv_heads': self.num_kv_heads,
                 'd_mlp': self.d_mlp,
@@ -294,19 +380,32 @@ class ModelConfig:
                 self.head_dim
         if self.o_bias:
             attn += self.d_model
+        if self.attn_gate:
+            attn += self.d_model * self.num_heads * self.head_dim
+        if self.qk_norm:
+            attn += 2 * self.head_dim
         mlp_mats = 3 if self.mlp_style == 'glu' else 2
+        dense_mlp = mlp_mats * self.d_model * self.d_mlp
         if self.is_moe:
-            mlp = self.num_experts * mlp_mats * self.d_model * self.d_mlp
+            # every expert of the published model, held here or not,
+            # and the one shared expert
+            mlp = mlp_mats * self.d_model * (
+                self.num_experts * self.expert_width
+                + self.d_shared_expert)
             router = self.d_model * self.num_experts
+            if self.router_bias:
+                router += self.num_experts
         else:
-            mlp = mlp_mats * self.d_model * self.d_mlp
+            mlp = dense_mlp
             router = 0
         if self.mlp_bias:
             mlp += (mlp_mats - 1) * self.d_mlp + self.d_model
         norm_params = (2 if self.norm_style == 'layernorm' else 1) * \
             self.d_model
-        # Parallel-block layers (Falcon) share ONE pre-norm for attn+mlp.
-        norms = (1 if self.parallel_block else 2) * norm_params
+        # Parallel-block layers (Falcon) share ONE pre-norm for attn+mlp;
+        # sandwich norms double them.
+        norms = (1 if self.parallel_block else 2) * norm_params * \
+            (2 if self.post_norms else 1)
         mixer = 0
         if self.ssm_heads:
             mixer = (self.d_model * self.ssm_proj_width        # in_proj
@@ -320,7 +419,10 @@ class ModelConfig:
             if self.ssm_proj_bias:
                 mixer += self.ssm_proj_width + self.d_model
         per_layer = attn + mlp + router + norms + mixer
-        return embed + self.num_layers * per_layer + norm_params
+        # leading dense layers of an expert model carry the dense MLP
+        dense = self.num_dense_layers if self.is_moe else 0
+        return (embed + self.num_layers * per_layer + norm_params
+                - dense * (mlp + router - dense_mlp))
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Training FLOPs/token (fwd+bwd ≈ 6 × params-matmul + attention
@@ -328,8 +430,9 @@ class ModelConfig:
         seq_len = seq_len or self.max_seq_len
         if self.is_moe:
             # Only active experts do work.
-            active_mlp = self.experts_per_token * 3 * self.d_model * \
-                self.d_mlp
+            active_mlp = 3 * self.d_model * (
+                self.experts_per_token * self.expert_width
+                + self.d_shared_expert)
             attn = (self.d_model * self.num_heads * self.head_dim +
                     2 * self.d_model * self.num_kv_heads * self.head_dim +
                     self.num_heads * self.head_dim * self.d_model)
@@ -542,6 +645,27 @@ FALCON_H1_34B = _register(ModelConfig(
     ssm_out_multiplier=0.08838834764831845,
     mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
     lm_head_multiplier=0.0078125))
+
+# --- Trinity-Large-Preview (Arcee, 2026; model_type afmoe), the
+# published config: 60 layers, the first 6 with a dense SwiGLU of 12288,
+# the rest with 256 routed experts of 3072 (sigmoid scores, a selection
+# bias, top 4 normalised and scaled by 2.448) and one shared expert;
+# three sliding layers (4096 keys, rotary) then one full layer (no
+# rotary), repeated; q/k RMSNorm, a sigmoid output gate, sandwich norms,
+# the embedding times sqrt(d). One chip holds a share of it
+# (experts_held, first_expert, a slice of the vocabulary, a cut in
+# depth: perf/configs/trinity-large-l5-ep8.json).
+TRINITY_LARGE_PREVIEW = _register(ModelConfig(
+    name='trinity-large-preview', vocab_size=200192, d_model=3072,
+    num_layers=60, num_heads=48, num_kv_heads=8, head_dim_override=128,
+    d_mlp=12288, max_seq_len=262144, rope_theta=10000.0, norm_eps=1e-5,
+    scale_embed_by_dim=True, num_experts=256, experts_per_token=4,
+    moe_impl='dropless', d_expert=3072, d_shared_expert=3072,
+    router_score='sigmoid', router_bias=True, route_norm=True,
+    route_scale=2.448, num_dense_layers=6,
+    layer_kinds=(((4096, True),) * 3 + ((0, False),)) * 15,
+    qk_norm=True, attn_gate=True, post_norms=True,
+    attention_impl='xla', remat=False))
 
 GPT2_124M = _register(ModelConfig(
     name='gpt2-124m', vocab_size=50304, d_model=768, num_layers=12,

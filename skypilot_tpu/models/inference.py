@@ -29,6 +29,7 @@ from flax import linen as nn
 
 from skypilot_tpu import exceptions
 from skypilot_tpu.models import kv_cache as kv_cache_lib
+from skypilot_tpu.models import moe as moe_lib
 from skypilot_tpu.models.configs import ModelConfig, get_config
 from skypilot_tpu.models.transformer import Transformer
 from skypilot_tpu.observability import metrics as obs
@@ -448,6 +449,34 @@ def infer_serving_tp(cfg: ModelConfig, n_devices: int) -> int:
 ENGINE_TIERS = ('monolithic', 'prefill', 'decode')
 
 
+def _refuse_pattern(cfg: ModelConfig, quantize, speculative: int,
+                    decode_kernel: str) -> None:
+    """Levers that cannot take a dropless expert layer or a layer
+    pattern refuse here, by name, before any weight is made (a tp mesh
+    is refused by `assert_tp_compatible`; docs/serving.md "Expert
+    layers and layer patterns")."""
+    routed = cfg.is_moe and cfg.moe_impl == 'dropless'
+    what = ('a dropless expert layer' if routed else 'a layer pattern')
+    for lever, on, why in (
+            (f'quantize={quantize!r}',
+             (routed or cfg.attn_gate) and quantize == 'int8',
+             'models/quantize.py rounds the dense kernels it knows by '
+             'name: expert stacks, most of the weights, would stay as '
+             'they are, and the attention\'s gate_proj is not the '
+             'MLP\'s of that name'),
+            (f'speculative={speculative}', routed and speculative > 0,
+             'the verify program is not told which rows are inert, so '
+             'their drafts would be routed and counted as tokens'),
+            (f'decode_kernel={decode_kernel!r}',
+             cfg.has_layer_pattern and decode_kernel != 'xla',
+             'the fused paged-attention kernel compiles one window in, '
+             'and here the window is the layer\'s, carried by the loop')):
+        if on:
+            raise NotImplementedError(
+                f'{lever} is not supported for {cfg.name}, a model '
+                f'with {what}: {why}')
+
+
 def _refuse_recurrent(cfg: ModelConfig, lever: str, why: str) -> None:
     """Levers that take a request's state to be a list of KV blocks
     refuse a model whose state is more than that (models/ssm.py), by
@@ -519,16 +548,19 @@ class _Inflight:
     churn; a preemption blanks its slot's entry, because the same
     request object may come back); `gen` ties the dispatch to the
     engine generation that issued it — a watchdog recovery discards
-    the whole ring."""
+    the whole ring. `counts` is what rides in with the tokens: the
+    routing counts of this step and of the chunks queued before it
+    (`_note_routing`), their host copies started with `out`'s."""
 
-    __slots__ = ('out', 'reqs', 'active', 'k', 'gen')
+    __slots__ = ('out', 'reqs', 'active', 'k', 'gen', 'counts')
 
-    def __init__(self, out, reqs, active, k, gen):
+    def __init__(self, out, reqs, active, k, gen, counts=()):
         self.out = out
         self.reqs = reqs
         self.active = active
         self.k = k
         self.gen = gen
+        self.counts = counts
 
 
 def greedy_sample(logits: jax.Array, rng: jax.Array,
@@ -1120,6 +1152,7 @@ class ContinuousBatchingEngine:
                  _NO_STATE_IN_STREAM)):
             if on:
                 _refuse_recurrent(base_cfg, lever, why)
+        _refuse_pattern(base_cfg, quantize, speculative, decode_kernel)
         self.cfg, self.params = _resolve_cfg_and_params(
             cfg, params, max_seq_len, rng_seed, quantize, kv_quant,
             mesh=mesh)
@@ -1333,6 +1366,24 @@ class ContinuousBatchingEngine:
         self.model = Transformer(self.cfg)
         self._rng = jax.random.PRNGKey(rng_seed)
         self._recurrent = self.cfg.has_recurrent_state
+        # -------- dropless expert layers (models/moe.py) --------
+        # A routed model's programs return one more output, the routing
+        # counts of the call summed over its expert layers
+        # (moe.ROUTE_COUNTS); it rides to the host with the step's
+        # tokens (`_Inflight.counts`), never with a wait of its own, and
+        # is summed here by the kind of program that counted it:
+        # route_stats['decode'] / ['chunk'] = [calls, *ROUTE_COUNTS].
+        self._routed = (self.cfg.is_moe
+                        and self.cfg.moe_impl == 'dropless')
+        self._mutable = ['cache'] + (['moe_stats'] if self._routed
+                                     else [])
+        self.route_stats = {kind: [0] * (1 + len(moe_lib.ROUTE_COUNTS))
+                            for kind in ('decode', 'chunk')}
+        self._counts_pending: list = []
+        # Which rows of a dispatch are real tokens: a recurrent state
+        # must not advance over the others, a router must not route
+        # them.
+        self._row_valid = self._recurrent or self._routed
         # Decode-tick valid-row cache (recurrent-state models only; see
         # _valid_for): 1 for a decoding slot, 0 for an inert one.
         self._valid_sig: Optional[tuple] = None
@@ -1572,13 +1623,14 @@ class ContinuousBatchingEngine:
             tokens.shape)
         # Recurrent state: the bucket's right pads must not advance it.
         rows = ((None, jnp.reshape(true_len, (1,)))
-                if self._recurrent else None)
+                if self._row_valid else None)
         logits, mutated = self.model.apply(
             self._variables(params, cache1, adapters), tokens, positions,
-            adapter_ids=aids, state_rows=rows, mutable=['cache'])
+            adapter_ids=aids, state_rows=rows, mutable=self._mutable)
         last = jax.lax.dynamic_index_in_dim(logits, true_len - 1, axis=1,
                                             keepdims=False)
-        return last[0], nn.unbox(mutated['cache'])
+        return (last[0], nn.unbox(mutated['cache'])) + self._riding(
+            self._route_counts(mutated))
 
     def _prefill_continue_impl(self, params, cache1, tokens, start_pos,
                                suffix_true_len, adapters=None,
@@ -1591,12 +1643,15 @@ class ContinuousBatchingEngine:
         positions = start_pos + jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
             tokens.shape)
+        rows = ((None, jnp.reshape(suffix_true_len, (1,)))
+                if self._routed else None)
         logits, mutated = self.model.apply(
             self._variables(params, cache1, adapters), tokens, positions,
-            adapter_ids=aids, mutable=['cache'])
+            adapter_ids=aids, state_rows=rows, mutable=self._mutable)
         last = jax.lax.dynamic_index_in_dim(logits, suffix_true_len - 1,
                                             axis=1, keepdims=False)
-        return last[0], nn.unbox(mutated['cache'])
+        return (last[0], nn.unbox(mutated['cache'])) + self._riding(
+            self._route_counts(mutated))
 
     def _insert_impl(self, cache, cache1, slot):
         """Copy a batch-1 prefilled cache into slot `slot` of the big
@@ -1628,12 +1683,14 @@ class ContinuousBatchingEngine:
         tenants). `valid` (recurrent-state models only): (num_slots,)
         1 for a decoding slot, 0 for an empty or prefilling one, whose
         recurrent state the step must leave bit for bit — unlike K and
-        V, it has no scratch block to absorb an inert row's write."""
+        V, it has no scratch block to absorb an inert row's write — and
+        which an expert layer neither routes nor counts. Returns
+        (tokens, cache, routing counts or None)."""
         logits, mutated = self.model.apply(
             self._variables(params, cache, adapters), tokens, positions,
             block_tables=tables, adapter_ids=aids,
             state_rows=None if valid is None else (None, valid),
-            mutable=['cache'])
+            mutable=self._mutable)
         last = logits[:, -1, :].astype(jnp.float32)
         greedy = jnp.argmax(last, axis=-1)
         scaled = apply_logit_filters(
@@ -1641,27 +1698,47 @@ class ContinuousBatchingEngine:
             self.top_k, self.top_p)
         sampled = jax.random.categorical(rng, scaled, axis=-1)
         out = jnp.where(temps <= 0, greedy, sampled).astype(jnp.int32)
-        return out, nn.unbox(mutated['cache'])
+        return (out, nn.unbox(mutated['cache']),
+                self._route_counts(mutated))
+
+    @staticmethod
+    def _route_counts(mutated):
+        """moe.ROUTE_COUNTS of one apply, summed over its expert layers
+        (every leaf of 'moe_stats' is (layers, counts)); None for a
+        model that counts nothing."""
+        leaves = jax.tree.leaves(mutated.get('moe_stats', {}))
+        if not leaves:
+            return None
+        return sum(jnp.sum(leaf.reshape(-1, leaf.shape[-1]), axis=0)
+                   for leaf in leaves)
+
+    @staticmethod
+    def _riding(counts) -> tuple:
+        """The one more output of a routed model's program; nothing
+        for any other, whose programs stay what they were."""
+        return () if counts is None else (counts,)
 
     def _decode_multi_impl(self, params, cache, tokens, positions, temps,
                            rngs, tables=None, adapters=None, aids=None,
                            valid=None):
         """K all-slots decode steps in one dispatch (K = rngs' leading
-        dim): returns ((num_slots, K) tokens, cache). tokens/positions:
+        dim): returns ((num_slots, K) tokens, cache, routing counts
+        of the K steps or None). tokens/positions:
         (num_slots,). Paged mode: the engine pre-allocates blocks to
         cover all K positions, so `tables` stays fixed across the
         scan."""
 
         def body(carry, rng):
             cache, toks, pos = carry
-            out, cache = self._decode_impl(params, cache, toks[:, None],
-                                           pos[:, None], temps, rng,
-                                           tables, adapters, aids, valid)
-            return (cache, out, pos + 1), out
+            out, cache, counts = self._decode_impl(
+                params, cache, toks[:, None], pos[:, None], temps, rng,
+                tables, adapters, aids, valid)
+            return (cache, out, pos + 1), (out, counts)
 
-        (cache, _, _), toks = jax.lax.scan(
+        (cache, _, _), (toks, counts) = jax.lax.scan(
             body, (cache, tokens, positions), rngs)
-        return toks.swapaxes(0, 1), cache
+        return (toks.swapaxes(0, 1), cache,
+                None if counts is None else jnp.sum(counts, axis=0))
 
     def _decode_step_impl(self, params, cache, tokens, positions, temps,
                           rng, tables=None, adapters=None, aids=None,
@@ -1676,26 +1753,26 @@ class ContinuousBatchingEngine:
         harmless cache (contiguous: their own row, overwritten whole by
         the next _insert; paged: the scratch block) and are never
         read."""
-        out, cache = self._decode_impl(params, cache, tokens[:, None],
-                                       positions[:, None], temps, rng,
-                                       tables, adapters, aids, valid)
+        out, cache, counts = self._decode_impl(
+            params, cache, tokens[:, None], positions[:, None], temps,
+            rng, tables, adapters, aids, valid)
         out = self._repl_constrain(out)
         return (out[:, None],
-                (out, self._repl_constrain(positions + 1)), cache)
+                (out, self._repl_constrain(positions + 1)),
+                cache) + self._riding(counts)
 
     def _decode_multi_feed_impl(self, params, cache, tokens, positions,
                                 temps, rngs, tables=None, adapters=None,
                                 aids=None, valid=None):
         """K-step variant of _decode_step_impl (K = rngs' leading dim):
         ((num_slots, K) columns, next feed, cache)."""
-        toks, cache = self._decode_multi_impl(params, cache, tokens,
-                                              positions, temps, rngs,
-                                              tables, adapters, aids,
-                                              valid)
+        toks, cache, counts = self._decode_multi_impl(
+            params, cache, tokens, positions, temps, rngs, tables,
+            adapters, aids, valid)
         toks = self._repl_constrain(toks)
-        return toks, (toks[:, -1],
-                      self._repl_constrain(positions + rngs.shape[0])), \
-            cache
+        return (toks, (toks[:, -1],
+                       self._repl_constrain(positions + rngs.shape[0])),
+                cache) + self._riding(counts)
 
     def _repl_constrain(self, x):
         """Pin an in-graph feed/emit array to REPLICATED under a tp
@@ -1738,8 +1815,9 @@ class ContinuousBatchingEngine:
             self._variables(params, cache, adapters), tokens, positions,
             block_tables=tables, adapter_ids=aids,
             head_rows=jnp.reshape(true_n - 1, (1,)), state_rows=rows,
-            mutable=['cache'])
-        return logits[0, 0], nn.unbox(mutated['cache'])
+            mutable=self._mutable)
+        return (logits[0, 0], nn.unbox(mutated['cache'])) + self._riding(
+            self._route_counts(mutated))
 
     def _cow_copy_impl(self, cache, src, dst):
         """Copy-on-write: clone physical block `src` into `dst` across
@@ -2028,6 +2106,7 @@ class ContinuousBatchingEngine:
             # generation before emitting, so this is belt and braces.)
             self._ring.clear()
             self._joins = []
+            self._counts_pending = []
             _DISPATCH_AHEAD.set(0)
             self._feed = None
             self._temps_sig = None
@@ -2402,10 +2481,10 @@ class ContinuousBatchingEngine:
 
     def _valid_for(self, active_set):
         """(num_slots,) int32, 1 for each decoding slot (recurrent-state
-        models only; None otherwise so other models' jit signatures
-        stay unchanged). Cached under the active set, which changes
+        and routed models only; None otherwise so other models' jit
+        signatures stay unchanged). Cached under the active set, which changes
         only with slot churn: steady-state ticks upload nothing."""
-        if not self._recurrent:
+        if not self._row_valid:
             return None
         sig = tuple(sorted(active_set))
         if sig != self._valid_sig:
@@ -2549,7 +2628,7 @@ class ContinuousBatchingEngine:
                 continue
             chunk = req.context[start:start + n] + \
                 [0] * (self.prefill_chunk - n)
-            logits, pool_arr = self._prefill_chunk_fn(
+            logits, pool_arr, *counts = self._prefill_chunk_fn(
                 self.params, self._cache,
                 _upload([chunk], jnp.int32, self._repl),
                 self._table_array([req]),
@@ -2557,7 +2636,8 @@ class ContinuousBatchingEngine:
                 _upload(n, jnp.int32, self._repl),
                 self._adapters, self._aids_single(req),
                 _upload(slot, jnp.int32, self._repl)
-                if self._recurrent else None)
+                if self._row_valid else None)
+            self._queue_counts('chunk', 1, counts)
             self._commit_gen(gen,
                              lambda: setattr(self, '_cache', pool_arr))
             req.prefill_pos = start + n
@@ -2787,6 +2867,18 @@ class ContinuousBatchingEngine:
             'decode_chained': self.tick_stats['chained'],
             'ring_flushes': self.tick_stats['flushes'],
         }
+        if self._routed:
+            # what the dropless expert layers routed, summed over the
+            # expert layers and over every decode step / prefill chunk
+            # whose tokens have landed (moe.ROUTE_COUNTS; docs/
+            # observability.md)
+            occ['expert_layers'] = (self.cfg.num_layers
+                                    - self.cfg.num_dense_layers)
+            occ['experts_held'] = self.cfg.held_experts
+            for kind, total in self.route_stats.items():
+                occ[f'route_{kind}_calls'] = total[0]
+                for name, value in zip(moe_lib.ROUTE_COUNTS, total[1:]):
+                    occ[f'route_{kind}_{name}'] = value
         if self._tp > 1 and self._cache is not None:
             # Per-device view: each device holds its kv-head shard of
             # every block, so bytes — not block counts — divide by tp.
@@ -3559,7 +3651,7 @@ class ContinuousBatchingEngine:
             bucket = self._bucket(len(suffix))
             tokens = _upload([suffix + [0] * (bucket - len(suffix))],
                              jnp.int32, self._repl)
-            logits, cache1 = self._prefill_continue(
+            logits, cache1, *counts = self._prefill_continue(
                 self.params, pcache, tokens,
                 _upload(plen, jnp.int32, self._repl),
                 _upload(len(suffix), jnp.int32, self._repl),
@@ -3572,13 +3664,14 @@ class ContinuousBatchingEngine:
             bucket = self._bucket(true_len)
             padded = context + [0] * (bucket - true_len)
             tokens = _upload([padded], jnp.int32, self._repl)
-            logits, cache1 = self._prefill(
+            logits, cache1, *counts = self._prefill(
                 self.params, tokens,
                 _upload(true_len, jnp.int32, self._repl),
                 self._adapters, self._aids_single(req))
             if use_prefix:
                 self.prefix_stats['misses'] += 1
                 _PREFIX_MISS.inc()
+        self._queue_counts('chunk', 1, counts)
         if gen >= 0:
             self._check_gen(gen)
         if use_prefix:
@@ -4251,14 +4344,15 @@ class ContinuousBatchingEngine:
         valid = self._valid_for(active_set)
         self._rng, rng = jax.random.split(self._rng)
         if k == 1:
-            out_cols, feed_next, cache = self._decode(
+            out_cols, feed_next, cache, *counts = self._decode(
                 self.params, self._cache, tok_dev, pos_dev, temps, rng,
                 tables, self._adapters, aids, valid)
         else:
             rngs = jax.random.split(rng, k)
-            out_cols, feed_next, cache = self._decode_multi(
+            out_cols, feed_next, cache, *counts = self._decode_multi(
                 self.params, self._cache, tok_dev, pos_dev, temps,
                 rngs, tables, self._adapters, aids, valid)
+        self._queue_counts('decode', k, counts)
         self._commit_gen(gen, lambda: setattr(self, '_cache', cache))
         self._decode_steps += k
         self.step_log.append((self._decode_steps, frozenset(active)))
@@ -4271,8 +4365,11 @@ class ContinuousBatchingEngine:
             for i in range(self.num_slots)))
         self.tick_stats['dispatches'] += 1
         out_cols.copy_to_host_async()
+        # Every program queued so far ends before this step does: its
+        # counts land with this step's tokens.
+        riding, self._counts_pending = self._counts_pending, []
         self._ring.append(_Inflight(out_cols, list(slots), list(active),
-                                    k, gen))
+                                    k, gen, riding))
         if self.async_depth:
             depth = len(self._ring)
             _DISPATCH_AHEAD.set(depth)
@@ -4301,12 +4398,28 @@ class ContinuousBatchingEngine:
         # The wait above may span a watchdog recovery: never emit into
         # a successor's world.
         self._check_gen(gen)
+        for kind, calls, counts in infl.counts:
+            self._note_routing(kind, calls, _land(counts))
         live = [i for i in infl.active if slots[i] is infl.reqs[i]]
         for i in live:
             slots[i].inflight -= infl.k
         if live:
             with tracing.phase('engine.tick.emit'):
                 self._emit(slots, live, out_cols, None)
+
+    def _queue_counts(self, kind: str, calls: int, counts: list) -> None:
+        """A routed program's counts (`counts` holds the program's one
+        more output, or nothing): their host copy starts now and they
+        ride in with the next decode step's tokens."""
+        for arr in counts:
+            arr.copy_to_host_async()
+            self._counts_pending.append((kind, calls, arr))
+
+    def _note_routing(self, kind: str, calls: int, counts) -> None:
+        total = self.route_stats[kind]
+        total[0] += calls
+        for i, c in enumerate(counts):
+            total[1 + i] += int(c)
 
     def _flush_ring(self, slots, gen: int) -> None:
         """Drain the whole pipeline oldest-first (spec ticks, a feed

@@ -1,23 +1,57 @@
-"""Mixture-of-Experts block (Mixtral-style) with expert parallelism.
+"""Mixture-of-Experts block with expert parallelism.
 
 Experts are a stacked weight dim carrying logical axis 'expert' → mesh axis
-`ep`. Two formulations, selected by ``cfg.moe_impl``:
+`ep`. Three formulations, selected by ``cfg.moe_impl``. **Whose is
+which:** the TRAINER uses `dispatch` (the default) or `dense`; the
+ENGINE serves `dropless`. Capacity dispatch drops the tokens that
+overflow an expert's buffer, and which tokens those are depends on who
+else is in the batch: a served request's output would change with its
+neighbours, and neither the engine's tests nor a comparison with a
+reference could hold. So nothing that serves takes `dispatch`.
 
-- **dispatch** (default): GShard/Switch-style capacity-based token
-  dispatch. Each token's top-k experts get it via a one-hot dispatch
-  einsum into per-expert capacity buffers (E, C, D); only the chosen
-  experts compute — k/E of the dense formulation's expert FLOPs. Under
-  `ep` sharding GSPMD turns the token-sharded → expert-sharded buffer
-  movement into the EP collective (an all-to-all when tokens and
+- **dispatch** (default; training): GShard/Switch-style capacity-based
+  token dispatch. Each token's top-k experts get it via a one-hot
+  dispatch einsum into per-expert capacity buffers (E, C, D); only the
+  chosen experts compute — k/E of the dense formulation's expert FLOPs.
+  Under `ep` sharding GSPMD turns the token-sharded → expert-sharded
+  buffer movement into the EP collective (an all-to-all when tokens and
   experts ride the same mesh axis; otherwise an all-reduce of the
   capacity buffers with identical volume) — the TPU-native EP data
   path, MaxText's dense-dispatch formulation. (jucor/skypilot has no
   in-tree MoE; its Mixtral/dbrx recipes delegate EP to vLLM/megablocks,
   SURVEY §2.9.) Tokens over an expert's capacity are dropped (standard
   GShard semantics; capacity_factor 1.25 gives headroom).
-- **dense**: every expert computes every token and a top-k one-hot
-  combine zeroes the rest. Exact (no drops), E/k× more expert FLOPs;
-  kept as the correctness reference and for tiny test configs.
+- **dense** (training's exact reference, tiny configs): every expert
+  computes every token and a top-k one-hot combine zeroes the rest.
+  Exact (no drops), E/k× more expert FLOPs.
+- **dropless** (serving): the layer is told which experts it holds
+  (`cfg.experts_held`, `cfg.first_expert`: one chip's share of an
+  expert-parallel layer, or all of them). The router keeps its whole
+  width; selection, the weights' normalisation and the scale are over
+  the token's whole top k, whoever holds them. The (token, choice)
+  pairs held here are sorted by expert and go through three grouped
+  matrix products over the expert stacks (`jax.lax.ragged_dot`, which
+  XLA lowers on the TPU to a grouped-matmul kernel of its own, whose
+  time goes by the groups that hold rows: an expert no token chose is
+  not read; PERF.md section 6, PR 33); what the absent experts would
+  have added is left out, and no code stands in for their chips or
+  their exchange. A layer loop that holds every layer's experts in one
+  stack hands them over whole (`ExpertStacks`): the products then run
+  over all the stack's groups with this layer's alone holding rows,
+  where a slice of the layer's experts would be copied first. A
+  token whose choices all lie elsewhere gets the shared expert's output
+  alone. Shapes are static whatever the routing: every pair has a row,
+  the pairs held elsewhere sorted behind the last group, where no
+  product reaches them. Pads of a chunk and inert slots of a decode
+  step (`valid`) route nowhere and count nowhere. Scoring is Mixtral's
+  softmax over the chosen k, or a sigmoid over all experts with a
+  selection bias (`cfg.router_score`, `cfg.router_bias`); an expert's
+  width may differ from `d_mlp` (`cfg.d_expert`), and one shared expert
+  may run on every token (`cfg.d_shared_expert`).
+
+  The dropless layer sows what it routed into the 'moe_stats'
+  collection (a no-op unless the caller makes it mutable, as the engine
+  does): `ROUTE_COUNTS` a call.
 """
 from __future__ import annotations
 
@@ -25,16 +59,65 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+import dataclasses
+from typing import Optional, Tuple
+
 from skypilot_tpu.models.configs import ModelConfig
+from skypilot_tpu.models.transformer import SwiGLU
 from skypilot_tpu.parallel import sharding
+
+# What a dropless layer counts a call, in this order: (token, choice)
+# pairs routed (k a real token), pairs whose expert is held here,
+# distinct held experts that got a token, the largest held expert's
+# tokens.
+ROUTE_COUNTS = ('pairs_routed', 'pairs_held', 'experts_touched',
+                'max_expert_load')
+
+
+def expert_stacks(module: nn.Module, cfg: ModelConfig, layers: int
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(w_gate, w_up, w_down) of the experts held here, declared in
+    `module`'s scope for `layers` layers at once: (layers, held, in,
+    out)."""
+    d, m, held = cfg.d_model, cfg.expert_width, cfg.held_experts
+    stack = lambda name, shape, axes: module.param(
+        name, nn.with_logical_partitioning(
+            nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                         batch_axis=(0, 1)),
+            ('layers', 'expert') + axes),
+        (layers, held) + shape, jnp.dtype(cfg.param_dtype))
+    return (stack('w_gate', (d, m), ('embed', 'mlp')),
+            stack('w_up', (d, m), ('embed', 'mlp')),
+            stack('w_down', (m, d), ('mlp', 'embed')))
+
+
+class ExpertStacks(nn.Module):
+    """Every expert layer's held experts as three whole leaves, declared
+    OUTSIDE the layer loop (`cache_carry.carry_layers`), which hands
+    them to each layer unsliced beside the layer's index."""
+    cfg: ModelConfig
+    layers: int
+
+    @nn.compact
+    def __call__(self):
+        return expert_stacks(self, self.cfg, self.layers)
 
 
 class MoEBlock(nn.Module):
     cfg: ModelConfig
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array,
+                 valid: Optional[jax.Array] = None,
+                 stacks: Optional[Tuple] = None) -> jax.Array:
+        """x: (B, S, D). The dropless layer alone reads the rest.
+        valid: (B, S) bool or None, the positions that are real tokens.
+        stacks: None (the layer declares its own expert weights), or
+        `(ExpertStacks' three leaves, this layer's index in them)` from
+        a layer loop that holds every layer's experts in one stack."""
         cfg = self.cfg
+        if cfg.moe_impl == 'dropless':
+            return self._dropless(x, valid, stacks)
         dtype = jnp.dtype(cfg.dtype)
         pdtype = jnp.dtype(cfg.param_dtype)
         e, d, m = cfg.num_experts, cfg.d_model, cfg.d_mlp
@@ -74,7 +157,7 @@ class MoEBlock(nn.Module):
             # over-capacity tokens; dense is exact).
             raise ValueError(
                 f'Unknown moe_impl {cfg.moe_impl!r}; expected '
-                f"'dispatch' or 'dense'.")
+                f"'dispatch', 'dense' or 'dropless'.")
         return self._dispatch(x, topk_idx, topk_probs,
                               (w_gate, w_up, w_down), dtype)
 
@@ -183,3 +266,105 @@ class MoEBlock(nn.Module):
                          expert_out.astype(jnp.float32), combine)
         out = out.reshape(b, s, d).astype(dtype)
         return sharding.constrain(out, 'batch', 'seq', 'act_embed')
+
+    # ---------------- dropless, for serving ----------------
+
+    def _dropless(self, x, valid, stacks):
+        cfg = self.cfg
+        dtype = jnp.dtype(cfg.dtype)
+        e, k, d = cfg.num_experts, cfg.experts_per_token, cfg.d_model
+        held, first = cfg.held_experts, cfg.first_expert
+        if not 0 <= first <= e - held:
+            raise ValueError(
+                f'experts [{first}, {first + held}) are not among the '
+                f'{e} the router scores')
+        if cfg.mlp_style != 'glu':
+            raise NotImplementedError(
+                'the dropless expert is a gated MLP (gate, up, down)')
+        router_w = self.param(
+            'router', nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), ('embed', None)),
+            (d, e), jnp.dtype(cfg.param_dtype))
+        if stacks is None:
+            # a layer on its own: its experts are a stack of one layer
+            w_gate, w_up, w_down = expert_stacks(self, cfg, 1)
+            layer = 0
+        else:
+            (w_gate, w_up, w_down), layer = stacks
+        # The grouped products are handed EVERY layer's experts as one
+        # stack of groups, of which this layer's alone hold rows: a
+        # slice of the layer's own would be a copy of it, 1.8 GB a
+        # layer at Trinity's widths, before a kernel could read it.
+        groups = w_gate.shape[0] * held
+        as_groups = lambda w: w.reshape((groups,) + w.shape[2:]).astype(
+            dtype)
+
+        b, s, _ = x.shape
+        n = b * s
+        xf = x.reshape(n, d)
+        # ---- routing, in float32, over every expert the model has ----
+        logits = jnp.dot(xf.astype(jnp.float32),
+                         router_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        if cfg.router_score == 'sigmoid':
+            scores = jax.nn.sigmoid(logits)
+        elif cfg.router_score == 'softmax':
+            scores = logits        # Mixtral: softmax over the chosen k
+        else:
+            raise ValueError(
+                f'Unknown router_score {cfg.router_score!r}; expected '
+                f"'softmax' or 'sigmoid'.")
+        select = scores
+        if cfg.router_bias:
+            # for SELECTION only: the weights are the scores themselves
+            select = scores + self.param(
+                'expert_bias', nn.with_logical_partitioning(
+                    nn.initializers.zeros, (None,)),
+                (e,), jnp.float32)
+        _, chosen = jax.lax.top_k(select, k)                  # (n, k)
+        weight = jnp.take_along_axis(scores, chosen, axis=-1)
+        if cfg.router_score == 'softmax':
+            weight = jax.nn.softmax(weight, axis=-1)
+        elif cfg.route_norm:
+            weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+        weight = weight * cfg.route_scale
+
+        # ---- the pairs held here, sorted by expert ----
+        real = (jnp.ones((n,), bool) if valid is None
+                else valid.reshape(n))
+        local = chosen - first
+        here = (local >= 0) & (local < held) & real[:, None]  # (n, k)
+        # a pair held elsewhere (or a pad's) sorts behind the last group
+        group = jnp.where(here, local, held).reshape(n * k)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        in_stack = jax.lax.dynamic_update_slice(
+            jnp.zeros((groups,), jnp.int32), sizes, (layer * held,))
+        rows = xf[order // k].astype(dtype)                  # (n k, d)
+        gate = jax.lax.ragged_dot(rows, as_groups(w_gate), in_stack)
+        up = jax.lax.ragged_dot(rows, as_groups(w_up), in_stack)
+        hidden = (nn.silu(gate) * up).astype(dtype)
+        out = jax.lax.ragged_dot(hidden, as_groups(w_down), in_stack,
+                                 preferred_element_type=jnp.float32)
+        # ---- back to token order, weighted; rows past the last group
+        # hold nothing that was computed, so they are selected out, not
+        # multiplied by zero ----
+        back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32))
+        out = out[back].reshape(n, k, d)
+        routed = jnp.sum(
+            jnp.where(here[..., None], out * weight[..., None], 0.0),
+            axis=1)
+        y = routed.astype(dtype).reshape(b, s, d)
+        if cfg.d_shared_expert:
+            y = y + SwiGLU(dataclasses.replace(
+                cfg, d_mlp=cfg.d_shared_expert), name='shared')(x)
+        if not self.is_initializing():
+            self.sow('moe_stats', 'counts', jnp.stack([
+                k * jnp.sum(real, dtype=jnp.int32),
+                jnp.sum(here, dtype=jnp.int32),
+                jnp.sum(sizes > 0, dtype=jnp.int32),
+                jnp.max(sizes)]),
+                init_fn=lambda: jnp.zeros((len(ROUTE_COUNTS),), jnp.int32),
+                reduce_fn=lambda a, c: a + c)
+        return sharding.constrain(y, 'batch', 'seq', 'act_embed')

@@ -494,8 +494,8 @@ def _attend_window(cfg: ModelConfig, q: jax.Array, k_win: jax.Array,
     q_pos = positions[:, :, None]                          # (b, q, 1)
     k_pos = jnp.arange(seq_len)[None, None, :]             # (1, 1, s)
     mask = k_pos <= q_pos                                  # causal+fill
-    if cfg.sliding_window:
-        mask &= q_pos - k_pos < cfg.sliding_window
+    if layer_window(cfg) is not None:
+        mask &= q_pos - k_pos < layer_window(cfg)
     scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     if kv_quant:
@@ -1045,17 +1045,17 @@ class Transformer(nn.Module):
         if mode == 'embed':
             return x, positions
 
-        if (cfg.decode and cfg.scan_layers
+        if cfg.has_layer_pattern or (cfg.decode and cfg.scan_layers
                 and self.has_variable('cache', 'layers')):
             # A decoding model whose cache exists: the loop CARRIES the
             # stacked (L, ...) cache leaves and every layer writes its
             # part in place, by its index (models/cache_carry.py says
-            # why). A branch of its own, so that the loop below stays
-            # the trainer's, line for line; late import, so that a
-            # trainer never loads it.
+            # why); a layer pattern always comes here. A branch of its
+            # own, so that the loop below stays the trainer's, line for
+            # line; late import, so that a trainer never loads it.
             from skypilot_tpu.models.cache_carry import carry_layers
             x = carry_layers(cfg, x, positions, block_tables, adapter_ids,
-                             state_rows)
+                             state_rows, self.has_variable('cache', 'layers'))
             if head_rows is not None:
                 x = jnp.take_along_axis(x, head_rows[:, None, None],
                                         axis=1)
@@ -1119,3 +1119,16 @@ class Transformer(nn.Module):
             logits = jnp.where(valid[None, None, :], logits,
                                jnp.asarray(-1e30, logits.dtype))
         return sharding.constrain(logits, 'batch', 'seq', 'vocab')
+
+
+def layer_window(cfg: ModelConfig) -> Optional[Any]:
+    """Keys a query of the layer being traced looks back over: under a
+    layer pattern's loop the layer's own window, a traced scalar that
+    the loop carries (models/cache_carry.py); else the model's one
+    `sliding_window`. None: no window, no mask."""
+    if cfg.has_layer_pattern:
+        from skypilot_tpu.models.cache_carry import current_kind
+        kind = current_kind()
+        if kind is not None:
+            return kind[0]
+    return cfg.sliding_window or None

@@ -74,6 +74,14 @@ def _cfg(layout: str, **kw):
             vocab_size=512, max_seq_len=128, ssm_heads=4, ssm_head_dim=8,
             ssm_state=16, ssm_groups=2, attention_impl='xla',
             **HEADS, **FALCON_MULTIPLIERS, **kw)
+    if layout == 'afmoe':
+        # a leading dense layer, then expert layers: two carried groups
+        return get_config(
+            'trinity-large-preview', num_layers=6, num_dense_layers=2,
+            d_model=64, d_mlp=128, vocab_size=512, max_seq_len=128,
+            num_experts=8, experts_held=4, first_expert=2,
+            experts_per_token=2, d_expert=32, d_shared_expert=32,
+            layer_kinds=((32, True), (0, False)) * 3, **HEADS, **kw)
     return get_config('test-tiny', num_layers=_layers(layout), **HEADS, **kw)
 
 
@@ -213,7 +221,9 @@ def _compile_programs(engine, one):
     params = sds(engine.params)
     cache = sds(nn.unbox(_boxed_cache(engine)))
     n = engine.num_slots
-    recurrent = engine.cfg.has_recurrent_state
+    # the programs that are told which rows are real: a recurrent
+    # state's, a router's
+    recurrent = engine._row_valid  # pylint: disable=protected-access
     progs = {}
     tables = None
     if engine.paged_block_size:
@@ -292,6 +302,55 @@ def test_compiled_for_the_cpu_no_program_copies_or_slices_a_leaf(layout):
 def test_compiled_for_a_v5e_no_program_copies_or_slices_a_leaf(layout,
                                                                v5e):
     _check_in_place(layout, v5e)
+
+
+def test_compiled_for_the_cpu_a_layer_pattern_carries_a_leaf_a_group():
+    """Two groups of layers ('dense_layers', 'layers'), each with a
+    loop of its own that carries its K and V: no copy, no slice."""
+    _check_in_place('afmoe', None)
+
+
+def test_compiled_for_a_v5e_a_layer_pattern_carries_a_leaf_a_group(v5e):
+    _check_in_place('afmoe', v5e)
+
+
+def test_compiled_for_a_v5e_the_grouped_products_at_published_widths(v5e):
+    """The dropless layer of Trinity-Large-Preview's share on one chip,
+    a decode step's 128 tokens, its experts one layer of a stack of
+    four: XLA lowers each `ragged_dot` to a grouped-matmul kernel of
+    its own (a `tpu_custom_call`), three of them, handed the stack
+    whole: nothing of one layer's experts' shape is sliced out of it
+    (1.8 GB a layer, which a scanned stack cost: PERF.md section 6,
+    PR 33)."""
+    from skypilot_tpu.models.moe import MoEBlock
+    cfg = get_config('trinity-large-preview', experts_held=32,
+                     param_dtype='bfloat16')
+    block = MoEBlock(cfg)
+    arr = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=v5e)
+    x = arr(jnp.bfloat16, 128, 1, 3072)
+    own = nn.unbox(jax.eval_shape(lambda: block.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 1, 3072), jnp.bfloat16))
+    )['params'])
+    assert own['w_gate'].shape == (1, 32, 3072, 3072)
+    stacks = tuple(arr(jnp.bfloat16, 4, *own[n].shape[1:])
+                   for n in ('w_gate', 'w_up', 'w_down'))
+    params = {n: jax.tree.map(lambda a: arr(a.dtype, *a.shape), own[n])
+              for n in ('router', 'expert_bias', 'shared')}
+    compiled = jax.jit(lambda p, s, x, layer: block.apply(
+        {'params': p}, x, None, (s, layer))).trace(
+            params, stacks, x, arr(jnp.int32)).lower(
+                lowering_platforms=('tpu',)).compile()
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines()
+             if 'custom-call(' in l and 'tpu_custom_call' in l
+             and re.search(r'= (f32|bf16)\[512,3072\]', l)]
+    assert len(calls) == 3, text[:2000]
+    assert all('[128,3072,3072]' in l for l in calls)
+    assert _new_buffers(text, {('bf16', (32, 3072, 3072)),
+                               ('bf16', (1, 32, 3072, 3072))}) == []
+    # scratch: the sorted rows and the products' outputs, a few MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 # What the checker reads in the scanned form, as the TPU compiler
