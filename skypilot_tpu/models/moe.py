@@ -30,15 +30,15 @@ reference could hold. So nothing that serves takes `dispatch`.
   width; selection, the weights' normalisation and the scale are over
   the token's whole top k, whoever holds them. The (token, choice)
   pairs held here are sorted by expert and go through three grouped
-  matrix products over the expert stacks (`jax.lax.ragged_dot`, which
-  XLA lowers on the TPU to a grouped-matmul kernel of its own, whose
-  time goes by the groups that hold rows: an expert no token chose is
-  not read; PERF.md section 6, PR 33); what the absent experts would
-  have added is left out, and no code stands in for their chips or
-  their exchange. A layer loop that holds every layer's experts in one
-  stack hands them over whole (`ExpertStacks`): the products then run
-  over all the stack's groups with this layer's alone holding rows,
-  where a slice of the layer's experts would be copied first. A
+  matrix products over the expert stacks (`ops/grouped_matmul.py`: on
+  a TPU a Pallas kernel of the repo's own that streams each touched
+  expert's matrix from HBM once, elsewhere `jax.lax.ragged_dot`; an
+  expert no token chose is not read; PERF.md section 6, PR 34); what
+  the absent experts would have added is left out, and no code stands
+  in for their chips or their exchange. A layer loop that holds every
+  layer's experts in one stack hands them over whole (`ExpertStacks`)
+  beside the layer's index, which the products add where they address
+  the weights: a slice of the layer's experts would be copied first. A
   token whose choices all lie elsewhere gets the shared expert's output
   alone. Shapes are static whatever the routing: every pair has a row,
   the pairs held elsewhere sorted behind the last group, where no
@@ -64,6 +64,7 @@ from typing import Optional, Tuple
 
 from skypilot_tpu.models.configs import ModelConfig
 from skypilot_tpu.models.transformer import SwiGLU
+from skypilot_tpu.ops.grouped_matmul import grouped_matmul
 from skypilot_tpu.parallel import sharding
 
 # What a dropless layer counts a call, in this order: (token, choice)
@@ -291,13 +292,10 @@ class MoEBlock(nn.Module):
             layer = 0
         else:
             (w_gate, w_up, w_down), layer = stacks
-        # The grouped products are handed EVERY layer's experts as one
-        # stack of groups, of which this layer's alone hold rows: a
-        # slice of the layer's own would be a copy of it, 1.8 GB a
-        # layer at Trinity's widths, before a kernel could read it.
-        groups = w_gate.shape[0] * held
-        as_groups = lambda w: w.reshape((groups,) + w.shape[2:]).astype(
-            dtype)
+        # The grouped products are handed EVERY layer's experts whole,
+        # beside this layer's index in them: a slice of the layer's own
+        # would be a copy of it, 1.8 GB a layer at Trinity's widths,
+        # before a kernel could read it.
 
         b, s, _ = x.shape
         n = b * s
@@ -338,14 +336,12 @@ class MoEBlock(nn.Module):
         group = jnp.where(here, local, held).reshape(n * k)
         order = jnp.argsort(group, stable=True)
         sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-        in_stack = jax.lax.dynamic_update_slice(
-            jnp.zeros((groups,), jnp.int32), sizes, (layer * held,))
         rows = xf[order // k].astype(dtype)                  # (n k, d)
-        gate = jax.lax.ragged_dot(rows, as_groups(w_gate), in_stack)
-        up = jax.lax.ragged_dot(rows, as_groups(w_up), in_stack)
+        gate = grouped_matmul(rows, w_gate, sizes, layer)
+        up = grouped_matmul(rows, w_up, sizes, layer)
         hidden = (nn.silu(gate) * up).astype(dtype)
-        out = jax.lax.ragged_dot(hidden, as_groups(w_down), in_stack,
-                                 preferred_element_type=jnp.float32)
+        out = grouped_matmul(hidden, w_down, sizes, layer,
+                             preferred_element_type=jnp.float32)
         # ---- back to token order, weighted; rows past the last group
         # hold nothing that was computed, so they are selected out, not
         # multiplied by zero ----

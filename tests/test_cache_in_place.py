@@ -314,15 +314,20 @@ def test_compiled_for_a_v5e_a_layer_pattern_carries_a_leaf_a_group(v5e):
     _check_in_place('afmoe', v5e)
 
 
-def test_compiled_for_a_v5e_the_grouped_products_at_published_widths(v5e):
+def test_compiled_for_a_v5e_the_grouped_products_at_published_widths(
+        v5e, monkeypatch):
     """The dropless layer of Trinity-Large-Preview's share on one chip,
     a decode step's 128 tokens, its experts one layer of a stack of
-    four: XLA lowers each `ragged_dot` to a grouped-matmul kernel of
-    its own (a `tpu_custom_call`), three of them, handed the stack
-    whole: nothing of one layer's experts' shape is sliced out of it
-    (1.8 GB a layer, which a scanned stack cost: PERF.md section 6,
-    PR 33)."""
+    four: each grouped product is the repo's own Pallas kernel
+    (`moe_gmm`, `ops/grouped_matmul.py`), three of them, handed the
+    stack whole with the layer as a scalar: no `ragged-dot` is left,
+    and nothing of one layer's experts' shape is sliced out of the
+    stack (1.8 GB a layer, which a scanned stack cost: PERF.md section
+    6, PR 33)."""
     from skypilot_tpu.models.moe import MoEBlock
+    from skypilot_tpu.ops import grouped_matmul
+    # the process sees the CPU; the program is compiled for the chip
+    monkeypatch.setattr(grouped_matmul, '_on_tpu', lambda: True)
     cfg = get_config('trinity-large-preview', experts_held=32,
                      param_dtype='bfloat16')
     block = MoEBlock(cfg)
@@ -343,10 +348,13 @@ def test_compiled_for_a_v5e_the_grouped_products_at_published_widths(v5e):
                 lowering_platforms=('tpu',)).compile()
     text = compiled.as_text()
     calls = [l for l in text.splitlines()
-             if 'custom-call(' in l and 'tpu_custom_call' in l
-             and re.search(r'= (f32|bf16)\[512,3072\]', l)]
+             if 'custom-call(' in l and 'tpu_custom_call' in l]
     assert len(calls) == 3, text[:2000]
-    assert all('[128,3072,3072]' in l for l in calls)
+    for l in calls:
+        assert re.match(r'\s*(ROOT )?%moe_gmm(\.\d+)? = (f32|bf16)'
+                        r'\[512,3072\]', l), l
+        assert '[4,32,3072,3072]' in l, l
+    assert 'ragged-dot' not in text and 'ragged_dot' not in text
     assert _new_buffers(text, {('bf16', (32, 3072, 3072)),
                                ('bf16', (1, 32, 3072, 3072))}) == []
     # scratch: the sorted rows and the products' outputs, a few MB
