@@ -215,6 +215,18 @@ class ModelConfig:
     # Sandwich norms: a second RMSNorm on each branch's OUTPUT before it
     # is added to the residual.
     post_norms: bool = False
+    # --- Generation by diffusion over blocks (docs/serving.md) ---
+    # block_length B > 0 ⇒ attention is causal between blocks of B
+    # positions (aligned to position 0) and runs both ways inside one
+    # (`last_key_seen`), and the engine generates a block at a time:
+    # B mask tokens are denoised over passes that each unmask the most
+    # confident positions (denoising_steps passes a whole block; B /
+    # steps a pass, the remainder to the first passes), then one more
+    # pass over the clean block writes its K/V. 0 ⇒ causal and
+    # autoregressive: every other model's programs are what they were.
+    block_length: int = 0
+    denoising_steps: int = 0
+    mask_token_id: int = -1
     # Execution knobs.
     scan_layers: bool = True          # lax.scan over stacked layers
     remat: bool = True                # checkpoint each layer
@@ -272,6 +284,14 @@ class ModelConfig:
         # A JSON override hands lists: keep the config hashable.
         kinds = tuple((int(w), bool(r)) for w, r in self.layer_kinds)
         object.__setattr__(self, 'layer_kinds', kinds)
+        if self.block_length and not (
+                0 < self.denoising_steps <= self.block_length
+                and self.mask_token_id >= 0):
+            raise ValueError(
+                f'{self.name}: block_length {self.block_length} needs '
+                f'1 to {self.block_length} denoising_steps (got '
+                f'{self.denoising_steps}) and a mask_token_id (got '
+                f'{self.mask_token_id})')
         if kinds and len(kinds) != self.num_layers:
             raise ValueError(
                 f'{self.name}: layer_kinds names {len(kinds)} layers, '
@@ -288,7 +308,26 @@ class ModelConfig:
         the output gate, post-norms): such a model takes the grouped
         layer loop, training's plain scan never sees it."""
         return bool(self.layer_kinds or self.num_dense_layers
-                    or self.qk_norm or self.attn_gate or self.post_norms)
+                    or self.qk_norm or self.attn_gate or self.post_norms
+                    or self.block_length)
+
+    def last_key_seen(self, q_pos):
+        """The last key position a query at `q_pos` attends to (an int
+        or an array of positions; plain arithmetic, whatever holds
+        them): itself under the causal mask, the end of its block
+        under the block-causal one. The one definition every XLA
+        attention site masks by (`transformer._attend_window`)."""
+        if not self.block_length:
+            return q_pos
+        return (q_pos // self.block_length + 1) * self.block_length - 1
+
+    def unmask_schedule(self) -> Tuple[int, ...]:
+        """Positions a denoising pass unmasks, by pass number: B / steps
+        each, the remainder to the first passes (SDAR's
+        `get_num_transfer_tokens`)."""
+        base, extra = divmod(self.block_length, self.denoising_steps)
+        return tuple(base + (i < extra)
+                     for i in range(self.denoising_steps))
 
     @property
     def held_experts(self) -> int:
@@ -336,6 +375,12 @@ class ModelConfig:
                 f'scan state, the convolution and the grouped norm are '
                 f'not sharded over `tp` yet (heads and groups would '
                 f'have to split together); serve it at tp=1')
+        if self.block_length:
+            raise NotImplementedError(
+                f'{self.name}: tp={tp} with generation by diffusion over '
+                f'blocks: the block step\'s feed and its (slots, B) '
+                f'logits carry no tp rule yet, and the q/k norms of its '
+                f'layer none either; serve it at tp=1')
         if self.moe_impl == 'dropless' and self.is_moe:
             raise NotImplementedError(
                 f'{self.name}: tp={tp} with a dropless expert layer: '
@@ -666,6 +711,23 @@ TRINITY_LARGE_PREVIEW = _register(ModelConfig(
     layer_kinds=(((4096, True),) * 3 + ((0, False),)) * 15,
     qk_norm=True, attn_gate=True, post_norms=True,
     attention_impl='xla', remat=False))
+
+# --- SDAR-30B-A3B-Chat (JetLM, 2025; model_type sdar_moe), the
+# published config: the Qwen3-MoE decoder it was continued from (48
+# layers, 128 routed experts of 768, 8 a token, softmax weights
+# normalised over the chosen, none shared, no dense layer, q/k RMSNorm,
+# theta 1e6, untied) under a block-causal mask, generating by diffusion
+# over blocks. Block length, steps and the mask token's id are not in
+# the published config (SDAR's generate.py: perf/configs/
+# sdar-30b-a3b-l6.json lists them under `assumed`).
+SDAR_30B_A3B_CHAT = _register(ModelConfig(
+    name='sdar-30b-a3b-chat', vocab_size=151936, d_model=2048,
+    num_layers=48, num_heads=32, num_kv_heads=4, head_dim_override=128,
+    d_mlp=6144, max_seq_len=32768, rope_theta=1000000.0, norm_eps=1e-6,
+    num_experts=128, experts_per_token=8, moe_impl='dropless',
+    d_expert=768, router_score='softmax', route_norm=True,
+    qk_norm=True, block_length=4, denoising_steps=4,
+    mask_token_id=151669, attention_impl='xla', remat=False))
 
 GPT2_124M = _register(ModelConfig(
     name='gpt2-124m', vocab_size=50304, d_model=768, num_layers=12,

@@ -488,6 +488,66 @@ def _refuse_recurrent(cfg: ModelConfig, lever: str, why: str) -> None:
             f'recurrent state: {why}')
 
 
+def _refuse_blocks(cfg: ModelConfig, lever: str, why: str) -> None:
+    """Levers that cannot take generation by diffusion over blocks
+    (`cfg.block_length`) refuse here, by name and with the reason
+    (docs/serving.md "Generation by diffusion over blocks")."""
+    if cfg.block_length:
+        raise NotImplementedError(
+            f'{lever} is not supported for {cfg.name}, a model that '
+            f'generates by diffusion over blocks: {why}')
+
+
+_NO_BLOCK_SHARING = (
+    'a block\'s K/V is held only from its commit pass and a prefix ends '
+    'where its prompt does, inside a block whose first pass has not run: '
+    'sharing needs snapshots at block boundaries')
+_NO_BLOCK_STREAM = (
+    'a KV chunk stream hands over a prompt\'s blocks up to its last '
+    'token, and a prompt\'s tail is not committed before its block is '
+    'denoised')
+
+
+def _refuse_block_levers(cfg: ModelConfig, *, speculative, decode_chunk,
+                         prefix_cache, tier, decode_kernel, quantize,
+                         kv_quant, max_adapters, paged_block_size) -> None:
+    """Every constructor lever that block mode cannot take, before any
+    weight is made (a tp mesh is refused by `assert_tp_compatible`)."""
+    b = cfg.block_length
+    for lever, on, why in (
+            (f'speculative={speculative}', speculative > 0,
+             'a draft is verified token by token under the causal mask; '
+             'a block step unmasks by confidence under the block-causal '
+             'one'),
+            (f'decode_chunk={decode_chunk}', decode_chunk > 1,
+             'a scanned dispatch feeds each step the last step\'s one '
+             'token; a pass yields none or several and its next feed is '
+             'a whole block'),
+            (f'prefix_cache={prefix_cache}', prefix_cache > 0,
+             _NO_BLOCK_SHARING),
+            (f'tier={tier!r}', tier != 'monolithic', _NO_BLOCK_STREAM),
+            (f'decode_kernel={decode_kernel!r}', decode_kernel != 'xla',
+             'the fused paged-attention kernel compiles the causal mask '
+             'in (`k_pos <= q_pos`), as the flash and ring kernels do'),
+            (f'quantize={quantize!r}', quantize == 'int8',
+             'confidences decide which position is unmasked, and no '
+             'test holds the int8 path\'s order of unmasking to the '
+             'reference\'s'),
+            (f'kv_quant={kv_quant!r}', bool(kv_quant),
+             'a block\'s keys are rewritten every pass and read back in '
+             'the same pass; the int8 pool\'s rounding of them is held '
+             'to no reference'),
+            (f'max_adapters={max_adapters}', max_adapters > 0,
+             'the block step carries no per-slot adapter index'),
+            (f'paged_block_size={paged_block_size}',
+             paged_block_size <= 0 or paged_block_size % b != 0,
+             f'block mode runs over the paged pool in chunks of whole '
+             f'blocks: a KV block holds a whole number of blocks of '
+             f'{b}')):
+        if on:
+            _refuse_blocks(cfg, lever, why)
+
+
 _NO_STATE_IN_BLOCKS = (
     'a shared KV block carries no snapshot of the scan and convolution '
     'state at its last position')
@@ -750,6 +810,10 @@ class InferenceEngine:
                  top_p: float = 0.0,
                  mesh: Optional[Any] = None,
                  decode_kernel: str = 'xla') -> None:
+        _refuse_blocks(get_config(cfg) if isinstance(cfg, str) else cfg,
+                       'InferenceEngine',
+                       'its generate() feeds back one token a step; '
+                       'ContinuousBatchingEngine has the block mode')
         self.cfg, self.params = _resolve_cfg_and_params(
             cfg, params, max_seq_len, rng_seed, quantize, kv_quant,
             mesh=mesh)
@@ -994,7 +1058,9 @@ class _Request:
                  'prefilling', 'prefill_pos', 'seq', 'trace',
                  'admit_time', 'tier', 'adapter', 'adapter_slot',
                  'adapter_pool', 'context', 'preemptions',
-                 'admit_mono', 'prefill_chunks', 'inflight')
+                 'admit_mono', 'prefill_chunks', 'inflight',
+                 'passes_total', 'passes_done', 'first_passes', 'block0',
+                 'unmask_pass', 'last_cols')
 
     def __init__(self, ids, max_new_tokens, temperature, eos_id, future,
                  on_token=None, deadline=None, tier='standard',
@@ -1065,6 +1131,22 @@ class _Request:
         # tracing-only admit_time): feeds the admission→first-token
         # service EWMA behind deadline-aware admission.
         self.admit_mono: Optional[float] = None
+        # -------- generation by diffusion over blocks --------
+        # Set when the request joins the block step (_join_block): the
+        # passes it needs in all and those whose results have landed
+        # (`inflight` counts the queued ones between), the passes of
+        # its first block (fewer masks: the prompt's tail opens it) and
+        # that block's first position. unmask_pass: for every emitted
+        # token the pass of its block that unmasked it (what a
+        # comparison with a reference replays); last_cols: the tokens of
+        # the last block emitted, whole, so that what max_new_tokens cut
+        # off is known too.
+        self.passes_total = 0
+        self.passes_done = 0
+        self.first_passes = 0
+        self.block0 = 0
+        self.unmask_pass: list = []
+        self.last_cols: list = []
 
 
 class ContinuousBatchingEngine:
@@ -1152,6 +1234,13 @@ class ContinuousBatchingEngine:
                  _NO_STATE_IN_STREAM)):
             if on:
                 _refuse_recurrent(base_cfg, lever, why)
+        if base_cfg.block_length:
+            _refuse_block_levers(
+                base_cfg, speculative=speculative,
+                decode_chunk=decode_chunk, prefix_cache=prefix_cache,
+                tier=tier, decode_kernel=decode_kernel, quantize=quantize,
+                kv_quant=kv_quant, max_adapters=max_adapters,
+                paged_block_size=paged_block_size)
         _refuse_pattern(base_cfg, quantize, speculative, decode_kernel)
         self.cfg, self.params = _resolve_cfg_and_params(
             cfg, params, max_seq_len, rng_seed, quantize, kv_quant,
@@ -1383,7 +1472,30 @@ class ContinuousBatchingEngine:
         # Which rows of a dispatch are real tokens: a recurrent state
         # must not advance over the others, a router must not route
         # them.
-        self._row_valid = self._recurrent or self._routed
+        # -------- generation by diffusion over blocks --------
+        # block_length B > 0: the engine's one loop runs in block mode.
+        # Prefill stops at the prompt's whole blocks and samples
+        # nothing; a decode step is one PASS over every slot's current
+        # block of B positions (_decode_block_impl), whose next feed is
+        # computed in-graph like the autoregressive step's, so the
+        # lookahead ring runs unchanged; a slot joins by having its
+        # row laid over the device feed (_join_block). A pass emits no
+        # token, or the block's new tokens at once.
+        self._block = self.cfg.block_length
+        # Passes that clear m masks under the model's schedule (one
+        # more, the commit pass, writes the clean block's K/V).
+        self._clear_passes = [0]
+        if self._block:
+            unmasked = list(itertools.accumulate(
+                self.cfg.unmask_schedule()))
+            self._clear_passes += [
+                next(n + 1 for n, done in enumerate(unmasked) if done >= m)
+                for m in range(1, self._block + 1)]
+        self.block_stats = {'block_passes': 0, 'block_commits': 0,
+                            'block_tokens': 0,
+                            'block_masked_positions': 0}
+        self._row_valid = (self._recurrent or self._routed
+                           or bool(self._block))
         # Decode-tick valid-row cache (recurrent-state models only; see
         # _valid_for): 1 for a decoding slot, 0 for an inert one.
         self._valid_sig: Optional[tuple] = None
@@ -1478,6 +1590,9 @@ class ContinuousBatchingEngine:
         self._cow_fn = jax.jit(self._cow_copy_impl,
                                donate_argnames=('cache',))
         self._join_feed = jax.jit(self._join_feed_impl)
+        self._decode_block = jax.jit(self._decode_block_impl,
+                                     donate_argnames=('cache',))
+        self._join_block_feed = jax.jit(self._join_block_impl)
         # Adapter slot write: donate the old stack (one device-side
         # dynamic_update_slice per leaf; runs in the tick thread only).
         self._adapter_write = jax.jit(self._adapter_write_impl,
@@ -1811,8 +1926,11 @@ class ContinuousBatchingEngine:
             tokens.shape)
         rows = None if slot is None else (jnp.reshape(slot, (1,)),
                                           jnp.reshape(true_n, (1,)))
+        # Block mode prefills a prompt's whole blocks and samples
+        # nothing: the chunk stops before the final norm and the head.
         logits, mutated = self.model.apply(
             self._variables(params, cache, adapters), tokens, positions,
+            mode='hidden' if self._block else 'full',
             block_tables=tables, adapter_ids=aids,
             head_rows=jnp.reshape(true_n - 1, (1,)), state_rows=rows,
             mutable=self._mutable)
@@ -1858,6 +1976,97 @@ class ContinuousBatchingEngine:
         return (self._repl_constrain(first),
                 self._repl_constrain(tokens.at[slot].set(first)),
                 self._repl_constrain(positions.at[slot].set(where[1])))
+
+    def _block_fed(self, tokens, is_masked):
+        """The ids a pass feeds the model: the mask token wherever the
+        FLAG says masked (never by comparing ids: a prompt may hold the
+        mask token's id, and a model may sample it)."""
+        return jnp.where(is_masked, jnp.int32(self.cfg.mask_token_id),
+                         tokens)
+
+    def _decode_block_impl(self, params, cache, feed, tables, valid):
+        """One PASS over every slot's current block (block mode's decode
+        step; `decode` stays in the name, the benchmark's readers find
+        the program by it). feed = (tokens (S, B), masked (S, B) 0/1,
+        start (S,) the block's first position, step (S,) the pass
+        number within the block, given (S,) how many leading positions
+        the prompt's tail filled, upass (S, B) the pass that unmasked
+        each position, -1 for given ones). valid (S,) 1 for a slot the
+        pass carries: an inert row routes nowhere and counts nowhere.
+
+        The pass forwards the B positions under the block-causal mask
+        (earlier blocks' clean K/V through the table, the block's own
+        keys as they stand now, written at their own positions: nothing
+        reads them but this block, and its commit pass overwrites them),
+        takes x0 = argmax and its probability at every masked position,
+        and unmasks the schedule's count of the most confident masked
+        ones (ties to the lower position). A pass over a block with no
+        mask left is its COMMIT: it has written the clean block's K/V,
+        and the next feed is the next block, all masks.
+
+        Returns (out (S, 2B + 2) int32: the block's new tokens in
+        position order | the pass that unmasked each | how many of them
+        to emit, non-zero on the pass that clears the last mask | masks
+        before the pass (0 marks a commit); the next feed; the cache)
+        and a routed model's counts."""
+        cfg = self.cfg
+        b = cfg.block_length
+        tokens, masked, start, step, given, upass = feed
+        idx = jnp.arange(b, dtype=jnp.int32)
+        positions = start[:, None] + idx[None, :]
+        is_masked = masked > 0
+        logits, mutated = self.model.apply(
+            self._variables(params, cache, None),
+            self._block_fed(tokens, is_masked), positions,
+            block_tables=tables, state_rows=(None, valid * b),
+            mutable=self._mutable)
+        logits = logits.astype(jnp.float32)
+        x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        conf = jnp.exp(jnp.max(logits, axis=-1)
+                       - jax.nn.logsumexp(logits, axis=-1))
+        n_masked = jnp.sum(is_masked, axis=-1, dtype=jnp.int32)
+        sched = jnp.asarray(cfg.unmask_schedule(), jnp.int32)
+        n_take = jnp.minimum(sched[jnp.minimum(step, sched.shape[0] - 1)],
+                             n_masked)
+        # rank of each masked position by confidence, the larger first
+        # and on a tie the lower position: B x B comparisons a row
+        conf = jnp.where(is_masked, conf, -1.0)
+        mine, other = conf[:, :, None], conf[:, None, :]
+        ahead = (other > mine) | ((other == mine)
+                                  & (idx[None, None, :] < idx[None, :, None]))
+        rank = jnp.sum(ahead, axis=-1, dtype=jnp.int32)
+        take = is_masked & (rank < n_take[:, None])
+        tokens = jnp.where(take, x0, tokens)
+        upass = jnp.where(take, step[:, None], upass)
+        left = (is_masked & ~take).astype(jnp.int32)
+        cleared = (n_masked > 0) & (n_masked == n_take)
+        commit = n_masked == 0
+        roll = (idx[None, :] + given[:, None]) % b
+        out = jnp.concatenate([
+            jnp.take_along_axis(tokens, roll, axis=1),
+            jnp.take_along_axis(upass, roll, axis=1),
+            jnp.where(cleared, b - given, 0)[:, None],
+            n_masked[:, None]], axis=1)
+        new = commit[:, None]
+        feed = (jnp.where(new, 0, tokens), jnp.where(new, 1, left),
+                jnp.where(commit, start + b, start),
+                jnp.where(commit, 0, step + 1),
+                jnp.where(commit, 0, given), jnp.where(new, -1, upass))
+        return (out, feed, nn.unbox(mutated['cache'])) + self._riding(
+            self._route_counts(mutated))
+
+    def _join_block_impl(self, feed, row):
+        """Lay a joining slot over the device's block feed. row
+        (2B + 3,) int32: the block's tokens | its mask flags | its first
+        position, the positions given, the slot."""
+        b = self._block
+        tokens, masked, start, step, given, upass = feed
+        slot = row[2 * b + 2]
+        return (tokens.at[slot].set(row[:b]),
+                masked.at[slot].set(row[b:2 * b]),
+                start.at[slot].set(row[2 * b]), step.at[slot].set(0),
+                given.at[slot].set(row[2 * b + 1]),
+                upass.at[slot].set(-1))
 
     def _verify_impl(self, params, cache, tokens, positions, temps, rng,
                      tables=None, adapters=None, aids=None):
@@ -2244,10 +2453,13 @@ class ContinuousBatchingEngine:
                         now: float) -> None:
         if req.trace is None or req.first_token_time is None:
             return
+        attrs = {'slot': slot, 'new_tokens': len(req.tokens)}
+        if self._block:
+            attrs.update(passes=req.passes_done,
+                         blocks=self._block_of(req, req.passes_done - 1)
+                         + 1)
         tracing.record_span('engine.decode', req.first_token_time, now,
-                            parent=req.trace,
-                            attrs={'slot': slot,
-                                   'new_tokens': len(req.tokens)})
+                            parent=req.trace, attrs=attrs)
 
     def _flight_extra(self, why: str) -> dict:
         """Engine state for a flight record: the step_log tail + tick
@@ -2325,6 +2537,56 @@ class ContinuousBatchingEngine:
         sig = feed[2][:slot] + ((req.seq, position),) + feed[2][slot + 1:]
         self._feed = (tok, pos, sig)
         self._joins.append((slots, slot, req, first))
+
+    def _prefill_total(self, req: '_Request') -> int:
+        """Positions a request's prefill covers: its context, or in
+        block mode the context's whole blocks (the tail opens the first
+        generated block)."""
+        n = len(req.context)
+        return n - n % self._block if self._block else n
+
+    def _join_block(self, slots, slot: int, req: '_Request') -> None:
+        """A prompt's whole blocks are prefilled (block mode; there is
+        no first token to sample): plan the request's passes and lay
+        its first block over the device feed, the prompt's tail and
+        masks behind it. The plan is exact, so `_spent` foresees the
+        finish: a block of m masks is `_clear_passes[m]` denoising
+        passes and a commit, and the last block needs no commit."""
+        b = self._block
+        p0 = self._prefill_total(req)
+        tail = req.context[p0:]
+        left = req.max_new_tokens - len(req.tokens)
+        blocks = -(-(len(tail) + left) // b)
+        req.block0 = p0
+        req.first_passes = self._clear_passes[b - len(tail)] + 1
+        req.passes_total = (req.first_passes + (blocks - 1)
+                            * (self._clear_passes[b] + 1) - 1)
+        req.passes_done = req.inflight = 0
+        # as after an autoregressive prefill: the position of the last
+        # token the request holds, so that `_emit` ends it at the window
+        req.next_pos = len(req.context) - 1
+        feed = self._feed
+        if feed is None:
+            zeros = lambda *shape: _upload(
+                np.zeros((self.num_slots,) + shape, np.int32),
+                sharding=self._repl)
+            feed = ((zeros(b), zeros(b), zeros(), zeros(), zeros(),
+                     zeros(b)), None, (None,) * self.num_slots)
+        row = (tail + [0] * (b - len(tail)) + [0] * len(tail)
+               + [1] * (b - len(tail)) + [p0, len(tail), slot])
+        state = self._join_block_feed(
+            feed[0], _upload(row, jnp.int32, self._repl))
+        sig = (feed[2][:slot] + (self._feed_key(req),)
+               + feed[2][slot + 1:])
+        self._feed = (state, None, sig)
+
+    def _block_of(self, req: '_Request', n: int) -> int:
+        """Which of the request's blocks (from 0) its pass `n` works
+        on."""
+        if n < req.first_passes:
+            return 0
+        return 1 + (n - req.first_passes) // (
+            self._clear_passes[self._block] + 1)
 
     def _deliver_first(self, req: '_Request', slot: int,
                        first: int) -> None:
@@ -2582,6 +2844,10 @@ class ContinuousBatchingEngine:
             self._commit_gen(gen, _commit)
         else:
             _commit()
+        if self._block and not self._prefill_total(req):
+            # shorter than a block: nothing to prefill
+            req.prefilling = False
+            self._join_block(self._slots, slot, req)
 
     def _store_prefix_paged(self, req: '_Request') -> None:
         """Publish the freshly prefilled prompt's blocks as a shared
@@ -2611,7 +2877,7 @@ class ContinuousBatchingEngine:
                               # from a successor's pool
         for slot in prefilling:
             req = slots[slot]
-            total = len(req.context)
+            total = self._prefill_total(req)
             start = req.prefill_pos
             n = min(self.prefill_chunk, total - start)
             try:
@@ -2652,6 +2918,9 @@ class ContinuousBatchingEngine:
             self.step_log.append(('prefill', frozenset([slot])))
             if req.prefill_pos >= total:
                 req.prefilling = False
+                if self._block:
+                    self._join_block(slots, slot, req)
+                    continue
                 self._store_prefix_paged(req)
                 self._first_token(slots, slot, req, logits, total)
 
@@ -2867,6 +3136,11 @@ class ContinuousBatchingEngine:
             'decode_chained': self.tick_stats['chained'],
             'ring_flushes': self.tick_stats['flushes'],
         }
+        if self._block:
+            # block mode, of the passes whose results have landed:
+            # row-passes, those that were commits, tokens emitted, and
+            # masked positions forwarded (each needed a row of logits)
+            occ.update(self.block_stats, block_length=self._block)
         if self._routed:
             # what the dropless expert layers routed, summed over the
             # expert layers and over every decode step / prefill chunk
@@ -3027,6 +3301,7 @@ class ContinuousBatchingEngine:
         or kill mid-export publishes nothing (atomic rename).
         Returns the kv_cache stats dict."""
         _refuse_recurrent(self.cfg, 'export_prefixes', _NO_STATE_IN_BLOCKS)
+        _refuse_blocks(self.cfg, 'export_prefixes', _NO_BLOCK_SHARING)
         empty = {'exported': 0, 'blocks': 0, 'skipped': 0,
                  'truncated': False, 'path': path}
         if not (self.paged_block_size and self.prefix_cache):
@@ -3086,6 +3361,7 @@ class ContinuousBatchingEngine:
         artifact from an incompatible pool (block_size / cache layout)
         raises kv_cache.ArtifactError without mutating anything."""
         _refuse_recurrent(self.cfg, 'import_prefixes', _NO_STATE_IN_BLOCKS)
+        _refuse_blocks(self.cfg, 'import_prefixes', _NO_BLOCK_SHARING)
         if not (self.paged_block_size and self.prefix_cache):
             raise ValueError('prefix import requires paged_block_size '
                              'and prefix_cache')
@@ -3261,6 +3537,7 @@ class ContinuousBatchingEngine:
         cached=False means the index evicted the entry already (storm
         pressure) and a subsequent export will fail retryably."""
         _refuse_recurrent(self.cfg, 'prefill_prefix', _NO_STATE_IN_STREAM)
+        _refuse_blocks(self.cfg, 'prefill_prefix', _NO_BLOCK_STREAM)
         ids = [int(t) for t in ids]
         if not (self.paged_block_size and self.prefix_cache):
             raise ValueError('prefill_prefix requires paged_block_size '
@@ -3286,6 +3563,7 @@ class ContinuousBatchingEngine:
         header so the decode replica's ingest spans join the sender's
         trace (docs/observability.md "Tracing")."""
         _refuse_recurrent(self.cfg, 'export_prefix_chunks', _NO_STATE_IN_STREAM)
+        _refuse_blocks(self.cfg, 'export_prefix_chunks', _NO_BLOCK_STREAM)
         if not (self.paged_block_size and self.prefix_cache):
             raise ValueError('export_prefix_chunks requires '
                              'paged_block_size and prefix_cache')
@@ -3393,6 +3671,7 @@ class ContinuousBatchingEngine:
         batched scatter + index publish run in the engine tick thread.
         """
         _refuse_recurrent(self.cfg, 'ingest_chunk', _NO_STATE_IN_STREAM)
+        _refuse_blocks(self.cfg, 'ingest_chunk', _NO_BLOCK_STREAM)
         fault_injection.point('engine.ingest')
         if not (self.paged_block_size and self.prefix_cache):
             raise ValueError('KV ingest requires paged_block_size and '
@@ -3721,6 +4000,18 @@ class ContinuousBatchingEngine:
             'new_tokens': len(req.tokens),
             'prompt_tokens': len(req.ids),
         }
+        if self._block:
+            # the pass of its block that unmasked each token, and what
+            # max_new_tokens (or EOS) cut off the last block: a replay
+            # of a pass needs the whole block
+            n = len(req.tokens)
+            over = len(req.unmask_pass) - n
+            stats.update(
+                passes=req.passes_done,
+                unmask_pass=req.unmask_pass[:n],
+                overshoot_tokens=req.last_cols[len(req.last_cols) - over:]
+                if over else [],
+                overshoot_pass=req.unmask_pass[n:])
         # Decode span BEFORE the future resolves: a caller that
         # snapshots the ring the moment generate() returns must see
         # the request's complete span set.
@@ -4231,6 +4522,10 @@ class ContinuousBatchingEngine:
         and no further step is dispatched for it meanwhile. EOS cannot
         be foreseen and costs up to async_depth discarded steps."""
         ahead = req.inflight
+        if self._block:
+            # block mode counts passes: the plan is exact (_join_block)
+            return ahead > 0 and \
+                req.passes_done + ahead >= req.passes_total
         return ahead > 0 and (
             len(req.tokens) + ahead >= req.max_new_tokens or
             req.next_pos + ahead + 1 >= self.cfg.max_seq_len)
@@ -4262,7 +4557,10 @@ class ContinuousBatchingEngine:
     @staticmethod
     def _feed_key(req: '_Request') -> tuple:
         """What a row of the decode feed is keyed by: the request and
-        the position its next dispatched step writes."""
+        the position its next dispatched step writes (in block mode
+        the number of its next pass, next_pos moving in lumps there)."""
+        if req.passes_total:
+            return (req.seq, req.passes_done + req.inflight)
         return (req.seq, req.next_pos + req.inflight)
 
     def _feed_fits(self, slots, active) -> bool:
@@ -4297,10 +4595,8 @@ class ContinuousBatchingEngine:
             try:
                 for i in active:
                     self._ensure_blocks(req=slots[i],
-                                        upto_pos=min(
-                                            slots[i].next_pos
-                                            + slots[i].inflight + k,
-                                            self.cfg.max_seq_len))
+                                        upto_pos=self._write_end(
+                                            slots[i], k))
             except kv_cache_lib.PoolExhaustedError as e:
                 # Can only happen with an undersized explicit pool:
                 # surface it through the tick-failure path (fails and
@@ -4315,6 +4611,21 @@ class ContinuousBatchingEngine:
             # numpy build + host-to-device transfer, and immune to
             # id()-recycling across request objects.
             tables = self._tables_for(slots, active_set)
+        if self._block:
+            # Block mode: the feed lives on the device from a slot's
+            # join to its finish (the host never sees a block between
+            # its passes), so it always fits, ring or no ring.
+            if not self._feed_fits(slots, active):
+                raise RuntimeError(
+                    'block mode: the device feed does not hold every '
+                    'active slot\'s block')
+            if chain is not None:
+                self.tick_stats['chained'] += 1
+            out_cols, state, cache, *counts = self._decode_block(
+                self.params, self._cache, self._feed[0], tables,
+                self._valid_for(active_set))
+            return self._queued(slots, active, active_set, 1, gen,
+                                out_cols, (state, None), cache, counts)
         tsig = tuple(slots[i].temperature if i in active_set else 0.0
                      for i in range(self.num_slots))
         if tsig != self._temps_sig:
@@ -4352,6 +4663,24 @@ class ContinuousBatchingEngine:
             out_cols, feed_next, cache, *counts = self._decode_multi(
                 self.params, self._cache, tok_dev, pos_dev, temps,
                 rngs, tables, self._adapters, aids, valid)
+        return self._queued(slots, active, active_set, k, gen, out_cols,
+                            feed_next, cache, counts)
+
+    def _write_end(self, req: '_Request', k: int) -> int:
+        """One past the last position the request's next dispatch of k
+        steps writes: its blocks must cover it. In block mode the end
+        of the block that its next pass works on."""
+        if self._block:
+            blk = self._block_of(req, req.passes_done + req.inflight)
+            return min(req.block0 + (blk + 1) * self._block,
+                       self.cfg.max_seq_len)
+        return min(req.next_pos + req.inflight + k, self.cfg.max_seq_len)
+
+    def _queued(self, slots, active, active_set, k, gen, out_cols,
+                feed_next, cache, counts):
+        """A dispatch has been issued: commit its cache, log it, key
+        the feed it returned and put it on the lookahead ring with its
+        host copy started."""
         self._queue_counts('decode', k, counts)
         self._commit_gen(gen, lambda: setattr(self, '_cache', cache))
         self._decode_steps += k
@@ -4405,7 +4734,33 @@ class ContinuousBatchingEngine:
             slots[i].inflight -= infl.k
         if live:
             with tracing.phase('engine.tick.emit'):
-                self._emit(slots, live, out_cols, None)
+                if self._block:
+                    self._emit(slots, live,
+                               *self._landed_pass(slots, live, out_cols))
+                else:
+                    self._emit(slots, live, out_cols, None)
+
+    def _landed_pass(self, slots, live, out):
+        """A block pass has landed (`_decode_block_impl`'s `out`): count
+        it, note the unmask pass of every token it emits, and hand
+        `_emit` the columns and how many of each row are valid (none on
+        most passes, the block's new tokens on the one that cleared its
+        last mask; `_emit` cuts them at max_new_tokens)."""
+        b = self._block
+        valid, masks = out[:, 2 * b], out[live, 2 * b + 1]
+        stats = self.block_stats
+        stats['block_passes'] += len(live)
+        stats['block_masked_positions'] += int(masks.sum())
+        stats['block_commits'] += int((masks == 0).sum())
+        for i in live:
+            req, n = slots[i], int(valid[i])
+            req.passes_done += 1
+            if n:
+                req.unmask_pass.extend(out[i, b:b + n].tolist())
+                req.last_cols = out[i, :n].tolist()
+                if req.first_token_time is None:
+                    self._note_first_token(req, i)
+        return out[:, :b], valid
 
     def _queue_counts(self, kind: str, calls: int, counts: list) -> None:
         """A routed program's counts (`counts` holds the program's one
@@ -4460,6 +4815,8 @@ class ContinuousBatchingEngine:
             # even the disabled-path boolean check adds up in the
             # hottest loop in the codebase).
             _TOKENS_TOTAL.inc(emitted)
+            if self._block:
+                self.block_stats['block_tokens'] += emitted
 
     # ---------------- public api ----------------
 
@@ -4510,6 +4867,12 @@ class ContinuousBatchingEngine:
                     f'engine admission queue is full ({backlog} '
                     f'queued beyond free capacity, cap '
                     f'{self.max_queue_depth})')
+        if temperature > 0:
+            _refuse_blocks(
+                self.cfg, f'temperature={temperature}',
+                'a pass takes x0 = argmax and its probability as the '
+                'confidence that orders the unmasking; sampling x0 is '
+                'not built')
         ids = [int(t) for t in prompt_ids]
         if not ids:
             raise ValueError('empty prompt')

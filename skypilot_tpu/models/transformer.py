@@ -493,7 +493,7 @@ def _attend_window(cfg: ModelConfig, q: jax.Array, k_win: jax.Array,
         scores = cap * jnp.tanh(scores / cap)
     q_pos = positions[:, :, None]                          # (b, q, 1)
     k_pos = jnp.arange(seq_len)[None, None, :]             # (1, 1, s)
-    mask = k_pos <= q_pos                                  # causal+fill
+    mask = k_pos <= cfg.last_key_seen(q_pos)     # (block-)causal+fill
     if layer_window(cfg) is not None:
         mask &= q_pos - k_pos < layer_window(cfg)
     scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
@@ -1059,7 +1059,7 @@ class Transformer(nn.Module):
             if head_rows is not None:
                 x = jnp.take_along_axis(x, head_rows[:, None, None],
                                         axis=1)
-            return self._head(embed, x)
+            return x if mode == 'hidden' else self._head(embed, x)
 
         if cfg.scan_layers:
             layer_cls = _ScannedLayer

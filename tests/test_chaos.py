@@ -1203,6 +1203,14 @@ class TestDisaggHandoff:
         tracing.reset()
         try:
             out = self._post(fleet['lb_url'], ids)
+            # The LB closes its lb.proxy and lb.request spans after it
+            # has written the response, which the client may have read
+            # already: wait for the root (a loaded machine lost the
+            # race twice in two whole runs).
+            deadline = time.monotonic() + 10.0
+            while time.monotonic() < deadline and not any(
+                    s['name'] == 'lb.request' for s in tracing.snapshot()):
+                time.sleep(0.01)
             # (a first-time compile on the way is a span of a trace of
             # its own, not part of the request's)
             spans = [s for s in tracing.snapshot()
