@@ -273,6 +273,12 @@ class _StaleEngineError(Exception):
     the (already replaced) slots/queue/cache of its successor."""
 
 
+# Calls of _upload since the process started. A plain add, no lock: the
+# engine's thread makes the uploads, and another thread uploading at the
+# same instant (an ingest, an adapter load) may lose one count.
+_uploads = 0
+
+
 def _upload(value, dtype=None, sharding=None):
     """The engine's single host→device upload funnel. Every hot-path
     host-list/scalar → device-array conversion routes through here so
@@ -285,7 +291,12 @@ def _upload(value, dtype=None, sharding=None):
     feeds, block tables and temps are tiny and every device needs them
     whole, so replication is THE right layout and pinning it here keeps
     jit signatures stable (no resharding, no recompiles when a feed
-    alternates between host-built and in-graph-chained)."""
+    alternates between host-built and in-graph-chained).
+
+    Each call is counted (`paged_occupancy()['host_uploads']`): one
+    integer add, tracing on or off."""
+    global _uploads
+    _uploads += 1
     arr = jnp.asarray(value, dtype)
     if sharding is not None:
         arr = jax.device_put(arr, sharding)
@@ -1440,6 +1451,9 @@ class ContinuousBatchingEngine:
         # so a speculative engine keeps the host-side first token.
         self._join_ahead = self.async_depth > 0 and speculative <= 0
         self.tick_stats = {'dispatches': 0, 'chained': 0, 'flushes': 0}
+        # `_upload`'s count when this engine was built: paged_occupancy()
+        # reports the engine's uploads as the difference
+        self._uploads_at_start = _uploads
         self._prefix_entries = self._new_prefix_index()
         # Cached routing-digest header value, keyed on (index identity,
         # index epoch) — see prefix_digest().
@@ -2482,13 +2496,10 @@ class ContinuousBatchingEngine:
                         now: float) -> None:
         if req.trace is None or req.first_token_time is None:
             return
-        attrs = {'slot': slot, 'new_tokens': len(req.tokens)}
-        if self._block:
-            attrs.update(passes=req.passes_done,
-                         blocks=self._block_of(req, req.passes_done - 1)
-                         + 1)
         tracing.record_span('engine.decode', req.first_token_time, now,
-                            parent=req.trace, attrs=attrs)
+                            parent=req.trace,
+                            attrs={'slot': slot,
+                                   'new_tokens': len(req.tokens)})
 
     def _flight_extra(self, why: str) -> dict:
         """Engine state for a flight record: the step_log tail + tick
@@ -2909,29 +2920,32 @@ class ContinuousBatchingEngine:
             total = self._prefill_total(req)
             start = req.prefill_pos
             n = min(self.prefill_chunk, total - start)
-            try:
-                # Blocks for the REAL tokens only: the chunk's pad
-                # positions fall on unmapped table entries, which
-                # point at the scratch block (_table_array).
-                self._ensure_blocks(req, start + n)
-            except kv_cache_lib.PoolExhaustedError:
-                slots[slot] = None
-                self._release_blocks(req)
-                self._fail_request(req, exceptions.EngineOverloadedError(
-                    'KV block pool exhausted mid-prefill; request shed '
-                    '(size paged_num_blocks to the load)'))
-                continue
-            chunk = req.context[start:start + n] + \
-                [0] * (self.prefill_chunk - n)
-            logits, pool_arr, *counts = self._prefill_chunk_fn(
-                self.params, self._cache,
-                _upload([chunk], jnp.int32, self._repl),
-                self._table_array([req]),
-                _upload(start, jnp.int32, self._repl),
-                _upload(n, jnp.int32, self._repl),
-                self._adapters, self._aids_single(req),
-                _upload(slot, jnp.int32, self._repl)
-                if self._row_valid else None)
+            with tracing.phase('engine.tick.prefill.inputs'):
+                try:
+                    # Blocks for the REAL tokens only: the chunk's pad
+                    # positions fall on unmapped table entries, which
+                    # point at the scratch block (_table_array).
+                    self._ensure_blocks(req, start + n)
+                except kv_cache_lib.PoolExhaustedError:
+                    slots[slot] = None
+                    self._release_blocks(req)
+                    self._fail_request(
+                        req, exceptions.EngineOverloadedError(
+                            'KV block pool exhausted mid-prefill; request '
+                            'shed (size paged_num_blocks to the load)'))
+                    continue
+                chunk = req.context[start:start + n] + \
+                    [0] * (self.prefill_chunk - n)
+                args = (self.params, self._cache,
+                        _upload([chunk], jnp.int32, self._repl),
+                        self._table_array([req]),
+                        _upload(start, jnp.int32, self._repl),
+                        _upload(n, jnp.int32, self._repl),
+                        self._adapters, self._aids_single(req),
+                        _upload(slot, jnp.int32, self._repl)
+                        if self._row_valid else None)
+            with tracing.phase('engine.tick.prefill.launch'):
+                logits, pool_arr, *counts = self._prefill_chunk_fn(*args)
             self._queue_counts('chunk', 1, counts)
             self._commit_gen(gen,
                              lambda: setattr(self, '_cache', pool_arr))
@@ -2947,11 +2961,12 @@ class ContinuousBatchingEngine:
             self.step_log.append(('prefill', frozenset([slot])))
             if req.prefill_pos >= total:
                 req.prefilling = False
-                if self._block:
-                    self._join_block(slots, slot, req)
-                    continue
-                self._store_prefix_paged(req)
-                self._first_token(slots, slot, req, logits, total)
+                with tracing.phase('engine.tick.prefill.first'):
+                    if self._block:
+                        self._join_block(slots, slot, req)
+                    else:
+                        self._store_prefix_paged(req)
+                        self._first_token(slots, slot, req, logits, total)
 
     # ------------- multi-LoRA adapter pool (serve/tenancy) -------------
 
@@ -3164,6 +3179,9 @@ class ContinuousBatchingEngine:
             'decode_dispatches': self.tick_stats['dispatches'],
             'decode_chained': self.tick_stats['chained'],
             'ring_flushes': self.tick_stats['flushes'],
+            # host-to-device uploads (`_upload` calls) since the engine
+            # was built, of every engine in the process
+            'host_uploads': _uploads - self._uploads_at_start,
         }
         if self._block:
             # block mode, of the passes whose results have landed:
@@ -4361,6 +4379,54 @@ class ContinuousBatchingEngine:
                         if isinstance(e, _StaleEngineError) else e)
                     raise
 
+    def _refresh_gauges(self, slots, queue) -> None:
+        """Saturation signals, refreshed once per tick while an exporter
+        is attached (a gauge set is a no-op otherwise, and its argument
+        is not built then)."""
+        _ACTIVE_SLOTS.set(sum(r is not None and not r.prefilling
+                              for r in slots))
+        _QUEUE_DEPTH.set(queue.qsize())
+        # Per-tier ADMISSION-QUEUE depth (matching the global
+        # skytpu_engine_queue_depth semantics — slotted requests are
+        # _ACTIVE_SLOTS' business).
+        for tier_name, depth in self._queue.tier_depths().items():
+            _TIER_QUEUE_DEPTH.labels(tier=tier_name).set(depth)
+        # Re-set every tick, not only at construction/probe: the
+        # exporter typically enables AFTER warmup, and a gauge set
+        # while recording is disabled is a no-op. Set for every engine,
+        # so a single-chip engine reads the documented 1, not an unset
+        # 0.
+        _TP_SIZE.set(self._tp)
+        _DECODE_KERNEL.set(_DECODE_KERNEL_CODE[self.decode_kernel])
+        if self.decode_kernel != 'xla' and self._pool is not None:
+            # Per-step fused-bytes gauge, recomputed per tick from live
+            # pool occupancy (re-set here, not only at construction —
+            # exporters usually enable after warmup, the PR-5 lesson).
+            _DECODE_FUSED_BYTES.set(self.fused_bytes_per_step())
+        if self._tp > 1 and self._hlo_probe_cache is not None:
+            _TP_COLLECTIVES.set(self._hlo_probe_cache['total'])
+            _TP_ALLREDUCE_BYTES.set(
+                self._hlo_probe_cache['all_reduce_bytes'])
+        if self._pool is not None:
+            # Capacity re-set here (not only at __init__): the exporter
+            # usually enables AFTER engine construction.
+            _PAGED_CAPACITY.set(self._pool.num_blocks)
+            _PAGED_USED.set(self._pool.used)
+            if self.paged_int8_bytes_saved:
+                _PAGED_INT8_SAVED.set(self.paged_int8_bytes_saved)
+            if self._per_dev_gauges:
+                # tp>1: per-device view of the pool. Bytes are static
+                # per engine (pool leaves / tp), computed once the
+                # cache exists; used-blocks match across devices while
+                # the block tables are replicated.
+                if self._pool_dev_bytes is None and \
+                        self._cache is not None:
+                    self._pool_dev_bytes = _tree_bytes(self._cache)[1]
+                for g_used, g_bytes in self._per_dev_gauges:
+                    g_used.set(self._pool.used)
+                    if self._pool_dev_bytes is not None:
+                        g_bytes.set(self._pool_dev_bytes)
+
     def _tick(self, gen: int) -> None:
         self._check_gen(gen)
         # Snapshot the slot table AND the queue: every read/write in
@@ -4401,54 +4467,8 @@ class ContinuousBatchingEngine:
         if self._generation == gen:
             self._heartbeat = time_lib.monotonic()
         self._admitting_tick = False
-        # Saturation signals, refreshed once per tick (cheap: gauge sets
-        # behind the enabled-check).
-        _ACTIVE_SLOTS.set(sum(r is not None and not r.prefilling
-                              for r in slots))
-        _QUEUE_DEPTH.set(queue.qsize())
         if obs.enabled():
-            # Per-tier ADMISSION-QUEUE depth (matching the global
-            # skytpu_engine_queue_depth semantics — slotted requests
-            # are _ACTIVE_SLOTS' business); costs a queue scan, so
-            # behind the exporter check.
-            for tier_name, depth in \
-                    self._queue.tier_depths().items():
-                _TIER_QUEUE_DEPTH.labels(tier=tier_name).set(depth)
-        # Re-set every tick, not only at construction/probe: the
-        # exporter typically enables AFTER warmup, and a gauge set
-        # while recording is disabled is a no-op. Unconditional so a
-        # single-chip engine reads the documented 1, not an unset 0.
-        _TP_SIZE.set(self._tp)
-        _DECODE_KERNEL.set(_DECODE_KERNEL_CODE[self.decode_kernel])
-        if self.decode_kernel != 'xla' and self._pool is not None:
-            # Per-step fused-bytes gauge, recomputed per tick from live
-            # pool occupancy (re-set here, not only at construction —
-            # exporters usually enable after warmup, the PR-5 lesson).
-            _DECODE_FUSED_BYTES.set(self.fused_bytes_per_step())
-        if self._tp > 1 and self._hlo_probe_cache is not None:
-            _TP_COLLECTIVES.set(self._hlo_probe_cache['total'])
-            _TP_ALLREDUCE_BYTES.set(
-                self._hlo_probe_cache['all_reduce_bytes'])
-        if self._pool is not None:
-            # Capacity re-set here (not only at __init__): the exporter
-            # usually enables AFTER engine construction, and a gauge set
-            # while recording is disabled is a no-op.
-            _PAGED_CAPACITY.set(self._pool.num_blocks)
-            _PAGED_USED.set(self._pool.used)
-            if self.paged_int8_bytes_saved:
-                _PAGED_INT8_SAVED.set(self.paged_int8_bytes_saved)
-            if self._per_dev_gauges:
-                # tp>1: per-device view of the pool. Bytes are static
-                # per engine (pool leaves / tp), computed once the
-                # cache exists; used-blocks match across devices while
-                # the block tables are replicated.
-                if self._pool_dev_bytes is None and \
-                        self._cache is not None:
-                    self._pool_dev_bytes = _tree_bytes(self._cache)[1]
-                for g_used, g_bytes in self._per_dev_gauges:
-                    g_used.set(self._pool.used)
-                    if self._pool_dev_bytes is not None:
-                        g_bytes.set(self._pool_dev_bytes)
+            self._refresh_gauges(slots, queue)
         ring = self._ring
         if ring and ring[0].gen != gen:
             # A recovery swapped engine state since those dispatches
@@ -4613,8 +4633,24 @@ class ContinuousBatchingEngine:
         whose tokens the host has not seen: the tick gives it only
         once it knows that the feed fits. The temps array caches under
         a value signature the same way. The result is appended to the
-        lookahead ring with its host copy started."""
+        lookahead ring with its host copy started. Three phases tile
+        it: the inputs, the jitted call alone, and `_queued`."""
         active_set = set(active)
+        with tracing.phase('engine.tick.dispatch.inputs'):
+            program, args = self._dispatch_inputs(slots, active,
+                                                  active_set, k, chain)
+        with tracing.phase('engine.tick.dispatch.launch'):
+            out_cols, feed_next, cache, *counts = program(*args)
+        if self._block:     # a pass returns the block state alone
+            feed_next, k = (feed_next, None), 1
+        with tracing.phase('engine.tick.dispatch.queue'):
+            return self._queued(slots, active, active_set, k, gen,
+                                out_cols, feed_next, cache, counts)
+
+    def _dispatch_inputs(self, slots, active, active_set, k, chain):
+        """The decode program a k-step dispatch of `active` calls, and
+        its arguments: blocks and tables, the feed, the signatures'
+        cached uploads and the step's keys."""
         tables = None
         if self.paged_block_size:
             # Cover every position this dispatch writes (k steps past
@@ -4650,11 +4686,9 @@ class ContinuousBatchingEngine:
                     'active slot\'s block')
             if chain is not None:
                 self.tick_stats['chained'] += 1
-            out_cols, state, cache, *counts = self._decode_block(
+            return self._decode_block, (
                 self.params, self._cache, self._feed[0], tables,
                 self._valid_for(active_set))
-            return self._queued(slots, active, active_set, 1, gen,
-                                out_cols, (state, None), cache, counts)
         tsig = tuple(slots[i].temperature if i in active_set else 0.0
                      for i in range(self.num_slots))
         if tsig != self._temps_sig:
@@ -4684,16 +4718,12 @@ class ContinuousBatchingEngine:
         valid = self._valid_for(active_set)
         self._rng, rng = jax.random.split(self._rng)
         if k == 1:
-            out_cols, feed_next, cache, *counts = self._decode(
+            return self._decode, (
                 self.params, self._cache, tok_dev, pos_dev, temps, rng,
                 tables, self._adapters, aids, valid)
-        else:
-            rngs = jax.random.split(rng, k)
-            out_cols, feed_next, cache, *counts = self._decode_multi(
-                self.params, self._cache, tok_dev, pos_dev, temps,
-                rngs, tables, self._adapters, aids, valid)
-        return self._queued(slots, active, active_set, k, gen, out_cols,
-                            feed_next, cache, counts)
+        return self._decode_multi, (
+            self.params, self._cache, tok_dev, pos_dev, temps,
+            jax.random.split(rng, k), tables, self._adapters, aids, valid)
 
     def _write_end(self, req: '_Request', k: int) -> int:
         """One past the last position the request's next dispatch of k

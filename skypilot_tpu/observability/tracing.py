@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import collections
 import contextvars
+import gc
 import json
 import os
 import sys
@@ -92,16 +93,26 @@ KNOWN_SPANS = (
     'engine.slot_preempt',   # batch slot yielded to an interactive arrival
     'engine.compile',        # one XLA backend compile while tracing is on
     # Tick phases of the engine's thread (`phase()`: profiler annotation
-    # + per-name totals, never a ring entry). The children tile _tick:
-    # what none covers is the tick's self time.
+    # + per-name totals, never a ring entry). The children tile _tick,
+    # and the grandchildren tile their parent: what none covers is the
+    # parent's self time.
     'engine.tick',            # one whole _tick
     'engine.tick.housekeep',  # engine work, ingest expiry, deadline scans
     'engine.tick.admit',      # admission loop (blocks, CoW, prefix lookup)
     'engine.tick.prefill',    # _prefill_tick: one chunk per prefilling slot
+    'engine.tick.prefill.inputs',  # a chunk's blocks, table and uploads
+    'engine.tick.prefill.launch',  # the chunk program's jitted call alone
+    'engine.tick.prefill.first',   # a prompt's last chunk: first token
     'engine.tick.dispatch',   # decode launch (_dispatch / _spec_tick)
+    'engine.tick.dispatch.inputs',  # blocks, tables, signatures, keys
+    'engine.tick.dispatch.launch',  # the decode program's jitted call
+    'engine.tick.dispatch.queue',   # _queued: commit, logs, the ring
     'engine.tick.land',       # host waiting for the device's answer
     'engine.tick.emit',       # tokens appended, callbacks, finishes
     'engine.tick.wait',       # idle engine sleeping on its wake event
+    # A pause of Python's garbage collector, on whichever thread
+    # triggered it (a `gc.callbacks` entry while tracing is on).
+    'process.gc',
 )
 
 # Tracing metrics (docs/observability.md).
@@ -120,17 +131,22 @@ _FLIGHT_RECORDS = _metrics.counter(
 
 def enable() -> None:
     """Turn span recording on (anchors the wall clock once so span
-    timestamps stay monotonic-derived afterwards)."""
+    timestamps stay monotonic-derived afterwards) and time the garbage
+    collector's pauses."""
     global _enabled, _anchor
     if _anchor is None:
         _anchor = (time.time(), time.monotonic())
     _bind_jax()
     _enabled = True
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def disable() -> None:
     global _enabled
     _enabled = False
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 def enabled() -> bool:
@@ -447,7 +463,9 @@ _annotation = None
 _jax_bound = False
 
 _phase_totals: Dict[str, list] = {}   # name -> [count, seconds]
-_phase_lock = threading.Lock()
+# Re-entrant: a collection, and with it the `process.gc` phase, may
+# start inside any allocation, one made under this lock included.
+_phase_lock = threading.RLock()
 
 
 def _bind_jax() -> None:
@@ -483,12 +501,14 @@ class _Phase:
     profiler session the span sits in the host plane on the clock the
     device's events are on, and a duration added to the per-name
     totals. Never a ring entry: seven phases at 25 ticks/s would roll
-    the ring over inside a minute and drop the request spans."""
+    the ring over inside a minute and drop the request spans. `also`
+    names a second total the duration is added to."""
 
-    __slots__ = ('name', '_start', '_ann')
+    __slots__ = ('name', '_also', '_start', '_ann')
 
-    def __init__(self, name: str) -> None:
+    def __init__(self, name: str, also: Optional[str] = None) -> None:
         self.name = name
+        self._also = also
 
     def __enter__(self) -> '_Phase':
         if not _jax_bound:
@@ -504,26 +524,49 @@ class _Phase:
         if self._ann is not None:
             self._ann.__exit__(*exc)
         with _phase_lock:
-            total = _phase_totals.setdefault(self.name, [0, 0.0])
-            total[0] += 1
-            total[1] += seconds
+            for name in (self.name, self._also):
+                if name is not None:
+                    total = _phase_totals.setdefault(name, [0, 0.0])
+                    total[0] += 1
+                    total[1] += seconds
 
 
-def phase(name: str):
+def phase(name: str, also: Optional[str] = None):
     """A lexical span for a stretch of the engine's tick (context
-    manager; names are the `engine.tick*` entries of KNOWN_SPANS).
-    Disabled tracing returns the shared no-op handle."""
+    manager; names are the `engine.tick*` entries of KNOWN_SPANS, and
+    `process.gc`). Disabled tracing returns the shared no-op handle."""
     if not _enabled:
         return NULL_SPAN
-    return _Phase(name)
+    return _Phase(name, also)
 
 
 def phase_totals() -> Dict[str, Dict[str, float]]:
     """{phase name: {'count', 'seconds'}} since the process started
-    (or `reset()`): where the engine's thread spent its time."""
+    (or `reset()`): where the engine's thread spent its time, and
+    `process.gc` / `process.gc.gen2` the collector's pauses."""
     with _phase_lock:
+        # a copy first: a collection may add a name mid-iteration
         return {name: {'count': c, 'seconds': s}
-                for name, (c, s) in _phase_totals.items()}
+                for name, (c, s) in dict(_phase_totals).items()}
+
+
+# The phase of the collection under way (collections never overlap).
+_gc_live: Any = None
+
+
+def _on_gc(stage: str, info: Dict[str, Any]) -> None:
+    """`gc.callbacks` entry while tracing is on: a collection is a
+    `process.gc` phase on the thread whose allocation triggered it, so
+    its pause lands on the profiler's clock beside the tick phases; a
+    generation-2 collection is also totalled as `process.gc.gen2`."""
+    global _gc_live
+    if stage == 'start':
+        _gc_live = phase('process.gc', 'process.gc.gen2'
+                         if info.get('generation') == 2 else None)
+        _gc_live.__enter__()
+    elif _gc_live is not None:
+        live, _gc_live = _gc_live, None
+        live.__exit__(None, None, None)
 
 
 # ---------------------------------------------------------------------

@@ -574,6 +574,59 @@ class TestJoiningTickUploads:
         assert len(joins) == 8, joins
 
 
+class TestUploadCount:
+    """`paged_occupancy()['host_uploads']`, the engine's count of its
+    upload funnel: none in a steady decode step, a chunk's own four
+    inputs (tokens, block table, start, length) for each prefill
+    chunk of a plain model."""
+
+    @pytest.fixture(scope='class')
+    def engine(self):
+        from skypilot_tpu.models.inference import ContinuousBatchingEngine
+        # blocks of 32: neither a prompt below nor the decode window
+        # watched grows a table
+        engine = ContinuousBatchingEngine(
+            _cfg(), num_slots=2, paged_block_size=32, prefill_chunk=8)
+        engine.generate(PROMPT, max_new_tokens=2)   # compile
+        yield engine
+        engine.stop()
+
+    @staticmethod
+    def _uploads(engine) -> int:
+        return engine.paged_occupancy()['host_uploads']
+
+    def test_steady_decode_steps_upload_nothing(self, engine):
+        start = engine._decode_steps  # pylint: disable=protected-access
+        # a slow client keeps each step on the engine's thread >= 10 ms
+        fut = engine.submit(PROMPT, max_new_tokens=20,
+                            on_token=lambda _t: time.sleep(0.01))
+        deadline = time.time() + 60
+
+        def wait_for(steps):
+            while engine._decode_steps < start + steps and \
+                    time.time() < deadline:  # pylint: disable=protected-access
+                time.sleep(0.001)
+
+        wait_for(3)
+        before = self._uploads(engine)
+        wait_for(12)
+        after = self._uploads(engine)
+        fut.result(timeout=120)
+        assert engine._decode_steps >= start + 12  # pylint: disable=protected-access
+        assert after == before
+
+    def test_a_chunk_counts_its_own_four_uploads(self, engine):
+        """Two more chunks of a longer prompt, the rest alike: the same
+        first token, table, temperature and decode steps."""
+        counts = []
+        for prompt in (PROMPT, list(range(40, 64))):    # 1 and 3 chunks
+            before = self._uploads(engine)
+            engine.generate(prompt, max_new_tokens=2, timeout=120)
+            counts.append(self._uploads(engine) - before)
+        assert counts[1] - counts[0] == 2 * 4, counts
+        assert engine.paged_occupancy()['host_uploads'] >= sum(counts)
+
+
 @pytest.mark.chaos
 class TestAsyncWedgeRecovery:
 

@@ -182,18 +182,25 @@ class TestDisabledOverhead:
     def test_disabled_generation_reads_no_tracer_clock(
             self, paged_engine, monkeypatch):
         """THE pin: with tracing disabled, a full generation —
-        admission, chunked prefill, decode ticks, finish — never calls
-        the tracer's clock or record funnel and allocates no span
-        state. Every engine hook must guard BEFORE touching either."""
+        admission, chunked prefill, decode ticks, finish, and with them
+        every tick phase and sub-phase — never calls the tracer's clock
+        or record funnel, allocates no span state and totals nothing;
+        and no collection calls into the tracer. Every engine hook must
+        guard BEFORE touching either."""
+        import gc
         assert not tracing.enabled()
+        assert tracing._on_gc not in gc.callbacks
         monkeypatch.setattr(tracing, '_now', _poisoned)
         monkeypatch.setattr(tracing, '_record', _poisoned)
         out, stats = paged_engine.generate([9, 10, 11, 12],
                                            max_new_tokens=4,
                                            timeout=300)
+        gc.collect()
         assert len(out) == 4
         assert stats['ttft_s'] >= 0
         assert tracing.snapshot() == []
+        assert tracing.phase_totals() == {}
+        assert tracing._on_gc not in gc.callbacks
 
     def test_disabled_span_is_the_shared_noop_singleton(self):
         assert tracing.span('lb.request') is tracing.NULL_SPAN
@@ -284,15 +291,15 @@ class TestEngineSpans:
 
 PHASE_CHILDREN = ('housekeep', 'admit', 'prefill', 'dispatch', 'land',
                   'emit', 'wait')
+# the grandchildren: each tiles its parent, what none covers is the
+# parent's self time
+SUB_PHASES = ('prefill.inputs', 'prefill.launch', 'prefill.first',
+              'dispatch.inputs', 'dispatch.launch', 'dispatch.queue')
 
 
-@pytest.fixture(scope='class')
-def phase_run(paged_engine):
-    """One generation on the warmed paged engine with `TraceAnnotation`
-    replaced by a recorder, then the engine left to idle until it has
-    slept once. Yields (events, totals, ring): events are (name,
-    start_ns, end_ns, thread) in order of exit."""
-    events = []
+def _recorder(events: list):
+    """A stand-in for `TraceAnnotation` whose every exit appends (name,
+    start_ns, end_ns, thread) to `events`."""
 
     class Recorder:
         def __init__(self, name):
@@ -306,11 +313,21 @@ def phase_run(paged_engine):
             events.append((self.name, self.start, time.perf_counter_ns(),
                            threading.get_ident()))
 
+    return Recorder
+
+
+@pytest.fixture(scope='class')
+def phase_run(paged_engine):
+    """One generation on the warmed paged engine with `TraceAnnotation`
+    replaced by a recorder, then the engine left to idle until it has
+    slept once. Yields (events, totals, ring): events are (name,
+    start_ns, end_ns, thread) in order of exit."""
+    events = []
     # enable() first: it binds jax's own TraceAnnotation, so that what
     # this fixture restores is the real one and not "unbound".
     tracing.enable()
     tracing.reset()
-    real, tracing._annotation = tracing._annotation, Recorder
+    real, tracing._annotation = tracing._annotation, _recorder(events)
     try:
         out, _ = paged_engine.generate(list(range(1, 21)),
                                        max_new_tokens=4, timeout=300)
@@ -326,15 +343,17 @@ def phase_run(paged_engine):
     yield list(events), totals, ring
 
 
-def _whole_ticks(events):
+def _whole_ticks(events, depth=1):
     """(ticks, children): the recorded `engine.tick` spans, and the
-    child spans that lie between the first one's start and the last
-    one's end (a tick under way when recording began or ended has
-    children and no recorded parent)."""
+    spans `depth` levels below them (the depth-1 `engine.tick.<x>`
+    children, or the depth-2 sub-phases) that lie between the first
+    one's start and the last one's end (a tick under way when recording
+    began or ended has children and no recorded parent)."""
     ticks = [e for e in events if e[0] == 'engine.tick']
     assert ticks
     first, last = min(t[1] for t in ticks), max(t[2] for t in ticks)
     children = [e for e in events if e[0].startswith('engine.tick.')
+                and e[0].count('.') == depth + 1
                 and e[1] >= first and e[2] <= last]
     return ticks, children
 
@@ -381,17 +400,41 @@ class TestTickPhases:
 
     def test_totals_hold_every_phase_and_the_ring_none(self, phase_run):
         _, totals, ring = phase_run
-        for name in ('engine.tick',) + tuple(
-                f'engine.tick.{c}' for c in PHASE_CHILDREN):
+        children = tuple(f'engine.tick.{c}' for c in PHASE_CHILDREN)
+        for name in ('engine.tick',) + children:
             assert name in tracing.KNOWN_SPANS
             assert totals[name]['count'] >= 1
             assert totals[name]['seconds'] >= 0
-        # each child's seconds lie inside the ticks'
-        assert sum(v['seconds'] for k, v in totals.items()
-                   if k != 'engine.tick') <= \
+        # each depth-1 child's seconds lie inside the ticks'
+        assert sum(totals[c]['seconds'] for c in children) <= \
             totals['engine.tick']['seconds'] + 0.1
         assert not [s for s in ring
                     if s['name'].startswith('engine.tick')]
+
+    @pytest.mark.parametrize('sub', SUB_PHASES)
+    def test_sub_phase_sits_inside_its_parent_beside_its_siblings(
+            self, phase_run, sub):
+        """A sub-phase lies inside an event of its parent on the same
+        thread, and the sub-phases of one parent never overlap: they
+        tile it, what none covers being the parent's self time."""
+        events, totals, _ = phase_run
+        name = f'engine.tick.{sub}'
+        parent = name.rsplit('.', 1)[0]
+        assert name in tracing.KNOWN_SPANS
+        ticks, subs = _whole_ticks(events, depth=2)
+        mine = [e for e in subs if e[0] == name]
+        assert mine, f'no {name} recorded'
+        parents = [e for e in events if e[0] == parent]
+        for _, start, end, thread in mine:
+            assert any(p[1] <= start and end <= p[2] and p[3] == thread
+                       for p in parents)
+        assert {e[3] for e in mine} == {t[3] for t in ticks}
+        siblings = sorted((e[1], e[2], e[0]) for e in subs
+                          if e[0].startswith(parent + '.'))
+        for (_, end, a), (start, _, b) in zip(siblings, siblings[1:]):
+            assert end <= start, f'{a} overlaps {b}'
+        assert totals[name]['count'] >= len(mine)
+        assert totals[name]['seconds'] <= totals[parent]['seconds']
 
     def test_disabled_phase_is_the_noop_and_totals_stay_empty(
             self, paged_engine):
@@ -460,6 +503,40 @@ class TestTickPhases:
             cwd=os.path.dirname(os.path.dirname(__file__)))
         assert out.returncode == 0, out.stderr
         assert 'CLEAN' in out.stdout
+
+
+class TestCollectorPauses:
+
+    def test_the_gc_callback_is_there_exactly_while_tracing_is_on(self):
+        import gc
+        assert tracing._on_gc not in gc.callbacks
+        tracing.enable()
+        tracing.enable()        # twice: still one entry
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        tracing.disable()
+        assert tracing._on_gc not in gc.callbacks
+
+    def test_a_forced_collection_is_one_gc_phase_and_a_total(self):
+        """`gc.collect()` is one generation-2 collection on this thread:
+        one `process.gc` annotation there, and both totals."""
+        import gc
+        events = []
+        tracing.enable()
+        tracing.reset()
+        real, tracing._annotation = tracing._annotation, _recorder(events)
+        try:
+            gc.collect()
+        finally:
+            tracing._annotation = real
+            tracing.disable()
+        here = [e for e in events if e[3] == threading.get_ident()]
+        assert [e[0] for e in here] == ['process.gc']
+        assert here[0][2] >= here[0][1]
+        totals = tracing.phase_totals()
+        assert 'process.gc' in tracing.KNOWN_SPANS
+        for name in ('process.gc', 'process.gc.gen2'):
+            assert totals[name]['count'] >= 1
+            assert totals[name]['seconds'] > 0
 
 
 class TestCompileSpans:
