@@ -275,7 +275,9 @@ class ModelConfig:
     # with the paged pool, tp=1 and no softcap): the ops/paged_attention
     # kernel in the programs with few query rows a slot (decode step,
     # block pass, verify span) — each slot reads the blocks it holds,
-    # several a tile. 'pallas': the same, asked for by name, and
+    # several a tile — and, for a model with a scan state, the
+    # ops/ssd_state_step kernel in the all-slots decode step (models/
+    # ssm.takes_state_kernel). 'pallas': the same, asked for by name, and
     # multi-LoRA engines also route the adapter gather+dot through
     # ops/fused_lora. 'pallas_interpret': 'pallas' under the Pallas
     # interpreter (CPU tier-1 pinning).

@@ -30,6 +30,7 @@ from flax import linen as nn
 from skypilot_tpu import exceptions
 from skypilot_tpu.models import kv_cache as kv_cache_lib
 from skypilot_tpu.models import moe as moe_lib
+from skypilot_tpu.models import ssm as ssm_lib
 from skypilot_tpu.models.configs import ModelConfig, get_config
 from skypilot_tpu.models.transformer import Transformer
 from skypilot_tpu.observability import metrics as obs
@@ -243,8 +244,9 @@ _DECODE_FUSED_BYTES = obs.gauge(
 # The decode_kernel values a caller may name.
 _NAMED_KERNELS = ('xla', 'pallas', 'pallas_interpret')
 # What decode_kernel=None resolves to where the engine can run the
-# kernel: the Pallas paged attention in the decode programs and nothing
-# else. The multi-LoRA delta fuses under 'pallas' by name only
+# kernel: the decode programs' Pallas kernels (the paged attention; a
+# scan state's one-step recurrence) and nothing else. The multi-LoRA
+# delta fuses under 'pallas' by name only
 # (transformer.MultiLoRADenseGeneral), so it keeps its gather here. It
 # reads 1 on the gauge; it cannot be asked for by name.
 _DEFAULT_KERNEL = 'pallas_attention'
@@ -1358,7 +1360,9 @@ class ContinuousBatchingEngine:
         # a recurrent-state model ran over (pads included) and those
         # that advanced a state; state_bytes / kv_pool_bytes: device
         # bytes of the recurrent leaves and of the block pool (filled
-        # in by the first paged_occupancy()).
+        # in by the first paged_occupancy()); state_kernel_steps: decode
+        # dispatches whose program ran the scan state's recurrence
+        # through ops/ssd_state_step (one integer add a dispatch).
         self.paged_stats = {'cow_copies': 0, 'blocks_reused': 0,
                             'prefill_chunks': 0, 'prefill_tokens': 0,
                             'prefix_evictions': 0,
@@ -1366,7 +1370,8 @@ class ContinuousBatchingEngine:
                             'scan_positions': 0, 'scan_tokens': 0,
                             'state_bytes': 0, 'kv_pool_bytes': 0,
                             'decode_blocks_read': 0,
-                            'decode_window_blocks': 0}
+                            'decode_window_blocks': 0,
+                            'state_kernel_steps': 0}
         # -------- paged decode kernel (docs/performance.md) --------
         # The decode programs' paged attention runs through
         # ops/paged_attention where the engine observes that it can (a
@@ -1536,6 +1541,11 @@ class ContinuousBatchingEngine:
                             'block_masked_positions': 0}
         self._row_valid = (self._recurrent or self._routed
                            or bool(self._block))
+        # Whether the decode programs run the scan state's recurrence
+        # through its kernel (ops/ssd_state_step; `state_kernel_steps`
+        # counts their dispatches).
+        self._state_kernel = ssm_lib.takes_state_kernel(
+            self.cfg, self._block or 1)
         # Decode-tick valid-row cache (recurrent-state models only; see
         # _valid_for): 1 for a decoding slot, 0 for an inert one.
         self._valid_sig: Optional[tuple] = None
@@ -4773,6 +4783,8 @@ class ContinuousBatchingEngine:
             self._feed_key(slots[i]) if i in active_set else None
             for i in range(self.num_slots)))
         self.tick_stats['dispatches'] += 1
+        if self._state_kernel:
+            self.paged_stats['state_kernel_steps'] += 1
         out_cols.copy_to_host_async()
         # Every program queued so far ends before this step does: its
         # counts land with this step's tokens.

@@ -33,7 +33,13 @@ T > 1 walks the chunk in blocks of `ssm_chunk` positions by the chunked
 form (a block's outputs from one masked (Q, Q) product and the state it
 started from; its end state from one more product), never materialising
 a state a position. T == 1 is the one-step recurrence, elementwise in
-float32. Where the float32 state enters a product (`S @ C`) the product
+float32. The all-slots decode step under the decode kernels (any
+`cfg.decode_kernel` but 'xla': the engine resolves it by the backend and
+the cache's layout) runs it through one Pallas kernel,
+ops/ssd_state_step, which reads each row's state once and writes the new
+state into the leaf where it lies, where XLA reads it twice (for the
+output, then for the kept rows and the write). Every other call keeps
+`ssd_step`. Where the float32 state enters a product (`S @ C`) the product
 runs at `highest` precision: at the default a TPU would round the state
 to bfloat16 on the way in, and its extra bits would be held for nothing.
 """
@@ -96,6 +102,36 @@ def ssd_step(state, xs, b, c, delta, a_log, d_skip):
            * b[:, :, None, None, :])
     y = jnp.sum(new * c[:, :, None, None, :], axis=-1)
     return y + d_skip[..., None] * xs, new
+
+
+def takes_state_kernel(cfg: ModelConfig, query_positions: int) -> bool:
+    """True when a decode program of this many query positions a slot
+    runs its recurrence through ops/ssd_state_step: a one-step program
+    of a model with a scan state, under the decode kernels (any
+    `decode_kernel` but 'xla': the engine's default on a TPU with the
+    paged pool, or one asked for by name). Static at trace time."""
+    return (cfg.has_recurrent_state and query_positions == 1
+            and cfg.decode_kernel != 'xla')
+
+
+def ssd_step_in_place(leaf, layer, touched, fresh, xs, b, c, delta, a_log,
+                      d_skip, interpret: bool):
+    """`ssd_step` over every slot of the scan-state leaf, by the kernel
+    that reads and writes each state tile once (ops/ssd_state_step),
+    the new state written into the leaf at `layer`: a row not `touched`
+    keeps its state, a `fresh` one starts from zero. xs: (B, G, R, P);
+    b, c: (B, G, N); delta: (B, G, R), already masked. Returns (y (B, G,
+    R, P) float32, the leaf)."""
+    # Late import: only a decode program under the kernels loads it.
+    from skypilot_tpu.ops.ssd_state_step import ssd_state_step
+    batch, g, r, p_dim = xs.shape
+    xs = xs.astype(F32)
+    decay = jnp.exp(-jnp.exp(a_log) * delta)                 # (B, G, R)
+    dx = delta[..., None] * xs
+    y, leaf = ssd_state_step(
+        leaf, layer, touched, fresh, decay.reshape(batch, g * r),
+        dx.reshape(batch, g * r, p_dim), b, c, interpret=interpret)
+    return y.reshape(xs.shape) + d_skip[..., None] * xs, leaf
 
 
 def ssd_chunked(state, xs, b, c, delta, a_log, d_skip, block: int,
@@ -178,6 +214,10 @@ class Mamba2Mixer(nn.Module):
         keep = cfg.ssm_conv - 1
         slots, valid = state_rows if state_rows is not None else (None,
                                                                   None)
+        # the all-slots decode step under the decode kernels: the state
+        # is read and written by one kernel, in place
+        kernel = (cfg.decode and slots is None
+                  and takes_state_kernel(cfg, t))
 
         proj = dense_general(cfg, cfg.ssm_proj_width, ('embed', 'mlp'),
                              'in_proj', use_bias=cfg.ssm_proj_bias)(
@@ -230,11 +270,13 @@ class Mamba2Mixer(nn.Module):
             # (the stacked one under the cache-carrying layer loop).
             from skypilot_tpu.models import cache_carry
             layer = cache_carry.current_layer()
-            s_old = cache_carry.read_rows(state_leaf, slots, layer)
+            if not kernel:
+                s_old = cache_carry.read_rows(state_leaf, slots, layer)
             c_old = cache_carry.read_rows(conv_leaf, slots, layer)
             fresh = positions[:, 0] == 0
-            s0 = jnp.where(fresh[:, None, None, None], 0,
-                           s_old).astype(F32)
+            if not kernel:
+                s0 = jnp.where(fresh[:, None, None, None], 0,
+                               s_old).astype(F32)
             c0 = jnp.where(fresh[:, None, None], 0, c_old)
         else:
             s0 = jnp.zeros((batch, heads, p_dim, n), F32)
@@ -260,9 +302,16 @@ class Mamba2Mixer(nn.Module):
         delta = jax.nn.softplus(dt + dt_bias)
         delta = jnp.where(real[..., None], delta, 0.0).reshape(
             batch, t, g, r)
-        s0 = s0.reshape(batch, g, r, p_dim, n)
+        if not kernel:
+            s0 = s0.reshape(batch, g, r, p_dim, n)
         a_gr, d_gr = a_log.reshape(g, r), d_skip.reshape(g, r)
-        if t == 1:
+        if kernel:
+            y, state_leaf = ssd_step_in_place(
+                state_leaf, layer, valid > 0, fresh, xs[:, 0], b_in[:, 0],
+                c_in[:, 0], delta[:, 0], a_gr, d_gr,
+                interpret=cfg.decode_kernel == 'pallas_interpret')
+            y = y[:, None]
+        elif t == 1:
             y, s1 = ssd_step(s0, xs[:, 0], b_in[:, 0], c_in[:, 0],
                              delta[:, 0], a_gr, d_gr)
             y = y[:, None]
@@ -273,12 +322,14 @@ class Mamba2Mixer(nn.Module):
 
         if cfg.decode:
             touched = valid > 0
-            s1 = jnp.where(touched[:, None, None, None],
-                           s1.reshape(batch, heads, p_dim, n).astype(
-                               state_leaf.dtype), s_old)
+            if not kernel:
+                s1 = jnp.where(touched[:, None, None, None],
+                               s1.reshape(batch, heads, p_dim, n).astype(
+                                   state_leaf.dtype), s_old)
             c1 = jnp.where(touched[:, None, None], c1, c_old)
-            _rebox(state_var, state_box, cache_carry.write_rows(
-                state_leaf, slots, layer, s1))
+            _rebox(state_var, state_box, state_leaf if kernel
+                   else cache_carry.write_rows(state_leaf, slots, layer,
+                                               s1))
             _rebox(conv_var, conv_box, cache_carry.write_rows(
                 conv_leaf, slots, layer, c1))
 

@@ -61,8 +61,11 @@ BLOCKS = 257     # a pool far larger than anything else a tiny step holds
 HEADS = dict(num_heads=8, num_kv_heads=8, head_dim_override=128)
 F32 = dict(dtype='float32', param_dtype='float32')
 
+# 'falcon-h1-kernel': the decode step's recurrence through its kernel
+# (ops/ssd_state_step), which writes the scan state where it lies; the
+# interpreter's on the CPU, the Mosaic kernel on a described v5e
 LAYOUTS = ('paged', 'paged-int8', 'contiguous', 'contiguous-int8',
-           'falcon-h1')
+           'falcon-h1', 'falcon-h1-kernel')
 
 
 def _layers(layout: str) -> int:
@@ -73,7 +76,7 @@ def _layers(layout: str) -> int:
 
 
 def _cfg(layout: str, **kw):
-    if layout == 'falcon-h1':
+    if layout.startswith('falcon-h1'):
         return get_config(
             'falcon-h1-34b', num_layers=_layers(layout), d_model=64, d_mlp=128,
             vocab_size=512, max_seq_len=128, ssm_heads=4, ssm_head_dim=8,
@@ -96,6 +99,8 @@ def _engine_kw(layout: str) -> dict:
         kw.update(paged_block_size=16, paged_num_blocks=BLOCKS)
     if layout.endswith('int8'):
         kw['kv_quant'] = 'int8'
+    if layout.endswith('kernel'):
+        kw['decode_kernel'] = 'pallas_interpret'
     return kw
 
 
@@ -253,6 +258,11 @@ def _compile_programs(engine, one):
 def _check_in_place(layout: str, one) -> None:
     # on the CPU float32 stands in for bfloat16 (the module's docstring)
     engine = _engine(layout, **(F32 if one is None else {}))
+    if one is not None and engine.decode_kernel == 'pallas_interpret':
+        # compiled for the chip: its kernels, not the interpreter's (the
+        # process sees the CPU, where the engine refuses 'pallas')
+        engine.cfg = dataclasses.replace(engine.cfg, decode_kernel='pallas')
+        engine.model = Transformer(engine.cfg)
     try:
         compiled, cache = _compile_programs(engine, one)
     finally:
@@ -282,6 +292,11 @@ def _check_in_place(layout: str, one) -> None:
     for name, prog in compiled.items():
         text = prog.as_text()
         assert _new_buffers(text, strict) == [], name
+        if one is not None and layout.endswith('kernel'):
+            # the decode step's recurrence is the kernel, the chunk's not
+            state_kernel = re.search(
+                r'%ssd_state_step(\.\d+)? = .*custom-call\(', text)
+            assert bool(state_kernel) == (name == 'decode'), name
         if one is not None:
             made = _new_buffers(text, whole)
             assert [m for m in made if m[0]] == [], (name, made)
@@ -400,6 +415,30 @@ def test_compiled_for_a_v5e_the_paged_decode_kernel(v5e, pool, kv_heads,
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     # no gathered window: scratch is the queries' and the scale rows'
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+def test_compiled_for_a_v5e_the_state_kernel_at_published_widths(v5e):
+    """Falcon-H1-34B's recurrence over 64 slots, one Mosaic call on the
+    stacked scan state of six layers (a traced layer): the leaf is
+    written where it lies, never copied, and the scratch is the small
+    per-head inputs and `y`."""
+    from skypilot_tpu.ops import ssd_state_step
+    arr = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=v5e)
+    layers, slots, heads, p_dim, n, groups = 6, 64, 32, 128, 256, 2
+    leaf = (layers, slots, heads, p_dim, n)
+    compiled = jax.jit(ssd_state_step.ssd_state_step,
+                       donate_argnums=(0,)).trace(
+        arr(jnp.float32, *leaf), arr(jnp.int32), arr(jnp.bool_, slots),
+        arr(jnp.bool_, slots), arr(jnp.float32, slots, heads),
+        arr(jnp.float32, slots, heads, p_dim),
+        arr(jnp.float32, slots, groups, n),
+        arr(jnp.float32, slots, groups, n)).lower(
+            lowering_platforms=('tpu',)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert _new_buffers(text, {('f32', leaf), ('f32', leaf[1:])}) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
@@ -580,7 +619,7 @@ def test_logits_and_cache_are_the_unrolled_models(layout):
         _unrolled(jax.tree.map(jnp.zeros_like, cache), layers), paged,
         recurrent)
     got_cache = _unrolled(got_cache, layers)
-    if layout != 'falcon-h1':
+    if not layout.startswith('falcon-h1'):
         _same_bits(got, want, got_cache, want_cache)
         return
     for a, b in zip(got, want):
@@ -787,6 +826,8 @@ TREES = {
                               ('layers', 'batch', None, None, None)),
     },
 }
+
+TREES['falcon-h1-kernel'] = TREES['falcon-h1']
 
 
 @pytest.mark.parametrize('layout', LAYOUTS)

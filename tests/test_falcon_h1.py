@@ -252,12 +252,24 @@ def _prefill_then_decode(cfg, params, prompt, steps: int):
     return np.stack(rows), toks
 
 
-@pytest.mark.parametrize('length', [3, 100, 127, 128, 129, 255, 256, 257,
-                                    300])
-def test_chunked_prefill_and_one_step_decode_match_the_scan(model, length):
+LENGTHS = (3, 100, 127, 128, 129, 255, 256, 257, 300)
+# The one-step recurrence by XLA (`ssd_step`) and by the kernel that
+# updates the state in place (ops/ssd_state_step, under the interpreter);
+# the XLA cases keep the ids they had before the kernel.
+KERNELS = ('xla', 'pallas_interpret')
+
+
+@pytest.mark.parametrize(
+    'length, decode_kernel',
+    [(n, k) for k in KERNELS for n in LENGTHS],
+    ids=[str(n) if k == 'xla' else f'{n}-kernel'
+         for k in KERNELS for n in LENGTHS])
+def test_chunked_prefill_and_one_step_decode_match_the_scan(model, length,
+                                                           decode_kernel):
     """Prompt lengths on both sides of a 128-position block and of the
     256-token chunk, and one shorter than the convolution's taps."""
     cfg, params, oracle = model
+    cfg = dataclasses.replace(cfg, decode_kernel=decode_kernel)
     prompt = prompt_of(length)
     got, toks = _prefill_then_decode(cfg, params, prompt, 4)
     want = oracle.logits(prompt + toks)[length - 1:]
@@ -286,10 +298,13 @@ def test_the_mixer_switches_follow_the_reference(switch):
         assert float(jnp.abs(other[0] - got).max()) > 1e-3
 
 
-def test_pads_and_inert_rows_leave_the_state_bit_for_bit(model):
+@pytest.mark.parametrize('decode_kernel', KERNELS)
+def test_pads_and_inert_rows_leave_the_state_bit_for_bit(model,
+                                                        decode_kernel):
     """Right pads advance neither state whatever they hold, and a decode
     row whose count is 0 keeps both states bit for bit."""
     cfg, params, _ = model
+    cfg = dataclasses.replace(cfg, decode_kernel=decode_kernel)
     net, cache = _decode_net(cfg, 3)
     rng = np.random.default_rng(0)
     cache = jax.tree.map(
@@ -349,15 +364,23 @@ def _tap_first_logits(engine, into: list):
     return real
 
 
-@pytest.mark.parametrize('paged', [True, False],
-                         ids=['paged', 'contiguous'])
-def test_the_batching_engine_serves_what_the_reference_would(model, paged):
+# The decode kernels need the paged pool; the contiguous layout keeps XLA.
+ENGINE_CASES = dict(argnames='paged, decode_kernel',
+                    argvalues=[(True, 'xla'), (False, 'xla'),
+                               (True, 'pallas_interpret')],
+                    ids=['paged', 'contiguous', 'paged-kernel'])
+
+
+@pytest.mark.parametrize(**ENGINE_CASES)
+def test_the_batching_engine_serves_what_the_reference_would(model, paged,
+                                                            decode_kernel):
     """Prefill (one chunk, two chunks, a chunk's edge) then decode
     through the per-slot state, more requests than slots so that slots
     are reused: every served token is the reference's best to 2e-4, and
     the first token's logits agree."""
     cfg, params, oracle = model
-    engine = _engine(cfg, params, paged, slots=3)
+    engine = _engine(cfg, params, paged, slots=3,
+                     decode_kernel=decode_kernel)
     first_logits = []
     _tap_first_logits(engine, first_logits)
     try:
@@ -454,9 +477,9 @@ def test_a_request_is_bit_identical_alone_and_in_a_full_batch(model,
     _same(alone, crowd)
 
 
-@pytest.mark.parametrize('paged', [True, False],
-                         ids=['paged', 'contiguous'])
-def test_the_ring_rides_through_churn_with_the_state_exact(model, paged):
+@pytest.mark.parametrize(**ENGINE_CASES)
+def test_the_ring_rides_through_churn_with_the_state_exact(model, paged,
+                                                          decode_kernel):
     """Depth 0 against the default. Slot 0's request finishes by length
     while its neighbours decode on, so with the ring up its row rides
     inert through steps queued before its last token was read: its
@@ -468,7 +491,8 @@ def test_the_ring_rides_through_churn_with_the_state_exact(model, paged):
     crowd = [prompt_of(n, 3) for n in (33, 70, 300, 5, 130, 18)]
     runs, tokens = [], []
     for depth in (0, 1):
-        engine = _engine(cfg, params, paged, slots=3, async_depth=depth)
+        engine = _engine(cfg, params, paged, slots=3, async_depth=depth,
+                         decode_kernel=decode_kernel)
         try:
             runs.append(_serve(engine, prompt, crowd[:2], other_new=20,
                                first_new=5))
@@ -687,6 +711,32 @@ def test_other_models_carry_no_state_and_count_none():
     assert occ['state_bytes'] == 0 and occ['scan_positions'] == 0
     assert occ['kv_pool_bytes'] > 0 and occ['state_slots_used'] == 0
     assert not any('state' in str(path) for path, _ in leaves)
+
+
+def test_the_state_kernel_counts_the_decode_dispatches_that_ran_it(model):
+    """`state_kernel_steps` is every decode dispatch of a recurrent model
+    under the decode kernels (one-token steps and a `decode_chunk` scan
+    alike), none under XLA, and none for a model without state."""
+    cfg, params, _ = model
+
+    def counts(engine):
+        try:
+            engine.generate(prompt_of(20), max_new_tokens=6)
+            return engine.paged_occupancy()
+        finally:
+            engine.stop()
+
+    for kw in ({}, {'decode_chunk': 4}):
+        occ = counts(_engine(cfg, params, True, slots=2,
+                             decode_kernel='pallas_interpret', **kw))
+        assert occ['decode_dispatches'] > 0
+        assert occ['state_kernel_steps'] == occ['decode_dispatches'], kw
+    occ = counts(_engine(cfg, params, True, slots=2, decode_kernel='xla'))
+    assert occ['decode_dispatches'] > 0 and occ['state_kernel_steps'] == 0
+    occ = counts(ContinuousBatchingEngine(
+        'test-tiny', num_slots=2, paged_block_size=16,
+        decode_kernel='pallas_interpret'))
+    assert occ['decode_dispatches'] > 0 and occ['state_kernel_steps'] == 0
 
 
 # ---- the checkpoint's names ---------------------------------------------------
