@@ -29,10 +29,11 @@ no copy after the loop (pinned on the compiled programs by
 tests/test_cache_in_place.py).
 
 A model whose layers differ by position (`cfg.has_layer_pattern`:
-leading dense layers before expert layers, an attention kind a layer;
-models/layer_pattern.py) takes the same loop once a GROUP of layers of
-one shape: 'dense_layers', then 'layers'. Beside the weights and the
-index each loop scans the layers' kinds, `(window, rope)` a layer, which
+leading dense layers before expert layers, an attention kind a layer,
+a mixer kind a layer; models/layer_pattern.py) takes the same loop once
+a GROUP of layers of one shape (`layer_groups`): 'dense_layers', then
+'layers', then a run more at each change of mixer or clamps. Beside the
+weights and the index each loop scans the layers' kinds, `(window, rope)` a layer, which
 a layer reads through `current_kind()`: the pattern is data of the one
 program, not a program a kind. Such a model comes here for every apply,
 init and whole sequences too (`carried` false: the cache, if the apply
@@ -55,6 +56,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import dataclasses
 from typing import Optional, Tuple
 
 import jax
@@ -185,9 +187,11 @@ class CarriedPatternLayer(nn.Module):
     """`layer_pattern.PatternLayer` under nn.scan's signature: beside
     its index the loop hands a layer its kind. `carried` false: the
     cache is scanned with the weights (or there is none), so a leaf is
-    the layer's own and the index says nothing."""
+    the layer's own and the index says nothing. `group` is the run's
+    `LayerGroup`; its expert layers read the stacks at `expert_offset`
+    plus their index."""
     cfg: ModelConfig
-    dense: bool
+    group: 'LayerGroup'
     carried: bool
 
     @nn.compact
@@ -196,27 +200,59 @@ class CarriedPatternLayer(nn.Module):
         from skypilot_tpu.models.layer_pattern import PatternLayer
         layer, window, rope = xs
         x, positions, block_tables, adapter_ids, state_rows = carry
+        g = self.group
+        expert_layer = layer + g.expert_offset if g.expert_offset else layer
         with _at_layer(layer if self.carried else None, (window, rope)):
-            x = PatternLayer(self.cfg, self.dense, name='layer')(
+            x = PatternLayer(self.cfg, g.dense, g.mixer, g.limits,
+                             name='layer')(
                 x, positions, block_tables, adapter_ids, state_rows,
-                None if stacks is None else (stacks, layer))
+                None if stacks is None else (stacks, expert_layer))
         return (x, positions, block_tables, adapter_ids, state_rows), None
 
 
-def layer_groups(cfg: ModelConfig) -> Tuple[Tuple[str, bool, int, int], ...]:
-    """The groups of a layer pattern, each (name, dense, first layer,
-    one past its last): the leading dense layers of an expert model,
-    then its expert layers; a model without experts is one dense group.
-    The last group is always 'layers'."""
-    if not cfg.is_moe:
-        return (('layers', True, 0, cfg.num_layers),)
-    n = cfg.num_dense_layers
-    if not 0 <= n < cfg.num_layers:
+@dataclasses.dataclass(frozen=True)
+class LayerGroup:
+    """A run of layers of one kind, stacked and looped over as one:
+    `name` its scope in the parameter and cache trees, `dense` its MLP
+    (dense, or the expert block), `mixer` its mixer ('attention', 'kda'
+    or 'mla'), `limits` its (routed, shared) SwiGLU clamps, layers
+    [start, stop), and `expert_offset` the expert layers before it,
+    where it reads the expert stacks."""
+    name: str
+    dense: bool
+    mixer: str
+    limits: Tuple[float, float]
+    start: int
+    stop: int
+    expert_offset: int
+
+
+def layer_groups(cfg: ModelConfig) -> Tuple[LayerGroup, ...]:
+    """The groups of a layer pattern: maximal runs of layers with one
+    (dense or expert, mixer, clamps) kind, so that a group's layers
+    share one shape and each carries only its own cache leaves. The
+    leading dense layers of an expert model come first; a model without
+    experts is dense throughout. The first dense run of an expert model
+    is 'dense_layers', the first other run 'layers'; a later run is
+    named by its first layer ('layers_6')."""
+    n = cfg.num_dense_layers if cfg.is_moe else cfg.num_layers
+    if cfg.is_moe and not 0 <= n < cfg.num_layers:
         raise ValueError(
             f'{cfg.name}: {n} leading dense layers leave no expert '
             f'layer of {cfg.num_layers}')
-    experts = ('layers', False, n, cfg.num_layers)
-    return (('dense_layers', True, 0, n), experts) if n else (experts,)
+    kind = lambda l: (l < n, cfg.mixer_kind(l), cfg.swiglu_limits(l))
+    groups, start, named = [], 0, set()
+    for stop in range(1, cfg.num_layers + 1):
+        if stop < cfg.num_layers and kind(stop) == kind(start):
+            continue
+        dense, mixer, limits = kind(start)
+        base = 'dense_layers' if dense and cfg.is_moe else 'layers'
+        groups.append(LayerGroup(
+            f'{base}_{start}' if base in named else base, dense, mixer,
+            limits, start, stop, 0 if dense else start - n))
+        named.add(base)
+        start = stop
+    return tuple(groups)
 
 
 def _carry_pattern(cfg: ModelConfig, carry: Tuple, carried: bool) -> Tuple:
@@ -236,28 +272,32 @@ def _carry_pattern(cfg: ModelConfig, carry: Tuple, carried: bool) -> Tuple:
         variable_axes['adapters'] = 0
     if not carried:
         variable_axes['cache'] = 0
-    for name, dense, start, stop in layer_groups(cfg):
-        # The experts' weights are the one thing a layer is NOT handed
-        # its own slice of: scanned, each (held, in, out) slice would be
-        # copied out of its stack before a grouped product could read
-        # it. They are declared here, whole, and broadcast into the loop
-        # (models/moe.py reads a layer's groups out of the stack).
-        stacks = None
-        if not dense and cfg.moe_impl == 'dropless':
-            from skypilot_tpu.models.moe import ExpertStacks
-            stacks = ExpertStacks(cfg, stop - start, name='experts')()
+    groups = layer_groups(cfg)
+    # The experts' weights are the one thing a layer is NOT handed its
+    # own slice of: scanned, each (held, in, out) slice would be copied
+    # out of its stack before a grouped product could read it. Every
+    # expert layer's are declared here, whole, and broadcast into each
+    # group's loop (models/moe.py reads a layer's out of the stack).
+    stacks = None
+    if cfg.is_moe and cfg.moe_impl == 'dropless':
+        from skypilot_tpu.models.moe import ExpertStacks
+        stacks = ExpertStacks(
+            cfg, sum(g.stop - g.start for g in groups if not g.dense),
+            name='experts')()
+    for g in groups:
         scanned = nn.scan(
             CarriedPatternLayer,
             variable_axes=variable_axes,
             variable_carry='cache' if carried else False,
             split_rngs={'params': True},
             in_axes=(0, nn.broadcast),
-            length=stop - start,
+            length=g.stop - g.start,
             metadata_params={nn.PARTITION_NAME: 'layers'},
-        )(cfg, dense, carried, name=name)
+        )(cfg, g, carried, name=g.name)
         carry, _ = scanned(carry, (
-            jnp.arange(stop - start, dtype=jnp.int32),
-            windows[start:stop], ropes[start:stop]), stacks)
+            jnp.arange(g.stop - g.start, dtype=jnp.int32),
+            windows[g.start:g.stop], ropes[g.start:g.stop]),
+            None if g.dense else stacks)
     return carry
 
 

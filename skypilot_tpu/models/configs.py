@@ -215,6 +215,43 @@ class ModelConfig:
     # Sandwich norms: a second RMSNorm on each branch's OUTPUT before it
     # is added to the residual.
     post_norms: bool = False
+    # --- Mixers of more than one kind (a layer pattern) ---
+    # One a layer: 'attention' (the kinds above), 'kda' (Kimi delta
+    # attention, models/kda.py: num_heads heads of head_dim, a float32
+    # (head_dim, head_dim) state a head and slot, rewritten every step)
+    # or 'mla' (latent attention, models/mla.py: one cached latent of
+    # mla_kv_rank + mla_rope_dim values a position, no kv-head axis).
+    # () ⇒ every layer is attention. The layer loop runs a group a run
+    # of one (dense or expert, mixer) kind (cache_carry.layer_groups),
+    # each with only its own cache leaves.
+    mixer_kinds: Tuple[str, ...] = ()
+    # KDA: the causal depthwise convolution's taps over q, k and v, and
+    # the lower bound of the per-channel log decay (g = bound *
+    # sigmoid(exp(A_log) * (f_proj(x) + dt_bias)), in (bound, 0); the
+    # chunked form needs 16 x |bound| inside float32's exp: kda.CHUNK).
+    kda_conv: int = 4
+    kda_gate_bound: float = -5.0
+    # MLA (DeepSeek-V2 form without a q down-projection): num_heads
+    # query heads of mla_nope_dim + mla_rope_dim, a latent of
+    # mla_kv_rank (RMSNormed) and one rotary key of mla_rope_dim shared
+    # by the heads; keys' nope part and values (mla_v_dim) are
+    # up-projected from the latent; a sigmoid gate a head on the output.
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_v_dim: int = 128
+    # Group-limited routing: the experts form n_group groups of equal
+    # size; a group scores the sum of its two best biased scores, and a
+    # token chooses its experts_per_token within its topk_group best
+    # groups. 1 ⇒ no groups: every expert is a candidate.
+    n_group: int = 1
+    topk_group: int = 1
+    # Per-layer clamps of the expert SwiGLUs (0 ⇒ none): gate clamped
+    # from above, up to [-limit, limit], before silu(gate) * up; one a
+    # layer for the routed experts and one for the shared expert. () ⇒
+    # none anywhere.
+    expert_swiglu_limits: Tuple[float, ...] = ()
+    shared_swiglu_limits: Tuple[float, ...] = ()
     # --- Generation by diffusion over blocks (docs/serving.md) ---
     # block_length B > 0 ⇒ attention is causal between blocks of B
     # positions (aligned to position 0) and runs both ways inside one
@@ -287,6 +324,28 @@ class ModelConfig:
         # A JSON override hands lists: keep the config hashable.
         kinds = tuple((int(w), bool(r)) for w, r in self.layer_kinds)
         object.__setattr__(self, 'layer_kinds', kinds)
+        for name in ('mixer_kinds', 'expert_swiglu_limits',
+                     'shared_swiglu_limits'):
+            value = tuple(getattr(self, name))
+            object.__setattr__(self, name, value)
+            if value and len(value) != self.num_layers:
+                raise ValueError(
+                    f'{self.name}: {name} names {len(value)} layers, '
+                    f'num_layers is {self.num_layers}')
+        if 'kda' in self.mixer_kinds and not -5.0 <= self.kda_gate_bound < 0:
+            raise ValueError(
+                f'{self.name}: a KDA gate bound of {self.kda_gate_bound} '
+                f'takes a block of 16 positions past float32\'s exp')
+        unknown = set(self.mixer_kinds) - {'attention', 'kda', 'mla'}
+        if unknown:
+            raise ValueError(f'{self.name}: unknown mixer kinds '
+                             f'{sorted(unknown)}')
+        if self.n_group > 1 and (
+                self.num_experts % self.n_group
+                or not 0 < self.topk_group <= self.n_group):
+            raise ValueError(
+                f'{self.name}: {self.num_experts} experts in '
+                f'{self.n_group} groups, {self.topk_group} kept')
         if self.block_length and not (
                 0 < self.denoising_steps <= self.block_length
                 and self.mask_token_id >= 0):
@@ -312,7 +371,17 @@ class ModelConfig:
         layer loop, training's plain scan never sees it."""
         return bool(self.layer_kinds or self.num_dense_layers
                     or self.qk_norm or self.attn_gate or self.post_norms
-                    or self.block_length)
+                    or self.block_length or self.mixer_kinds)
+
+    def mixer_kind(self, layer: int) -> str:
+        """'attention', 'kda' or 'mla': the mixer of layer `layer`."""
+        return self.mixer_kinds[layer] if self.mixer_kinds else 'attention'
+
+    def swiglu_limits(self, layer: int) -> Tuple[float, float]:
+        """(routed, shared) SwiGLU clamps of layer `layer`; 0 is none."""
+        pick = lambda limits: float(limits[layer]) if limits else 0.0
+        return (pick(self.expert_swiglu_limits),
+                pick(self.shared_swiglu_limits))
 
     def last_key_seen(self, q_pos):
         """The last key position a query at `q_pos` attends to (an int
@@ -346,7 +415,13 @@ class ModelConfig:
         """True when a sequence's state is more than its K and V: the
         mixer's scan state and convolution inputs, of fixed size, owned
         by a slot and rewritten every step."""
-        return self.ssm_heads > 0
+        return self.ssm_heads > 0 or 'kda' in self.mixer_kinds
+
+    @property
+    def has_latent_cache(self) -> bool:
+        """True when some layer caches a latent a position (MLA), with
+        no kv-head axis, in place of K and V."""
+        return 'mla' in self.mixer_kinds
 
     @property
     def d_ssm(self) -> int:
@@ -372,6 +447,12 @@ class ModelConfig:
         footprint guarantee — so serving refuses it up front."""
         if tp <= 1:
             return
+        if self.has_latent_cache:
+            raise NotImplementedError(
+                f'{self.name}: tp={tp} with a latent cache: the pool '
+                f'has no kv-head axis to split (each chip would hold '
+                f'every latent, or the heads\' absorbed products would '
+                f'have to split over the mesh); serve it at tp=1')
         if self.has_recurrent_state:
             raise NotImplementedError(
                 f'{self.name}: tp={tp} with a recurrent-state mixer: the '
@@ -466,11 +547,33 @@ class ModelConfig:
                 mixer += self.d_ssm
             if self.ssm_proj_bias:
                 mixer += self.ssm_proj_width + self.d_model
-        per_layer = attn + mlp + router + norms + mixer
+        per_layer = mlp + router + norms + mixer
         # leading dense layers of an expert model carry the dense MLP
         dense = self.num_dense_layers if self.is_moe else 0
-        return (embed + self.num_layers * per_layer + norm_params
-                - dense * (mlp + router - dense_mlp))
+        mixers = sum(attn if kind == 'attention'
+                     else self._other_mixer_params(kind)
+                     for kind in map(self.mixer_kind,
+                                     range(self.num_layers)))
+        return (embed + self.num_layers * per_layer + mixers
+                + norm_params - dense * (mlp + router - dense_mlp))
+
+    def _other_mixer_params(self, kind: str) -> int:
+        """Parameters of one KDA or MLA mixer (models/kda.py,
+        models/mla.py)."""
+        d, h, hd = self.d_model, self.num_heads, self.head_dim
+        if kind == 'kda':
+            # q, k, v, f, g and o; beta; the convolution; A_log a head,
+            # dt_bias a channel; the heads' norm
+            return (6 * d * h * hd + d * h + self.kda_conv * 3 * h * hd
+                    + h + h * hd + hd)
+        qk = self.mla_nope_dim + self.mla_rope_dim
+        rank = self.mla_kv_rank
+        # q (and its norm), the latent's down-projection and norm, keys'
+        # and values' up-projection, the gate a head, o
+        return (d * h * qk + (qk if self.qk_norm else 0)
+                + d * (rank + self.mla_rope_dim) + rank
+                + rank * h * (self.mla_nope_dim + self.mla_v_dim)
+                + d * h + h * self.mla_v_dim * d)
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Training FLOPs/token (fwd+bwd ≈ 6 × params-matmul + attention
@@ -731,6 +834,31 @@ SDAR_30B_A3B_CHAT = _register(ModelConfig(
     d_expert=768, router_score='softmax', route_norm=True,
     qk_norm=True, block_length=4, denoising_steps=4,
     mask_token_id=151669, attention_impl='xla', remat=False))
+
+# --- Ling-3.0-flash (inclusionAI, 2026; model_type bailing_hybrid), the
+# language model of the published Ling-3.0-flash-VL config: 42 layers in
+# groups of six, five of Kimi delta attention (a per-channel gated
+# delta rule over a float32 state a head) and the sixth of latent
+# attention (MLA: a latent of 512 and a rotary key of 64 a position);
+# the first two layers with a dense SwiGLU of 6144, the rest with 512
+# routed experts of 768 (sigmoid scores, a selection bias, 8 chosen
+# within the best 4 of 8 groups, normalised and scaled by 2.5) and one
+# shared expert of 768; SwiGLU clamps on the last layers. One chip
+# holds a share of it (perf/configs/ling-3.0-flash-l7-ep8.json).
+LING_3_FLASH = _register(ModelConfig(
+    name='ling-3.0-flash', vocab_size=157184, d_model=2560,
+    num_layers=42, num_heads=32, num_kv_heads=32, head_dim_override=128,
+    d_mlp=6144, max_seq_len=131072, rope_theta=6e6, norm_eps=1e-6,
+    num_experts=512, experts_per_token=8, moe_impl='dropless',
+    d_expert=768, d_shared_expert=768, router_score='sigmoid',
+    router_bias=True, route_norm=True, route_scale=2.5,
+    num_dense_layers=2, n_group=8, topk_group=4,
+    mixer_kinds=(('kda',) * 5 + ('mla',)) * 7,
+    kda_conv=4, kda_gate_bound=-5.0, mla_kv_rank=512, mla_nope_dim=128,
+    mla_rope_dim=64, mla_v_dim=128, qk_norm=True,
+    expert_swiglu_limits=(0.0,) * 35 + (4.0,) * 7,
+    shared_swiglu_limits=(0.0,) * 34 + (5.0,) * 6 + (7.0,) * 2,
+    attention_impl='xla', remat=False))
 
 GPT2_124M = _register(ModelConfig(
     name='gpt2-124m', vocab_size=50304, d_model=768, num_layers=12,

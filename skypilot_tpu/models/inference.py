@@ -506,6 +506,23 @@ def _refuse_recurrent(cfg: ModelConfig, lever: str, why: str) -> None:
             f'recurrent state: {why}')
 
 
+_MIXER_NAMES = {'kda': 'Kimi delta attention', 'mla': 'latent attention'}
+
+
+def _refuse_mixers(cfg: ModelConfig, lever: str, why: str,
+                   kinds: Tuple[str, ...]) -> None:
+    """Levers that cannot take one of the layer mixers `kinds`
+    (`cfg.mixer_kinds`: models/kda.py, models/mla.py) refuse here, by
+    name and with the reason (docs/serving.md "Layers of more than one
+    mixer")."""
+    found = [k for k in kinds if k in cfg.mixer_kinds]
+    if found:
+        raise NotImplementedError(
+            f'{lever} is not supported for {cfg.name}, a model with '
+            f'{" and ".join(_MIXER_NAMES[k] for k in found)} layers: '
+            f'{why}')
+
+
 def _refuse_blocks(cfg: ModelConfig, lever: str, why: str) -> None:
     """Levers that cannot take generation by diffusion over blocks
     (`cfg.block_length`) refuse here, by name and with the reason
@@ -1270,6 +1287,31 @@ class ContinuousBatchingEngine:
                  _NO_STATE_IN_STREAM)):
             if on:
                 _refuse_recurrent(base_cfg, lever, why)
+        for lever, on, why, kinds in (
+                (f'paged_block_size={paged_block_size}',
+                 paged_block_size <= 0,
+                 'a latent cache is served from the paged pool only: the '
+                 'contiguous cache has no latent layout', ('mla',)),
+                (f'kv_quant={kv_quant!r}', kv_quant == 'int8',
+                 'the int8 pool keeps a scale a position and kv head, and '
+                 'a latent has no kv-head axis: nothing rounds it',
+                 ('mla',)),
+                (f'prefix_cache={prefix_cache}', prefix_cache > 0,
+                 'copy-on-write and the prefix artifact copy a block by '
+                 'the K/V pool\'s (blocks, block, kv_heads, head_dim) '
+                 'layout, which a latent pool does not have', ('mla',)),
+                (f'tier={tier!r}', tier != 'monolithic',
+                 'a KV chunk stream is keyed by the K/V pool\'s leaves and '
+                 'carries no latent block', ('mla',)),
+                (f'quantize={quantize!r}', quantize == 'int8',
+                 'models/quantize.py rounds the dense kernels it knows by '
+                 'name, and not these mixers\' (f_proj, g_proj, b_proj, '
+                 'kv_down, kv_up)', ('kda', 'mla')),
+                (f'max_adapters={max_adapters}', max_adapters > 0,
+                 'their projections carry no per-slot adapter index',
+                 ('kda', 'mla'))):
+            if on:
+                _refuse_mixers(base_cfg, lever, why, kinds)
         if base_cfg.block_length:
             _refuse_block_levers(
                 base_cfg, speculative=speculative,

@@ -54,10 +54,12 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 # Two kinds of per-sequence state share the engine's one cache tree.
 # K and V grow with the position and live in the blocks this module
-# hands out. A model with a recurrent mixer (models/ssm.py) also keeps
+# hands out. A model with a recurrent mixer (models/ssm.py, kda.py) keeps
 # leaves of FIXED size a slot, named here, which no block holds: they
 # are indexed by slot, rewritten at every step, and reset by the first
-# chunk of the slot's next request. The engine counts the two apart.
+# chunk of the slot's next request. The engine counts the two apart. (A
+# latent cache, models/mla.py, keeps its rows in blocks of this pool in
+# K and V's place: one table a request addresses either.)
 STATE_LEAVES = ('ssm_state', 'conv_state')
 
 

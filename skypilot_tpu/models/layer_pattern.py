@@ -9,7 +9,10 @@ over (0 = all) and whether rotary position is applied. The kinds are
 not compiled in: the layer loop scans them beside the stacked weights
 (`cache_carry.carry_layers`), a layer reads its own through
 `cache_carry.current_kind()`, and so there is still ONE program over
-each group of layers, whatever the pattern. K/V leaves have one shape
+each group of layers, whatever the pattern. A layer's MIXER kind
+(`cfg.mixer_kinds`: attention, Kimi delta attention, latent attention)
+and its expert clamps are compiled in: the loop runs a group a run of
+one such kind (`cache_carry.layer_groups`). K/V leaves have one shape
 for both kinds (a windowed layer keeps its blocks for the whole
 context, in the one shared table), so the carried cache stays one
 stacked leaf a group.
@@ -102,9 +105,15 @@ class PatternAttention(Attention):
 class PatternLayer(nn.Module):
     """One block. `dense` says which MLP it carries: the dense SwiGLU
     (a leading layer, or any layer of a model without experts) or the
-    expert block."""
+    expert block; `mixer` what mixes positions: attention of the layer's
+    kind, Kimi delta attention (models/kda.py; its state beside the
+    cache's K/V, by slot) or latent attention (models/mla.py; a latent
+    pool in K/V's place); `limits` the expert block's (routed, shared)
+    SwiGLU clamps."""
     cfg: ModelConfig
     dense: bool
+    mixer: str = 'attention'
+    limits: Tuple[float, float] = (0.0, 0.0)
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array,
@@ -120,13 +129,22 @@ class PatternLayer(nn.Module):
         post = (lambda name, y: RMSNorm(cfg, name=name)(y)) \
             if cfg.post_norms else (lambda name, y: y)
         h = RMSNorm(cfg, name='attn_norm')(x)
-        x = x + post('post_attn_norm', PatternAttention(cfg, name='attn')(
-            h, positions, block_tables, adapter_ids))
+        if self.mixer == 'kda':
+            from skypilot_tpu.models.kda import KimiDeltaMixer
+            y = KimiDeltaMixer(cfg, name='mixer')(h, positions, state_rows)
+        elif self.mixer == 'mla':
+            from skypilot_tpu.models.mla import LatentAttention
+            y = LatentAttention(cfg, name='attn')(h, positions,
+                                                  block_tables)
+        else:
+            y = PatternAttention(cfg, name='attn')(
+                h, positions, block_tables, adapter_ids)
+        x = x + post('post_attn_norm', y)
         h = RMSNorm(cfg, name='mlp_norm')(x)
         if self.dense:
             y = SwiGLU(cfg, name='mlp')(h, adapter_ids)
         else:
-            y = MoEBlock(cfg, name='moe')(
+            y = MoEBlock(cfg, self.limits, name='moe')(
                 h, real_positions(h, state_rows), stacks)
         return x + post('post_mlp_norm', y)
 

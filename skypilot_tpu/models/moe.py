@@ -45,7 +45,9 @@ reference could hold. So nothing that serves takes `dispatch`.
   product reaches them. Pads of a chunk and inert slots of a decode
   step (`valid`) route nowhere and count nowhere. Scoring is Mixtral's
   softmax over the chosen k, or a sigmoid over all experts with a
-  selection bias (`cfg.router_score`, `cfg.router_bias`); an expert's
+  selection bias (`cfg.router_score`, `cfg.router_bias`), chosen within
+  a token's best groups where the experts are grouped (`cfg.n_group`,
+  `limit_groups`; without groups the selection is untouched); an expert's
   width may differ from `d_mlp` (`cfg.d_expert`), and one shared expert
   may run on every token (`cfg.d_shared_expert`).
 
@@ -63,16 +65,17 @@ import dataclasses
 from typing import Optional, Tuple
 
 from skypilot_tpu.models.configs import ModelConfig
-from skypilot_tpu.models.transformer import SwiGLU
+from skypilot_tpu.models.transformer import SwiGLU, swiglu_clamp
 from skypilot_tpu.ops.grouped_matmul import grouped_matmul
 from skypilot_tpu.parallel import sharding
 
 # What a dropless layer counts a call, in this order: (token, choice)
 # pairs routed (k a real token), pairs whose expert is held here,
 # distinct held experts that got a token, the largest held expert's
-# tokens.
+# tokens, real tokens whose kept groups hold an expert held here (every
+# real token without groups).
 ROUTE_COUNTS = ('pairs_routed', 'pairs_held', 'experts_touched',
-                'max_expert_load')
+                'max_expert_load', 'tokens_group_held')
 
 
 def expert_stacks(module: nn.Module, cfg: ModelConfig, layers: int
@@ -104,8 +107,26 @@ class ExpertStacks(nn.Module):
         return expert_stacks(self, self.cfg, self.layers)
 
 
+def limit_groups(cfg: ModelConfig, select: jax.Array
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Group-limited selection (`cfg.n_group` > 1): a group's score is
+    the sum of its two best `select` values, and the experts outside a
+    token's `cfg.topk_group` best groups are put out of reach (-inf).
+    Returns (select, (n, n_group) bool: the groups each token keeps)."""
+    n, e = select.shape
+    grouped = select.reshape(n, cfg.n_group, e // cfg.n_group)
+    best2 = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)     # (n, groups)
+    _, kept = jax.lax.top_k(best2, cfg.topk_group)
+    keep = jnp.zeros((n, cfg.n_group), bool).at[
+        jnp.arange(n)[:, None], kept].set(True)
+    select = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(n, e)
+    return select, keep
+
+
 class MoEBlock(nn.Module):
     cfg: ModelConfig
+    # (routed, shared) SwiGLU clamps of this layer (0: none)
+    limits: Tuple[float, float] = (0.0, 0.0)
 
     @nn.compact
     def __call__(self, x: jax.Array,
@@ -319,6 +340,17 @@ class MoEBlock(nn.Module):
                 'expert_bias', nn.with_logical_partitioning(
                     nn.initializers.zeros, (None,)),
                 (e,), jnp.float32)
+        real = (jnp.ones((n,), bool) if valid is None
+                else valid.reshape(n))
+        if cfg.n_group > 1:
+            select, keep = limit_groups(cfg, select)
+            size = e // cfg.n_group
+            groups = jnp.arange(cfg.n_group)
+            mine = ((groups + 1) * size > first) & (
+                groups * size < first + held)
+            group_held = real & jnp.any(keep & mine, axis=-1)
+        else:
+            group_held = real
         _, chosen = jax.lax.top_k(select, k)                  # (n, k)
         weight = jnp.take_along_axis(scores, chosen, axis=-1)
         if cfg.router_score == 'softmax':
@@ -328,8 +360,6 @@ class MoEBlock(nn.Module):
         weight = weight * cfg.route_scale
 
         # ---- the pairs held here, sorted by expert ----
-        real = (jnp.ones((n,), bool) if valid is None
-                else valid.reshape(n))
         local = chosen - first
         here = (local >= 0) & (local < held) & real[:, None]  # (n, k)
         # a pair held elsewhere (or a pad's) sorts behind the last group
@@ -337,8 +367,9 @@ class MoEBlock(nn.Module):
         order = jnp.argsort(group, stable=True)
         sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
         rows = xf[order // k].astype(dtype)                  # (n k, d)
-        gate = grouped_matmul(rows, w_gate, sizes, layer)
-        up = grouped_matmul(rows, w_up, sizes, layer)
+        gate, up = swiglu_clamp(grouped_matmul(rows, w_gate, sizes, layer),
+                                grouped_matmul(rows, w_up, sizes, layer),
+                                self.limits[0])
         hidden = (nn.silu(gate) * up).astype(dtype)
         out = grouped_matmul(hidden, w_down, sizes, layer,
                              preferred_element_type=jnp.float32)
@@ -354,13 +385,15 @@ class MoEBlock(nn.Module):
         y = routed.astype(dtype).reshape(b, s, d)
         if cfg.d_shared_expert:
             y = y + SwiGLU(dataclasses.replace(
-                cfg, d_mlp=cfg.d_shared_expert), name='shared')(x)
+                cfg, d_mlp=cfg.d_shared_expert), self.limits[1],
+                name='shared')(x)
         if not self.is_initializing():
             self.sow('moe_stats', 'counts', jnp.stack([
                 k * jnp.sum(real, dtype=jnp.int32),
                 jnp.sum(here, dtype=jnp.int32),
                 jnp.sum(sizes > 0, dtype=jnp.int32),
-                jnp.max(sizes)]),
+                jnp.max(sizes),
+                jnp.sum(group_held, dtype=jnp.int32)]),
                 init_fn=lambda: jnp.zeros((len(ROUTE_COUNTS),), jnp.int32),
                 reduce_fn=lambda a, c: a + c)
         return sharding.constrain(y, 'batch', 'seq', 'act_embed')

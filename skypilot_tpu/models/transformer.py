@@ -880,11 +880,23 @@ class Attention(nn.Module):
                               positions)
 
 
+def swiglu_clamp(gate: jax.Array, up: jax.Array, limit: float):
+    """(min(gate, limit), clip(up, -limit, limit)): the clamp some expert
+    layers put on a SwiGLU's two projections before silu(gate) * up; 0
+    traces nothing."""
+    if not limit:
+        return gate, up
+    return jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+
+
 class SwiGLU(nn.Module):
     """Feed-forward in the family's dialect: GLU (gate·act × up → down;
     silu = Llama SwiGLU, gelu = Gemma GeGLU) or 'plain' (up → act → down;
-    GPT-2), with optional biases (GPT-2)."""
+    GPT-2), with optional biases (GPT-2). `limit` > 0 clamps a GLU's
+    gate from above and its up projection to [-limit, limit] before
+    they multiply (`swiglu_clamp`)."""
     cfg: ModelConfig
+    limit: float = 0.0
 
     @nn.compact
     def __call__(self, x: jax.Array,
@@ -900,7 +912,9 @@ class SwiGLU(nn.Module):
             gate = _apply_proj(
                 dense(cfg.d_mlp, ('embed', 'mlp'), 'gate_proj'),
                 x, adapter_ids)
-            h = act(scaled(gate, cfg.mlp_multipliers[0])) * up
+            gate, up = swiglu_clamp(scaled(gate, cfg.mlp_multipliers[0]),
+                                    up, self.limit)
+            h = act(gate) * up
         else:
             h = act(up)
         h = sharding.constrain(h, 'batch', 'seq', 'mlp')

@@ -280,7 +280,7 @@ def _check_in_place(layout: str, one) -> None:
     # loop-carried leaves and relays scale rows a trip, in the scanned
     # form as in this one: there K and V are what it tells apart.
     strict = {hlo(a, a.shape) for k, a in leaves.items()
-              if k.endswith(('cached_key', 'cached_value'))
+              if k.endswith(('cached_key', 'cached_value', 'cached_latent'))
               or (one is not None and '/mixer/' in k)}
     # one layer's part of a leaf that no program needs whole: a paged
     # pool, of which a step reads the rows its tables name, and in the
@@ -295,7 +295,7 @@ def _check_in_place(layout: str, one) -> None:
         if one is not None and layout.endswith('kernel'):
             # the decode step's recurrence is the kernel, the chunk's not
             state_kernel = re.search(
-                r'%ssd_state_step(\.\d+)? = .*custom-call\(', text)
+                r'%(ssd|kda)_state_step(\.\d+)? = .*custom-call\(', text)
             assert bool(state_kernel) == (name == 'decode'), name
         if one is not None:
             made = _new_buffers(text, whole)
@@ -332,6 +332,106 @@ def test_compiled_for_the_cpu_a_layer_pattern_carries_a_leaf_a_group():
 
 def test_compiled_for_a_v5e_a_layer_pattern_carries_a_leaf_a_group(v5e):
     _check_in_place('afmoe', v5e)
+
+
+def test_compiled_for_a_v5e_the_hybrid_cell_at_published_widths(v5e):
+    """Ling-3.0-flash's share as its cell serves it (7 layers, 256 slots,
+    context 2560, weights as shapes alone): the decode step runs the
+    delta rule of both KDA groups through ops/kda_state_step and the
+    latent attention through ops/paged_attention; neither program makes
+    a buffer of the KDA state's or the latent pool's shape, and inside
+    no loop one of any cache leaf's; weights, cache and scratch fit the
+    chip. (At tiny sizes the TPU compiler moves small leaves into
+    VMEM and back, which this test would read: so the cell's own
+    sizes. A group of one layer loops once, and its convolution inputs
+    are then written whole as the step's new value.)"""
+    cfg = get_config(
+        'ling-3.0-flash', num_layers=7, num_dense_layers=1,
+        experts_held=64, vocab_size=19648, max_seq_len=2560,
+        mixer_kinds=('kda',) * 6 + ('mla',),
+        expert_swiglu_limits=(0.0,) * 7, shared_swiglu_limits=(0.0,) * 7,
+        param_dtype='bfloat16')
+    abstract = nn.unbox(jax.eval_shape(lambda: Transformer(
+        dataclasses.replace(cfg, decode=False)).init(
+            jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32)))['params'])
+    engine = ContinuousBatchingEngine(cfg, params=abstract, num_slots=256,
+                                      paged_block_size=16,
+                                      decode_kernel='xla')
+    engine.cfg = dataclasses.replace(engine.cfg, decode_kernel='pallas')
+    engine.model = Transformer(engine.cfg)
+    try:
+        compiled, cache = _compile_programs(engine, v5e)
+    finally:
+        engine.stop()
+    leaves = _leaves(cache)
+    hlo = lambda a, dims: (_HLO_DTYPE[a.dtype.name], tuple(dims))
+    strict = {hlo(a, a.shape) for k, a in leaves.items()
+              if k.endswith(('ssm_state', 'cached_latent'))}
+    whole = {hlo(a, a.shape) for a in leaves.values()}
+    assert len(strict) == 3
+    for name, prog in compiled.items():
+        text = prog.as_text()
+        assert _new_buffers(text, strict) == [], name
+        assert [m for m in _new_buffers(text, whole) if m[0]] == [], name
+        kda = len(re.findall(r'%kda_state_step(\.\d+)? = ', text))
+        latent = len(re.findall(r'%attn\._paged(\.\d+)? = ', text))
+        assert (kda, latent) == ((2, 1) if name == 'decode' else (0, 0))
+        mem = prog.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11e9
+
+
+def test_compiled_for_a_v5e_the_kda_state_kernel_at_published_widths(v5e):
+    """Ling-3.0-flash's delta rule over 256 slots, one Mosaic call on
+    the stacked state of five layers (a traced layer): 2.7 GB written
+    where it lies, never copied; the scratch is the per-head inputs."""
+    from skypilot_tpu.ops import kda_state_step
+    arr = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=v5e)
+    layers, slots, heads, d = 5, 256, 32, 128
+    leaf = (layers, slots, heads, d, d)
+    vec = arr(jnp.float32, slots, heads, d)
+    compiled = jax.jit(kda_state_step.kda_state_step,
+                       donate_argnums=(0,)).trace(
+        arr(jnp.float32, *leaf), arr(jnp.int32), arr(jnp.bool_, slots),
+        arr(jnp.bool_, slots), vec, vec, vec, vec, vec).lower(
+            lowering_platforms=('tpu',)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert _new_buffers(text, {('f32', leaf), ('f32', leaf[1:])}) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_compiled_for_a_v5e_the_latent_decode_at_published_widths(v5e):
+    """Ling-3.0-flash's MLA decode step over 256 slots at context
+    2560: the pool of 576 values a position laid out as rows of 640
+    (five lane tiles), one kv head both key and value, 32 absorbed query
+    heads; one Mosaic call, and the pool is neither copied nor relaid
+    (its 4-d view for the kernel folds away)."""
+    from skypilot_tpu.models.mla import cache_width
+    from skypilot_tpu.ops import paged_attention
+    cfg = get_config('ling-3.0-flash')
+    width = cache_width(cfg)
+    assert width == 640
+    arr = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=v5e)
+    slots, bs, context = 256, 16, 2560
+    blocks = slots * context // bs + 1
+    pool = (1, blocks, bs, width)
+
+    def attend(q, pool, tables, positions, first):
+        view = pool.reshape(-1, bs, 1, width)
+        return paged_attention.paged_decode_attention(
+            q, view, view, tables, positions, first_block=first,
+            sm_scale=192 ** -0.5)[..., :512]
+    compiled = jax.jit(attend).trace(
+        arr(jnp.bfloat16, slots, 1, 32, width), arr(jnp.bfloat16, *pool),
+        arr(jnp.int32, slots, context // bs), arr(jnp.int32, slots, 1),
+        arr(jnp.int32)).lower(lowering_platforms=('tpu',)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert _new_buffers(text, {('bf16', pool), ('bf16', pool[1:]),
+                               ('bf16', (blocks, bs, 1, width))}) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 def test_compiled_for_a_v5e_the_grouped_products_at_published_widths(
