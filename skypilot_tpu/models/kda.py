@@ -14,9 +14,10 @@ D = head_dim (keys and values alike):
     o[t]     = S[t]^T q
     out      = o_proj(rms_norm_head(o) * sigmoid(g_proj x))
 
-The state is held TRANSPOSED, `S^T` (values by keys), so that a key
-runs along the lanes: the step is then `sd = S^T * exp(g)`, `u = v -
-sd . k`, `S^T = sd + u (x) beta k`, `o = S^T . q`.
+The state is held KEYS-MAJOR, `S[k, v]` (keys by values, as `fla`
+holds it), so that a key runs along the chip's sublanes and a value
+along its lanes: both contractions of a step, `u = v - sum_k (S exp g)
+k` and `o = sum_k S q`, are then sums over sublanes.
 
 A sequence's state is of FIXED size whatever its position: the float32
 state and the convolution's last `kda_conv - 1` inputs (compute dtype),
@@ -87,19 +88,19 @@ def kda_gate(f: jax.Array, a_log: jax.Array, dt_bias: jax.Array,
 
 
 def kda_step(state, q, k, v, g, beta):
-    """The one-step recurrence. state: (B, H, V, K) float32, the
-    transposed state; q, k, g: (B, H, K); v: (B, H, V); beta: (B, H),
-    all float32 and already masked. Returns (o (B, H, V), new state).
+    """The one-step recurrence. state: (B, H, K, V) float32, keys-major;
+    q, k, g: (B, H, K); v: (B, H, V); beta: (B, H), all float32 and
+    already masked. Returns (o (B, H, V), new state).
 
     Written so that nothing of the state's size but the new state is
     made: the decay folds into k and q before their products with the
-    old state, and `new . q = old . (e^g q) + u (beta k . q)`."""
+    old state, and `q . new = (e^g q) . old + u (beta k . q)`."""
     decay = jnp.exp(g)
     bk = beta[..., None] * k
-    u = v - jnp.sum(state * (decay * k)[:, :, None, :], axis=-1)
-    o = (jnp.sum(state * (decay * q)[:, :, None, :], axis=-1)
+    u = v - jnp.sum(state * (decay * k)[..., None], axis=-2)
+    o = (jnp.sum(state * (decay * q)[..., None], axis=-2)
          + u * jnp.sum(bk * q, axis=-1, keepdims=True))
-    return o, state * decay[:, :, None, :] + u[..., None] * bk[:, :, None, :]
+    return o, state * decay[..., None] + bk[..., None] * u[:, :, None, :]
 
 
 def kda_step_in_place(leaf, layer, touched, fresh, q, k, v, g, beta,
@@ -133,7 +134,7 @@ def _unit_lower_inverse(low: jax.Array) -> jax.Array:
 
 def kda_chunked(state, q, k, v, g, beta, block: int):
     """The chunked form over T positions, `block` at a time. state:
-    (B, H, V, K) float32, transposed; q, k, g: (B, T, H, K); v: (B, T,
+    (B, H, K, V) float32, keys-major; q, k, g: (B, T, H, K); v: (B, T,
     H, V); beta: (B, T, H); all float32, g and beta zero where a
     position must not advance the state. Returns (o (B, T, H, V)
     float32, end state)."""
@@ -162,11 +163,12 @@ def kda_chunked(state, q, k, v, g, beta, block: int):
                         0.0)
         m = jnp.where(upto, _mm('bhik,bhjk->bhij', qt, b) * beta_j, 0.0)
         u = _mm('bhij,bhjv->bhiv', _unit_lower_inverse(low),
-                v_k - _mm('bhck,bhvk->bhcv', a, s0))
-        o = _mm('bhck,bhvk->bhcv', qt, s0) + _mm('bhij,bhjv->bhiv', m, u)
+                v_k - _mm('bhck,bhkv->bhcv', a, s0))
+        o = _mm('bhck,bhkv->bhcv', qt, s0) + _mm('bhij,bhjv->bhiv', m, u)
         last = cum[:, :, -1:]                               # (B,H,1,K)
         w = k_k * jnp.exp(last - cum) * b_k[..., None]
-        s1 = s0 * jnp.exp(last) + _mm('bhcv,bhck->bhvk', u, w)
+        s1 = (s0 * jnp.exp(last).swapaxes(-1, -2)
+              + _mm('bhck,bhcv->bhkv', w, u))
         return s1, o
 
     state, os = jax.lax.scan(one_block, state,

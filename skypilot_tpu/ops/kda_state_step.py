@@ -7,32 +7,41 @@ step's recurrence as several passes over it (the decayed state for the
 delta correction, again for the new state and its contraction with q,
 again for an inert row's `where(touched, new, old)` and the write into
 the carried leaf). This kernel reads each state tile once and writes it
-once. With the state held transposed, `S[v, k]` (values along sublanes,
-keys along lanes), a head's step is
+once. The state is held keys-major, `S[k, v]`: keys along the sublanes,
+values along the lanes. A head's step is then
 
-    sd  = s0 * exp(g)                       s0 = 0 for a fresh row
-    u   = v - sd . k                        the delta correction (V,)
-    new = sd + u (x) (beta * k)
-    o   = new . q                           (float32)
+    sd  = s0 * exp(g)[:, None]              s0 = 0 for a fresh row
+    u   = v - sum_k sd * k[:, None]         the delta correction (1, V)
+    new = sd + (beta * k)[:, None] * u
+    o   = sum_k new * q[:, None]            (1, V), float32
     leaf[layer, slot] = new if the row is touched, else its old state
 
-Grid (slots, heads / hb): a cell holds `hb` heads of one slot, a
-`(hb, V, K)` tile read out of the stacked `(L, slots, H, V, K)` leaf at
-(layer, slot, head block) and written back to the same place. The layer
-and each slot's `touched` / `fresh` flags come in by scalar prefetch; the
-leaf is aliased to the output (`input_output_aliases`), so the layer
-loop that carries the cache updates it where it lies and no other layer
-is touched. A row that is not touched writes the tile it read: its state
+so both contractions over the keys are sums over sublanes: elementwise
+adds of a head's vregs and one reduction across the sublanes of the
+last, where keys along the lanes would cost a reduction across the lanes
+of every vreg. The per-key vectors come in as (hb, K) rows, and eight
+heads' rows at a time are transposed into columns.
+
+Grid (slots, heads / hb): a cell holds `hb` heads of one slot (a whole
+slot of 32 heads where the heads allow, `head_block`), a `(hb, K, V)`
+tile read out of the stacked `(L, slots, H, K, V)` leaf at (layer, slot,
+head block) and written back to the same place; inside, a `fori_loop`
+walks the heads eight at a time, where a Python loop over the heads
+would unroll the kernel's body that many times. The layer and each
+slot's `touched` / `fresh` flags come in by scalar prefetch; the leaf is
+aliased to the output (`input_output_aliases`), so the layer loop that
+carries the cache updates it where it lies and no other layer is
+touched. A row that is not touched writes the tile it read: its state
 stays bit for bit.
 
-The arithmetic is `models/kda.kda_step`'s, operation for operation; its
-two sums over the key axis run in the kernel's own order, so the state
-and `o` agree with it to float32 round-off. Every product with the state
-is elementwise in float32, never a bfloat16 pass of the MXU. The decay
+The arithmetic is `models/kda.kda_step`'s in another order (the decay
+applied to the state, not folded into k and q), so the state and `o`
+agree with it to float32 round-off. Every product with the state is
+elementwise in float32, never a bfloat16 pass of the MXU. The decay
 `exp(g)` and `beta * k` are computed by the caller in XLA.
 
 `interpret=True` runs the same kernel under the Pallas interpreter on
-the CPU (tests/test_kda.py pins it against `kda_step`).
+the CPU (tests/test_ling.py pins it against `kda_step`).
 """
 from __future__ import annotations
 
@@ -46,9 +55,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
 # Largest head block tried first: a (hb, 128, 128) float32 tile is
-# hb x 64 KB, read and written double-buffered, so 16 heads take 4 MB of
-# the v5e's 16 MB of scoped VMEM.
-_HEAD_BLOCKS = (16, 8, 4, 2, 1)
+# hb x 64 KB, read and written double-buffered, so a whole slot of 32
+# heads takes 8 MB of the v5e's 16 MB of scoped VMEM.
+_HEAD_BLOCKS = (32, 16, 8, 4, 2, 1)
+# heads a trip of the loop inside a cell: one (8, 128) vreg of each
+# per-key vector, transposed into eight columns
+_GROUP = 8
 
 
 def head_block(heads: int) -> int:
@@ -64,25 +76,31 @@ def _state_kernel(layer_ref, touched_ref, fresh_ref,     # SMEM
     slot = pl.program_id(0)
     fresh = fresh_ref[slot] != 0
     touched = touched_ref[slot] != 0
-    decay = decay_ref[0, 0]                          # (hb, K)
-    q = q_ref[0, 0]
-    k = k_ref[0, 0]
-    bk = bk_ref[0, 0]
-    v_cols = v_ref[0, 0].T                           # (V, hb)
-    v_dim = v_cols.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (v_dim, hb), 1)
-    o_cols = jnp.zeros((v_dim, hb), F32)
-    for j in range(hb):
-        old = s_ref[0, 0, j]                         # (V, K)
-        s0 = jnp.where(fresh, 0.0, old)
-        sd = s0 * decay[j:j + 1]
-        u = v_cols[:, j:j + 1] - jnp.sum(sd * k[j:j + 1], axis=-1,
-                                         keepdims=True)       # (V, 1)
-        new = sd + u * bk[j:j + 1]
-        o_j = jnp.sum(new * q[j:j + 1], axis=-1, keepdims=True)
-        o_cols = jnp.where(lane == j, o_j, o_cols)
-        out_ref[0, 0, j] = jnp.where(touched, new, old)
-    o_ref[0, 0] = o_cols.T                           # (hb, V)
+    group = min(_GROUP, hb)
+
+    def heads(g, carry):
+        decay = decay_ref[0, g].T                    # (K, group)
+        q = q_ref[0, g].T
+        k = k_ref[0, g].T
+        bk = bk_ref[0, g].T
+        v = v_ref[0, g]                              # (group, V)
+        row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        o = jnp.zeros(v.shape, F32)
+        for j in range(group):
+            h = g * group + j
+            col = slice(j, j + 1)
+            old = s_ref[0, 0, h]                     # (K, V)
+            sd = jnp.where(fresh, 0.0, old) * decay[:, col]
+            u = v[col] - jnp.sum(sd * k[:, col], axis=0, keepdims=True)
+            new = sd + bk[:, col] * u
+            o = jnp.where(row == j,
+                          jnp.sum(new * q[:, col], axis=0, keepdims=True),
+                          o)
+            out_ref[0, 0, h] = jnp.where(touched, new, old)
+        o_ref[0, g] = o
+        return carry
+
+    jax.lax.fori_loop(0, hb // group, heads, 0)
 
 
 def kda_state_step(leaf: jax.Array, layer: Optional[jax.Array],
@@ -92,8 +110,8 @@ def kda_state_step(leaf: jax.Array, layer: Optional[jax.Array],
     """One step of every slot's KDA recurrence, written into the leaf.
 
     Args:
-      leaf: the state, float32 (L, S, H, V, K) stacked with `layer` its
-        index in it, or one layer's (S, H, V, K) with `layer` None.
+      leaf: the state, float32 (L, S, H, K, V) stacked with `layer` its
+        index in it, or one layer's (S, H, K, V) with `layer` None.
       touched: (S,) true where the row advances; an untouched row's
         state is written back as it was read.
       fresh: (S,) true where the row starts from a zero state
@@ -103,31 +121,35 @@ def kda_state_step(leaf: jax.Array, layer: Optional[jax.Array],
         and key; bk: (S, H, K) float32, beta * k.
       v: (S, H, V) float32.
 
-    Returns (o (S, H, V) float32 = new . q, the leaf with every row's
-    new state at `layer`).
+    Returns (o (S, H, V) float32 = sum_k new * q, the leaf with every
+    row's new state at `layer`).
     """
     stacked = leaf if layer is not None else leaf[None]
-    _, slots, heads, v_dim, k_dim = stacked.shape
+    _, slots, heads, k_dim, v_dim = stacked.shape
     hb = head_block(heads)
-    nhb = heads // hb
+    group = min(_GROUP, hb)
     layer_arr = jnp.reshape(jnp.asarray(0 if layer is None else layer,
                                         jnp.int32), (1,))
+    # the per-head vectors as (slots, groups, group, width): a trip of
+    # the loop reads one whole (group, width) tile of each
     row = lambda width: pl.BlockSpec(
-        (1, 1, hb, width), lambda s, h, *_: (s, h, 0, 0))
+        (1, hb // group, group, width), lambda s, h, *_: (s, h, 0, 0))
     tile = pl.BlockSpec(
-        (1, 1, hb, v_dim, k_dim),
+        (1, 1, hb, k_dim, v_dim),
         lambda s, h, lyr, *_: (lyr[0], s, h, 0, 0))
-    blocks = lambda a, width: a.astype(F32).reshape(slots, nhb, hb, width)
+    blocks = lambda a, width: a.astype(F32).reshape(
+        slots, heads // group, group, width)
     o, out = pl.pallas_call(
         functools.partial(_state_kernel, hb=hb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(slots, nhb),
+            grid=(slots, heads // hb),
             in_specs=[row(k_dim), row(k_dim), row(k_dim), row(k_dim),
                       row(v_dim), tile],
             out_specs=[row(v_dim), tile],
         ),
-        out_shape=[jax.ShapeDtypeStruct((slots, nhb, hb, v_dim), F32),
+        out_shape=[jax.ShapeDtypeStruct(
+            (slots, heads // group, group, v_dim), F32),
                    jax.ShapeDtypeStruct(stacked.shape, stacked.dtype)],
         input_output_aliases={8: 1},
         compiler_params=pltpu.CompilerParams(

@@ -4,7 +4,8 @@ group-limited router of models/moe.py), pinned on the CPU at tiny sizes:
 - Kimi delta attention: the chunked form against the recurrence at
   lengths that are not a multiple of its block, pads that advance
   nothing, a row of count 0 kept bit for bit, a reused slot that starts
-  clean, and ops/kda_state_step (interpreted) against the XLA step;
+  clean, and ops/kda_state_step (interpreted, at head blocks of 32 and
+  16, and on a state the chunked form wrote) against the XLA step;
 - latent attention: the absorbed form (whole sequences, the paged pool
   through the gather and through ops/paged_attention) against the
   expanded one;
@@ -103,34 +104,79 @@ def test_pads_advance_nothing_in_the_chunked_form():
                                np.asarray(o_real), atol=1e-6, rtol=1e-6)
 
 
-def test_the_state_kernel_is_the_xla_step():
-    """ops/kda_state_step under the interpreter against `kda_step` on a
-    stacked leaf at a traced layer: a touched row advances (to float32
+def _state_kernel_against_xla_step(rng, h, layers, layer, touched, fresh,
+                                   d=16):
+    """The interpreted kernel over a stacked leaf of `layers` at a traced
+    `layer`, against `kda_step`: a touched row advances (to float32
     round-off), a fresh one starts from zero, an untouched one keeps its
     state bit for bit, and no other layer is touched."""
     from skypilot_tpu.ops.kda_state_step import kda_state_step
-    rng = np.random.default_rng(4)
-    slots, h, d = 4, 4, 16
+    slots = len(touched)
     q, k, v, g, beta = (a[:, 0] for a in _inputs(rng, slots, 1, h, d))
-    leaf = jnp.asarray(rng.standard_normal((3, slots, h, d, d)), F32)
-    touched = jnp.asarray([True, True, False, True])
-    fresh = jnp.asarray([False, True, False, False])
+    leaf = jnp.asarray(rng.standard_normal((layers, slots, h, d, d)), F32)
+    touched, fresh = jnp.asarray(touched), jnp.asarray(fresh)
     o, out = jax.jit(lambda *a: kda_state_step(*a, interpret=True))(
-        leaf, jnp.int32(1), touched, fresh, jnp.exp(g), q, k,
+        leaf, jnp.int32(layer), touched, fresh, jnp.exp(g), q, k,
         beta[..., None] * k, v)
-    s0 = jnp.where(fresh[:, None, None, None], 0.0, leaf[1])
+    s0 = jnp.where(fresh[:, None, None, None], 0.0, leaf[layer])
     want_o, want_s = kda_lib.kda_step(s0, q, k, v, g, beta)
     live = np.asarray(touched)
     np.testing.assert_allclose(np.asarray(o)[live],
                                np.asarray(want_o)[live], atol=1e-5,
                                rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(out[1])[live],
+    np.testing.assert_allclose(np.asarray(out[layer])[live],
                                np.asarray(want_s)[live], atol=1e-5,
                                rtol=1e-5)
-    assert np.array_equal(np.asarray(out[1, 2]), np.asarray(leaf[1, 2]))
-    for other in (0, 2):
+    for slot in np.flatnonzero(~live):
+        assert np.array_equal(np.asarray(out[layer, slot]),
+                              np.asarray(leaf[layer, slot]))
+    for other in set(range(layers)) - {layer}:
         assert np.array_equal(np.asarray(out[other]),
                               np.asarray(leaf[other]))
+
+
+def test_the_state_kernel_is_the_xla_step():
+    """ops/kda_state_step under the interpreter against `kda_step` on a
+    stacked leaf at a traced layer: a touched row advances, a fresh one
+    starts from zero, an untouched one is kept and no other layer is
+    touched."""
+    _state_kernel_against_xla_step(
+        np.random.default_rng(4), h=4, layers=3, layer=1,
+        touched=[True, True, False, True], fresh=[False, True, False, False])
+
+
+@pytest.mark.parametrize('hb', [32, 16])
+def test_the_state_kernel_at_a_head_block(hb):
+    """The same rules at a head block of a whole slot of 32 heads and of
+    16: every head of a cell (the loop's groups of eight)."""
+    from skypilot_tpu.ops import kda_state_step as ops
+    assert ops.head_block(hb) == hb
+    _state_kernel_against_xla_step(
+        np.random.default_rng(40 + hb), h=hb, layers=2, layer=0,
+        touched=[True, False, True], fresh=[False, False, True])
+
+
+def test_a_state_from_the_chunked_form_steps_in_the_kernel_as_in_xla():
+    """The layout's round trip from prefill to decode: the state that
+    `kda_chunked` writes, advanced one step by the interpreted kernel,
+    equals `kda_step` on the same state."""
+    from skypilot_tpu.ops.kda_state_step import kda_state_step
+    rng = np.random.default_rng(6)
+    slots, t, h, d = 2, 21, 4, 16
+    q, k, v, g, beta = _inputs(rng, slots, t + 1, h, d)
+    _, state = kda_lib.kda_chunked(
+        jnp.zeros((slots, h, d, d), F32),
+        *(a[:, :t] for a in (q, k, v, g, beta)), kda_lib.CHUNK)
+    step = [a[:, t] for a in (q, k, v, g, beta)]
+    want_o, want_s = kda_lib.kda_step(state, *step)
+    q1, k1, v1, g1, b1 = step
+    o, got = jax.jit(lambda *a: kda_state_step(*a, interpret=True))(
+        state, None, jnp.ones((slots,), bool), jnp.zeros((slots,), bool),
+        jnp.exp(g1), q1, k1, b1[..., None] * k1, v1)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want_s),
+                               atol=1e-5, rtol=1e-5)
 
 
 def _mixer_cache(cfg, params, x, positions, state_rows, cache=None):
@@ -173,6 +219,7 @@ def test_a_slot_of_count_zero_keeps_its_state_and_a_reused_one_starts_clean():
     # a decode step over every slot, slot 2 of count 0: kept bit for bit
     step_x = jnp.asarray(rng.standard_normal((3, 1, 64)), F32)
     step_pos = jnp.asarray([[6], [6], [3]], jnp.int32)
+    states = {}
     for kernel in ('xla', 'pallas_interpret'):
         kcfg = dataclasses.replace(cfg, decode_kernel=kernel)
         _, after = _mixer_cache(kcfg, params, step_x, step_pos,
@@ -183,6 +230,10 @@ def test_a_slot_of_count_zero_keeps_its_state_and_a_reused_one_starts_clean():
                                   np.asarray(cache[name][2])), kernel
             assert not np.array_equal(np.asarray(after[name][0]),
                                       np.asarray(cache[name][0])), kernel
+        states[kernel] = np.asarray(after['ssm_state'])
+    # both read and write the one keys-major leaf
+    np.testing.assert_allclose(states['pallas_interpret'], states['xla'],
+                               atol=1e-5, rtol=1e-5)
 
 
 def test_the_absorbed_latent_attention_is_the_expanded_one():
